@@ -233,7 +233,9 @@ class ObsConfig:
     fence_device: bool = True       # block_until_ready between dispatch
     #                                 and wait (host/device attribution)
     jax_annotations: bool = False   # also emit jax.profiler
-    #                                 TraceAnnotations per span
+    #                                 TraceAnnotations per span (with its
+    #                                 attributes) and one engine.step
+    #                                 annotation per tick
     max_events: int = 262_144       # storage bound: spans+events beyond
     #                                 this are counted (dropped), not kept
 

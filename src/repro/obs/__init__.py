@@ -13,6 +13,9 @@ live in one registry that all exporters read.
             per-tick host/device/padding aggregates. Disabled mode is a
             shared no-op singleton — near-zero overhead, asserted in
             tier-1.
+  compiles  the one process-wide jax.monitoring listener: compiles
+            during an engine tick count in engine_compiles_total by
+            function name, and in the tick's ``compiles`` stat.
   registry  Counter/Gauge/Histogram registry: the shared substrate
             engine, scheduler, pool, prefix cache, and spec metrics
             register into; metrics.summary() and every exporter read

@@ -26,7 +26,10 @@ value}}`` exposes ``bucket_attainment_<suffix>{bucket="<value>"}``.
 Label VALUES pass through ``escape_label_value`` (backslash, quote,
 newline — the Prometheus text-format escapes), so entity names the
 registry doesn't control (request ids, bucket labels) can't corrupt
-the exposition.
+the exposition. A labeled counter is the push-style variant with one
+label (``labeled_counter("engine_compiles_total", "fun_name")``): its
+``value`` is the sum, and each label value is exported as its own
+series.
 """
 
 from __future__ import annotations
@@ -65,6 +68,26 @@ class Counter:
 
     def inc(self, n=1) -> None:
         self.value += n
+
+
+class LabeledCounter:
+    """Monotonic counter split by one label (``inc(label_value)``);
+    ``value`` is the sum over label values."""
+
+    __slots__ = ("name", "help", "label", "values")
+
+    def __init__(self, name: str, help: str = "", label: str = "label"):
+        self.name = name
+        self.help = help
+        self.label = label
+        self.values: Dict[str, float] = {}
+
+    def inc(self, label_value: str, n=1) -> None:
+        self.values[label_value] = self.values.get(label_value, 0) + n
+
+    @property
+    def value(self):
+        return sum(self.values.values())
 
 
 class Gauge:
@@ -147,6 +170,10 @@ class Registry:
     def counter(self, name: str, help: str = "") -> Counter:
         return self._get(Counter, name, help=help)
 
+    def labeled_counter(self, name: str, label: str, help: str = ""
+                        ) -> LabeledCounter:
+        return self._get(LabeledCounter, name, help=help, label=label)
+
     def gauge(self, name: str, help: str = "",
               fn: Optional[Callable[[], float]] = None) -> Gauge:
         g = self._get(Gauge, name, help=help)
@@ -225,6 +252,10 @@ class Registry:
                                          in m.cumulative()]}
             else:
                 out[name] = m.value
+            if isinstance(m, LabeledCounter):
+                for lv, v in m.values.items():
+                    out[f'{name}{{{m.label}="{escape_label_value(lv)}"}}'] \
+                        = v
         out.update(self._group_values())
         for name, label, lv, v in self._labeled_series():
             out[f'{name}{{{label}="{escape_label_value(lv)}"}}'] = v
@@ -243,6 +274,11 @@ class Registry:
             if isinstance(m, Counter):
                 lines.append(f"# TYPE {name} counter")
                 lines.append(f"{name} {m.value}")
+            elif isinstance(m, LabeledCounter):
+                lines.append(f"# TYPE {name} counter")
+                for lv, v in sorted(m.values.items()):
+                    lines.append(f'{name}{{{m.label}='
+                                 f'"{escape_label_value(lv)}"}} {v}')
             elif isinstance(m, Gauge):
                 lines.append(f"# TYPE {name} gauge")
                 lines.append(f"{name} {m.value}")
@@ -266,5 +302,5 @@ class Registry:
         return "\n".join(lines) + "\n"
 
 
-__all__ = ["Counter", "DEFAULT_BUCKETS", "Gauge", "Histogram", "Registry",
-           "escape_help", "escape_label_value"]
+__all__ = ["Counter", "DEFAULT_BUCKETS", "Gauge", "Histogram",
+           "LabeledCounter", "Registry", "escape_help", "escape_label_value"]

@@ -22,7 +22,19 @@ batch (1 - valid token slots / B*S — the mixed-tick padding artifact
 the disaggregated-prefill ROADMAP item wants to kill).
 
 Spans are recorded AT EXIT with (t0, t1, depth, tick); request events
-record (rid, name, t, tick, attrs). Storage is bounded by
+record (rid, name, t, tick, attrs). The clock is ``time.monotonic``,
+the one ``MetricsCollector`` stamps requests with; span and event
+times are relative to the tracer epoch, and each tick's stats entry
+also keeps its absolute start (``t0_mono_s``).
+
+With ``ObsConfig.jax_annotations`` every span opens a
+``jax.profiler.TraceAnnotation`` carrying its attributes, and every
+tick runs inside an ``engine.step`` annotation: ``step_num`` (the
+tick), ``mono_ns`` (``time.monotonic_ns()`` at entry — the profiler's
+start_ns minus it maps any engine stamp onto the profiler timeline),
+and on exit the tick's attributes, ``compiles`` among them (compiles
+during the tick, obs.compiles). ``slowest_tick`` is the stats entry
+of the window's longest tick. Storage is bounded by
 ObsConfig.max_events: past it, new entries are dropped and counted
 (``dropped``) rather than silently wrapping — a truncated trace must be
 detectable (tools/check_trace.py warns on it).
@@ -35,12 +47,13 @@ import time
 from typing import Dict, List, Optional
 
 from repro.configs.base import ObsConfig
+from repro.obs import compiles
 
 
 @dataclasses.dataclass
 class Span:
-    """One closed phase span. Times are seconds on the tracer's
-    monotonic clock (``perf_counter``), relative to the tracer epoch."""
+    """One closed phase span. Times are seconds on the tracer's clock
+    (``time.monotonic``), relative to the tracer epoch."""
     name: str
     t0: float
     t1: float
@@ -90,6 +103,7 @@ class NullTracer:
     spans: tuple = ()
     events: tuple = ()
     tick_stats: tuple = ()
+    slowest_tick = None
     dropped = 0
 
     def span(self, name, **attrs):
@@ -112,9 +126,10 @@ NULL_TRACER = NullTracer()
 
 
 class _SpanCM:
-    """Context manager recording one span on exit (enabled mode)."""
+    """Context manager recording one span on exit (enabled mode); with
+    profiler annotations on, it holds its own, attributes attached."""
 
-    __slots__ = ("tr", "name", "t0", "attrs")
+    __slots__ = ("tr", "name", "t0", "attrs", "annot")
 
     def __init__(self, tr: "Tracer", name: str, attrs: Optional[dict]):
         self.tr = tr
@@ -125,8 +140,8 @@ class _SpanCM:
         tr = self.tr
         tr._depth += 1
         if tr._annot is not None:
-            tr._annot_stack.append(tr._annot(self.name))
-            tr._annot_stack[-1].__enter__()
+            self.annot = tr._annot(self.name, **(self.attrs or {}))
+            self.annot.__enter__()
         self.t0 = tr._now() - tr.epoch
         return self
 
@@ -135,7 +150,7 @@ class _SpanCM:
         t1 = tr._now() - tr.epoch
         tr._depth -= 1
         if tr._annot is not None:
-            tr._annot_stack.pop().__exit__(*exc)
+            self.annot.__exit__(*exc)
         tr._phase_s[self.name] = tr._phase_s.get(self.name, 0.0) \
             + (t1 - self.t0)
         tr._record(Span(self.name, self.t0, t1, tr._depth, tr.n_ticks,
@@ -144,11 +159,12 @@ class _SpanCM:
 
 
 class _TickCM:
-    """Context manager for one engine tick: opens the ``tick`` span,
-    resets per-phase accumulators, and folds a tick_stats entry (host vs
-    device attribution + the engine's tick_attrs) on exit."""
+    """Context manager for one engine tick: opens the ``tick`` span (and
+    the ``engine.step`` profiler annotation), resets per-phase
+    accumulators, and folds a tick_stats entry (host vs device
+    attribution + the engine's tick_attrs + compiles) on exit."""
 
-    __slots__ = ("tr", "t0")
+    __slots__ = ("tr", "t0", "c0", "annot")
 
     def __init__(self, tr: "Tracer"):
         self.tr = tr
@@ -158,27 +174,42 @@ class _TickCM:
         tr._phase_s = {}
         tr._tick_attrs = {}
         tr._depth += 1
-        self.t0 = tr._now() - tr.epoch
+        self.c0 = compiles.total
+        if tr._annot is not None:
+            self.annot = tr._annot("engine.step", step_num=tr.n_ticks,
+                                   mono_ns=time.monotonic_ns())
+            self.annot.__enter__()
+        self.t0 = tr._now()
         return self
 
     def __exit__(self, *exc):
         tr = self.tr
-        t1 = tr._now() - tr.epoch
+        t1 = tr._now()
         tr._depth -= 1
         tick = tr.n_ticks
-        tr._record(Span("tick", self.t0, t1, tr._depth, tick, None))
+        attrs = tr._tick_attrs
+        attrs["compiles"] = compiles.total - self.c0
+        if tr._annot is not None:
+            self.annot.set_metadata(**attrs)
+            self.annot.__exit__(*exc)
+        t0 = self.t0 - tr.epoch
+        tr._record(Span("tick", t0, t1 - tr.epoch, tr._depth, tick, None))
         dur = t1 - self.t0
         device = tr._phase_s.get("device_wait", 0.0)
         entry = {
             "tick": tick,
-            "t0_s": self.t0,
+            "t0_s": t0,
+            "t0_mono_s": self.t0,
             "dur_ms": dur * 1e3,
             "device_ms": device * 1e3,
             "host_ms": max(dur - device, 0.0) * 1e3,
             "phases_ms": {k: v * 1e3 for k, v in tr._phase_s.items()},
         }
-        entry.update(tr._tick_attrs)
+        entry.update(attrs)
         tr.tick_stats.append(entry)
+        if tr.slowest_tick is None \
+                or entry["dur_ms"] > tr.slowest_tick["dur_ms"]:
+            tr.slowest_tick = entry
         tr.n_ticks = tick + 1
         return False
 
@@ -188,18 +219,18 @@ class Tracer:
 
     One per engine; not thread-safe (the engine tick loop is single-
     threaded host code). ``spans`` and ``events`` hold the raw record;
-    ``tick_stats`` is the per-tick aggregate benchmarks read
-    (host_ms/device_ms/pad waste/per-kind row counts); exporters
+    ``tick_stats`` is the per-tick aggregate (host_ms/device_ms/pad
+    waste/per-kind row counts/rows/compiles), ``slowest_tick``
+    the entry of the window's longest tick; exporters
     (repro.obs.export) turn the raw record into Perfetto/JSONL files.
     """
 
-    _now = staticmethod(time.perf_counter)
+    _now = staticmethod(time.monotonic)
 
     def __init__(self, cfg: Optional[ObsConfig] = None):
         self.cfg = cfg if cfg is not None else ObsConfig(enabled=True)
         self.enabled = True
         self._annot = None
-        self._annot_stack: List = []
         if self.cfg.jax_annotations:
             import jax.profiler
             self._annot = jax.profiler.TraceAnnotation
@@ -207,13 +238,14 @@ class Tracer:
 
     # --- lifecycle --------------------------------------------------------
     def reset(self) -> None:
-        """Fresh measurement window (benchmarks call via
-        Engine.reset_metrics after warmup). The epoch restarts so
-        exported timestamps are relative to the window."""
+        """Fresh measurement window (Engine.reset_metrics, e.g. after
+        warm-up). The epoch restarts so exported timestamps are relative
+        to the window."""
         self.epoch = self._now()
         self.spans: List[Span] = []
         self.events: List[Event] = []
         self.tick_stats: List[dict] = []
+        self.slowest_tick: Optional[dict] = None
         self.n_ticks = 0
         self.dropped = 0
         self._depth = 0

@@ -33,7 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.models import Model
-from repro.obs import make_tracer
+from repro.obs import compiles, make_tracer
 from repro.serve import kv_cache, metrics as metrics_mod, paged_kv, sampling
 from repro.serve.metrics import StepStats  # noqa: F401  (compat re-export)
 from repro.serve.runner import (DECODE, PREFILL, STOP_L, STOP_NS, VERIFY,
@@ -99,6 +99,7 @@ class Engine:
             else make_tracer(scfg.obs)
         self.metrics = metrics_mod.MetricsCollector(cfg, scfg)
         self.metrics.tracer = self.tracer
+        compiles.install()
         self.profiler = None           # obs.ServingProfiler (obs.profile)
         self._requests: Dict[int, Request] = {}
         self._rids = itertools.count()
@@ -283,15 +284,25 @@ class Engine:
 
     def step(self) -> List[int]:
         """One engine tick; returns the rids that finished this tick.
-        Under tracing (ServeConfig.obs) the whole tick runs inside a
-        ``tick`` span whose exit folds host/device attribution into
+        Always on: its wall time feeds ``engine_tick_seconds`` /
+        ``engine_tick_max_seconds``, and compiles during it count in
+        ``engine_compiles_total`` (obs.compiles). Under tracing
+        (ServeConfig.obs) the whole tick runs inside a ``tick`` span
+        whose exit folds host/device attribution into
         ``tracer.tick_stats``."""
-        with self.tracer.tick():
-            if self.scfg.paged:
-                if self._async is not None:
-                    return self._tick_paged_async()
-                return self._tick_paged()
-            return self._step_slots()
+        m = self.metrics
+        t0 = m.clock()
+        prev = compiles.attach(m.compiles)
+        try:
+            with self.tracer.tick():
+                if self.scfg.paged:
+                    if self._async is not None:
+                        return self._tick_paged_async()
+                    return self._tick_paged()
+                return self._step_slots()
+        finally:
+            compiles.attach(prev)
+            m.on_tick(m.clock() - t0)
 
     # ------------------------------------------------------------------
     # sampling plumbing (shared by both modes)
@@ -531,6 +542,7 @@ class Engine:
         with tr.span("schedule"):
             with tr.span("admit"):
                 for e in self.sched.admit():
+                    self.metrics.on_admit(e.req.rid)
                     self._seed_presence(e.slot, e.req)
                     tr.event(e.req.rid, "admitted", slot=e.slot,
                              cached=e.cached_len, replay=e.replay)
@@ -691,6 +703,15 @@ class Engine:
                     sample_pairs.append((e.slot, e.req))
         if spec is None:
             sample_pairs.extend((e.slot, e.req) for e in run_rows)
+        if tr.enabled:
+            # each row as n_tokens:ctx_before:sampled (docs/observability.md)
+            sampled = {slot for slot, _ in sample_pairs}
+            tr.tick_attrs(
+                has_prefill=bool(prefill_plan),
+                prefill_tokens=sum(v for _, _, v in prefill_plan),
+                sampled_rows=len(sample_pairs),
+                rows=";".join(f"{len(toks)}:{start}:{int(slot in sampled)}"
+                              for slot, _, toks, start in rows))
         tok_np = lp_np = None
         if sample_pairs:
             with tr.span("sample_sync", rows=len(sample_pairs)):
@@ -1442,6 +1463,7 @@ class Engine:
         self._active[req.rid] = req
         n_prompt = len(np.asarray(req.prompt))
         self.metrics.on_arrival(req.rid, n_prompt)
+        self.metrics.on_admit(req.rid)
         self.tracer.event(req.rid, "arrival", prompt_len=n_prompt)
         self.tracer.event(req.rid, "admitted", slot=slot)
         # prefill into a batch-1 temp cache, then splice that row into the

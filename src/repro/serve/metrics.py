@@ -89,6 +89,7 @@ class RequestMetrics:
     rid: int
     arrival: float
     prompt_len: int = 0
+    admitted_at: Optional[float] = None     # first handed to a slot
     first_token_at: Optional[float] = None
     last_token_at: Optional[float] = None   # inter-token gap frontier
     finished_at: Optional[float] = None
@@ -102,6 +103,12 @@ class RequestMetrics:
     spec_accepted: int = 0          # ... accepted
     spec_emitted: int = 0           # tokens committed via verify passes
     spec_verifies: int = 0          # verify passes this request rode
+
+    @property
+    def queue_wait(self) -> Optional[float]:
+        if self.admitted_at is None:
+            return None
+        return self.admitted_at - self.arrival
 
     @property
     def ttft(self) -> Optional[float]:
@@ -210,6 +217,18 @@ class MetricsCollector:
             "inter-token gaps with no prefill in flight")
         self._tpot_overlap: List[float] = []
         self._tpot_steady: List[float] = []
+        # --- admission and ticks (always on: a clock read per admission,
+        # two per tick) ---
+        self._h_qwait = r.histogram("request_queue_wait_seconds",
+                                    "arrival to first admission")
+        self._queue_wait: List[float] = []
+        self._h_tick = r.histogram("engine_tick_seconds",
+                                   "Engine.step wall time")
+        self._g_tick_max = r.gauge("engine_tick_max_seconds",
+                                   "longest Engine.step in the window")
+        self.compiles = r.labeled_counter(
+            "engine_compiles_total", "fun_name",
+            "compiles and compile-cache loads during engine ticks")
         # --- prefix cache (serve.prefix_cache) ---
         self._c_plook = r.counter("prefix_lookups_total",
                                   "admissions that consulted the index")
@@ -349,6 +368,22 @@ class MetricsCollector:
         self._c_arrive.inc()
         self.requests[rid] = RequestMetrics(rid=rid, arrival=at,
                                             prompt_len=prompt_len)
+
+    def on_admit(self, rid: int):
+        """The scheduler handed the request a slot. Only the first
+        admission is stamped: a preempted request's re-admission is
+        preemption cost, not queue wait."""
+        r = self.requests.get(rid)
+        if r is None or r.admitted_at is not None:
+            return
+        r.admitted_at = self.clock()
+        self._queue_wait.append(r.queue_wait)
+        self._h_qwait.observe(r.queue_wait)
+
+    def on_tick(self, secs: float):
+        self._h_tick.observe(secs)
+        if secs > self._g_tick_max.value:
+            self._g_tick_max.set(secs)
 
     def on_first_token(self, rid: int):
         r = self.requests[rid]
@@ -506,6 +541,9 @@ class MetricsCollector:
             "tpot_p99_steady_ms": _ms(percentile(self._tpot_steady, 99)),
             "tpot_overlap_samples": len(self._tpot_overlap),
             "tpot_steady_samples": len(self._tpot_steady),
+            # arrival -> first admission of every admitted request,
+            # seconds, in admission order
+            "queue_wait_samples": list(self._queue_wait),
             "decode_steps": self.decode_steps,
             "prefill_chunks": self.prefill_chunks,
             "evictions": self.evictions,
@@ -542,6 +580,7 @@ class MetricsCollector:
         if self.tracer is not None and self.tracer.enabled:
             out["ticks"] = self.tracer.tick_summary()
             out["phase_ms_per_tick"] = self.tracer.phase_ms_per_tick()
+            out["slowest_tick"] = self.tracer.slowest_tick
             # --- roofline attainment per width bucket (obs.profile) ---
             if self._profiler is not None:
                 out["bucket_attainment"] = self._profiler.report(

@@ -11,11 +11,12 @@ new row kind, not a new model entry.
 
 Shape discipline: per-tick token width S is bucketed — {1} for pure
 decode ticks, the prefill chunk width, and k_max+1 under speculation —
-so each bucket compiles exactly once and steady-state decode pays no
-padding. The runner owns the device cache; the engine republishes the
-host-truth ``lens`` and block tables before every step (the device never
-advances them — only the engine knows what actually committed, e.g.
-after speculative acceptance).
+so each bucket compiles exactly once (one named jit per bucket and
+prefill presence: ``serve_step_w<S>[_prefill]``) and steady-state
+decode pays no padding. The runner owns the device cache; the engine
+republishes the host-truth ``lens`` and block tables before every step
+(the device never advances them — only the engine knows what actually
+committed, e.g. after speculative acceptance).
 
 The attention read path is pluggable (``ServeConfig.attn_backend``):
 "naive" gathers blocks into a logical sequence (reference, shardable);
@@ -205,7 +206,9 @@ class ModelRunner:
 
     # --- the one step ------------------------------------------------------
     def _fn(self, width: int, has_prefill: bool):
-        """One jit per (width bucket, prefill-present) pair: no-prefill
+        """One jit per (width bucket, prefill-present) pair, named for it
+        (``serve_step_w32_prefill``, ``serve_step_w1``): a compile event
+        and the profiler's dispatch span say which bucket ran. No-prefill
         ticks — the serving steady state — compile to the pure sparse
         decode FFN and never stream the dense W_down."""
         key = (width, has_prefill)
@@ -214,10 +217,11 @@ class ModelRunner:
             mdl, bs = self.model, self.scfg.block_size
             backend = self.scfg.attn_backend
             mesh, policy = self.mesh, self.policy
+            name = f"serve_step_w{width}" + ("_prefill" if has_prefill
+                                             else "")
             if mesh is None:
-                # shared across runner instances: jit re-specializes by
-                # shape, so one cached fn covers every width bucket
-                gkey = (mdl.cfg, bs, backend, has_prefill)
+                # shared across runner instances
+                gkey = (mdl.cfg, bs, backend, width, has_prefill)
                 fn = _JIT_CACHE.get(gkey)
                 if fn is not None:
                     self._fns[key] = fn
@@ -245,11 +249,13 @@ class ModelRunner:
                         return base(params, tokens, cache, n_valid,
                                     is_prefill)
 
+                run.__name__ = run.__qualname__ = name
                 fn = jax.jit(run, out_shardings=(
                     self._repl, self._repl, self._cache_shardings))
             else:
+                run.__name__ = run.__qualname__ = name
                 fn = jax.jit(run)
-                _JIT_CACHE[(mdl.cfg, bs, backend, has_prefill)] = fn
+                _JIT_CACHE[gkey] = fn
             self._fns[key] = fn
         return fn
 
@@ -329,6 +335,8 @@ class ModelRunner:
                                         stop_len, hist0, sample_fn, bs,
                                         backend, k_ticks, k_max)
 
+            run.__name__ = run.__qualname__ = \
+                f"serve_burst_k{k_max}" + ("_sampled" if sampled else "")
             fn = jax.jit(run, donate_argnums=(1,))
             _JIT_CACHE[key] = fn
         return fn

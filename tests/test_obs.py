@@ -614,3 +614,220 @@ def test_legacy_engine_timeline_and_summary(nectar):
     assert {"tick", "device_dispatch", "sample_sync"} \
         <= {s.name for s in tr.spans}
     assert eng.metrics.summary()["ticks"]["n_ticks"] == tr.n_ticks
+
+
+# ---------------------------------------------------------------------------
+# the engine's own tick record: engine.step spans, the one clock, named
+# step executables, compile counter, admission stamps, tick gauges
+
+
+def _profiled_serve(tmp_path, cfg, params, prompts, max_new=6, **scfg_kw):
+    """Serve ``prompts`` under the CPU profiler with annotations on;
+    returns the engine, the batches its runner ran (n_valid, row_start,
+    phase) and the xplane's host events by name."""
+    from jax._src.profiler import ProfileData
+    obs = ObsConfig(enabled=True, jax_annotations=True)
+    eng = Engine(cfg, params, ServeConfig(obs=obs, **scfg_kw))
+    batches = []
+    orig = eng.runner.step
+
+    def recording(batch, *a, **k):
+        batches.append((batch.n_valid.copy(), batch.row_start.copy(),
+                        batch.phase.copy()))
+        return orig(batch, *a, **k)
+
+    eng.runner.step = recording
+    reqs = [Request(rid=eng.new_rid(), prompt=p, max_new=max_new)
+            for p in prompts]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs, max_steps=500)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+              for f in fs if f.endswith(".xplane.pb")]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        (int(e.start_ns), dict(e.stats)))
+    return eng, batches, events
+
+
+def _parse_rows(s):
+    return [tuple(int(x) for x in r.split(":")) for r in s.split(";")]
+
+
+@pytest.fixture(scope="module")
+def profiled(nectar, tmp_path_factory):
+    cfg, _, params = nectar
+    return _profiled_serve(
+        tmp_path_factory.mktemp("xplane"), cfg, params,
+        _prompts(cfg, [5, 40, 9]), paged=True, max_batch=2, max_seq=64,
+        block_size=8, prefill_chunk=16)
+
+
+def test_engine_step_spans_carry_tick_and_rows(profiled):
+    """Every tick runs inside one ``engine.step`` annotation whose stats
+    name the tick (step_num), the bucket (width, has_prefill) and the
+    rows as n_tokens:ctx_before:sampled — the rows of the batch the
+    runner executed."""
+    from repro.serve.runner import DECODE, IDLE, PREFILL
+    eng, batches, ev = profiled
+    steps = sorted(ev["engine.step"], key=lambda x: x[1]["step_num"])
+    assert [st["step_num"] for _, st in steps] \
+        == list(range(eng.tracer.n_ticks))
+    ran = [st for _, st in steps if "width" in st]
+    assert len(ran) == len(batches) > 0
+    assert any(st["has_prefill"] for st in ran)
+    assert not all(st["has_prefill"] for st in ran)
+    for st, (n_valid, start, phase) in zip(ran, batches):
+        live = phase != IDLE
+        rows = _parse_rows(st["rows"])
+        assert sorted((n, c) for n, c, _ in rows) == sorted(
+            zip(n_valid[live].tolist(), start[live].tolist()))
+        assert bool(st["has_prefill"]) == bool((phase == PREFILL).any())
+        assert st["width"] in eng.runner.buckets
+        assert st["prefill_tokens"] == int(n_valid[phase == PREFILL].sum())
+        assert st["sampled_rows"] == sum(s for _, _, s in rows)
+        # every decode row samples; only a prompt's last chunk does
+        assert int((phase == DECODE).sum()) <= st["sampled_rows"]
+        assert "compiles" in st
+    # the tracer's own tick stats hold the same rows
+    by_tick = {t["tick"]: t for t in eng.tracer.tick_stats}
+    for _, st in steps:
+        if "rows" in st:
+            assert by_tick[st["step_num"]]["rows"] == st["rows"]
+
+
+def test_engine_step_mono_ns_maps_to_profiler_clock(profiled):
+    """``start_ns - mono_ns`` is one offset for every tick of a trace:
+    the engine's monotonic stamps land on the profiler timeline."""
+    _, _, ev = profiled
+    offs = [start - st["mono_ns"] for start, st in ev["engine.step"]]
+    assert len(offs) > 3
+    assert max(offs) - min(offs) < 1_000_000
+
+
+def test_device_dispatch_span_carries_attributes(profiled):
+    """Span attributes reach the profiler: device_dispatch names its
+    width and prefill presence, sample_sync its rows."""
+    eng, _, ev = profiled
+    disp = ev["device_dispatch"]
+    assert disp and all({"width", "has_prefill"} <= set(st)
+                        for _, st in disp)
+    assert {st["width"] for _, st in disp} <= set(eng.runner.buckets)
+    assert all("rows" in st for _, st in ev["sample_sync"])
+
+
+def test_compile_of_new_bucket_is_counted_and_named(nectar):
+    """A step bucket compiled inside a tick raises engine_compiles_total
+    under the bucket's name, and the tick that compiled it says so."""
+    cfg, _, params = nectar
+    # prefill_chunk 13: a bucket no other test compiles
+    _, eng = _serve(cfg, params, _prompts(cfg, [20]), max_new=3,
+                    obs=OBS, paged=True, max_batch=2, max_seq=64,
+                    block_size=8, prefill_chunk=13)
+    reg = eng.metrics.registry
+    snap = reg.collect()
+    assert reg.value("engine_compiles_total") >= 1
+    assert snap['engine_compiles_total{fun_name="jit(serve_step_w13_'
+                'prefill)"}'] == 1
+    names = set(eng.metrics.compiles.values)
+    assert any(n.startswith("jit(serve_step_w") for n in names)
+    first = eng.tracer.tick_stats[0]
+    assert first["compiles"] >= 1 and first["width"] == 13
+    assert sum(t["compiles"] for t in eng.tracer.tick_stats) \
+        == reg.value("engine_compiles_total")
+    text = reg.prometheus_text()
+    assert "# TYPE engine_compiles_total counter" in text
+    assert 'engine_compiles_total{fun_name="jit(serve_step_w13_prefill)"}' \
+        ' 1' in text
+    # a fresh window starts the count again; warm buckets do not compile
+    eng.reset_metrics()
+    eng.run([Request(rid=eng.new_rid(), prompt=_prompts(cfg, [20])[0],
+                     max_new=3)], max_steps=100)
+    assert eng.metrics.registry.value("engine_compiles_total") == 0
+
+
+def test_queue_wait_is_admission_minus_arrival(nectar):
+    """Every request is stamped when the scheduler first admits it;
+    queue_wait_samples are admitted_at - arrival, one per request, and
+    the histogram counts them."""
+    cfg, _, params = nectar
+    # one slot: the second and third requests wait for the first
+    _, eng = _serve(cfg, params, _prompts(cfg, [5, 9, 7]), max_new=4,
+                    paged=True, max_batch=1, max_seq=64, block_size=8,
+                    prefill_chunk=16)
+    recs = sorted(eng.metrics.requests.values(), key=lambda r: r.admitted_at)
+    s = eng.metrics.summary()
+    assert s["queue_wait_samples"] == [r.admitted_at - r.arrival
+                                       for r in recs]
+    assert all(r.arrival <= r.admitted_at <= r.first_token_at for r in recs)
+    assert recs[-1].queue_wait > recs[0].queue_wait
+    h = eng.metrics.registry.collect()["request_queue_wait_seconds"]
+    assert h["count"] == 3
+
+
+def test_tick_gauges_and_slowest_tick(nectar):
+    """engine_tick_seconds counts every Engine.step, the max gauge is the
+    longest, and under tracing slowest_tick is that tick's stats entry
+    with its phases and compiles."""
+    cfg, _, params = nectar
+    _, eng = _serve(cfg, params, _prompts(cfg, [5, 30]), obs=OBS,
+                    paged=True, max_batch=2, max_seq=64, block_size=8,
+                    prefill_chunk=16)
+    reg = eng.metrics.registry
+    h = reg.collect()["engine_tick_seconds"]
+    assert h["count"] == eng.tracer.n_ticks
+    tmax = reg.value("engine_tick_max_seconds")
+    assert tmax * 1e3 >= max(t["dur_ms"] for t in eng.tracer.tick_stats)
+    slow = eng.tracer.slowest_tick
+    assert slow["dur_ms"] == max(t["dur_ms"] for t in eng.tracer.tick_stats)
+    assert slow["phases_ms"] and "compiles" in slow
+    assert eng.metrics.summary()["slowest_tick"] is slow
+    # the tracer's clock is the collector's: tick starts sit between the
+    # first arrival and the last finish
+    recs = eng.metrics.requests.values()
+    lo = min(r.arrival for r in recs)
+    hi = max(r.finished_at for r in recs)
+    assert all(lo - 1.0 <= t["t0_mono_s"] <= hi for t in eng.tracer.tick_stats)
+    eng.reset_metrics()
+    assert eng.metrics.registry.value("engine_tick_max_seconds") == 0
+    assert eng.tracer.slowest_tick is None
+
+
+def test_tick_record_without_annotations(nectar, monkeypatch):
+    """With the tracer disabled, or recording without profiler
+    annotations, no annotation is built; the tick histogram, max gauge
+    and admission stamps fill all the same."""
+    cfg, _, params = nectar
+    built = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: built.append(a))
+    for obs in (ObsConfig(), ObsConfig(enabled=True)):
+        _, eng = _serve(cfg, params, _prompts(cfg, [5, 9]), max_new=4,
+                        obs=obs, paged=True, max_batch=2, max_seq=64,
+                        block_size=8, prefill_chunk=16)
+        reg = eng.metrics.registry
+        assert reg.collect()["engine_tick_seconds"]["count"] > 0
+        assert reg.value("engine_tick_max_seconds") > 0
+        assert len(eng.metrics.summary()["queue_wait_samples"]) == 2
+    assert built == []
+
+
+def test_labeled_counter_registry_and_prometheus():
+    reg = Registry()
+    c = reg.labeled_counter("engine_compiles_total", "fun_name", "compiles")
+    assert reg.labeled_counter("engine_compiles_total", "fun_name") is c
+    c.inc('jit(a"b)')
+    c.inc("jit(c)", 2)
+    assert reg.value("engine_compiles_total") == 3
+    snap = reg.collect()
+    assert snap["engine_compiles_total"] == 3
+    assert snap['engine_compiles_total{fun_name="jit(c)"}'] == 2
+    text = reg.prometheus_text()
+    assert "# TYPE engine_compiles_total counter" in text
+    assert 'engine_compiles_total{fun_name="jit(a\\"b)"} 1' in text
