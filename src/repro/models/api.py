@@ -81,6 +81,17 @@ class Model:
                                             block_size, backend=backend,
                                             has_prefill=has_prefill)
 
+    def forward_step_grouped(self, params, dec_tokens, pf_tokens, cache,
+                             dec_valid, pf_slot, pf_start, pf_valid,
+                             block_size: int, backend: str = "naive"):
+        """A tick with prefill chunks as two row groups in one pass: a
+        width-1 decode group by slot and a chunk-wide prefill group
+        (transformer.forward_step_grouped)."""
+        with jax.default_matmul_precision(self._serving_precision):
+            return transformer.forward_step_grouped(
+                params, self.cfg, dec_tokens, pf_tokens, cache, dec_valid,
+                pf_slot, pf_start, pf_valid, block_size, backend=backend)
+
     def decode_burst(self, params, cache, tables, tok0, lens0, alive0,
                      budget, stops, stop_len, hist0, sample_fn,
                      block_size: int, backend: str, k_ticks, k_max: int):

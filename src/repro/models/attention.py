@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -206,6 +206,80 @@ def _read_paged(cache: dict, name: str, tables, n_blocks: int):
     return g.astype(jnp.float32) * s
 
 
+def _paged_slots(tables, lens, n_valid, width: int, block_size: int,
+                 n_blocks: int):
+    """Where a group's queries sit: ``gpos`` i32[B, W] absolute positions
+    lens[b]+j, and the pool coordinates (blk, off) their KV scatters to
+    (positions j >= n_valid[b], or past the table, get the sentinel
+    block ``n_blocks`` so the write drops)."""
+    MB = tables.shape[1]
+    j = jnp.arange(width)
+    gpos = lens[:, None] + j[None, :]                     # [B, W]
+    col = jnp.minimum(gpos // block_size, MB - 1)
+    blk = jnp.take_along_axis(tables, col, axis=1)        # [B, W]
+    blk = jnp.where((j[None, :] < n_valid[:, None])
+                    & (gpos // block_size < MB), blk, n_blocks)
+    return gpos, blk, gpos % block_size
+
+
+def _attend_paged(cfg: ModelConfig, q, cache: dict, tables, lens, gpos,
+                  block_size: int, backend: str, dtype):
+    """Attention of one row group's roped queries q [B, S, H, Dh] over
+    the (already written) pool ``cache``, query j of row b seeing kv
+    positions [0, gpos[b, j]]. Returns o [B, S, H*Dh]."""
+    B, S = q.shape[:2]
+    n_blocks = cache["k"].shape[0]
+    if backend == "flash":
+        from repro.kernels.decode_attn import paged_attention
+        # the Pallas interpreter is for hosts without a TPU only: on a
+        # TPU the kernel always compiles with Mosaic
+        o = paged_attention(
+            q, cache["k"], cache["v"], tables, lens, block_size=block_size,
+            interpret=jax.default_backend() != "tpu")
+        return o.reshape(B, S, cfg.n_heads * cfg.d_head).astype(dtype)
+    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
+    kg = _read_paged(cache, "k", tables, n_blocks)        # [B, MBbs, Kv, Dh]
+    vg = _read_paged(cache, "v", tables, n_blocks)
+    if S == 1:
+        scope = _kv_seq_scope(kg.shape[1])
+        if scope is not None:
+            # sequence-sharded decode (ShardingPolicy.shard_kv_seq): the
+            # gathered logical sequence shards over 'model' and each
+            # device softmaxes only its local KV slice; the partials
+            # merge with the LSE-combine collective — no device ever
+            # materializes a row's full KV (the long-context layout).
+            from repro.dist.collectives import lse_combine_decode_attention
+            o = lse_combine_decode_attention(scope, qg[:, 0], kg, vg,
+                                             lens + 1)[:, None]
+        else:
+            # single-token step: reference_attention keeps this
+            # bit-identical to the contiguous-cache decode (and
+            # GSPMD-shardable)
+            o = reference_attention(qg, kg, vg, causal=False,
+                                    kv_len=lens + 1)
+    else:
+        # per-(row, position) causal mask: kv position t visible to query
+        # j of row b iff t <= lens[b]+j. S is small, so full scores are
+        # [B, Kv, G, S, MB*bs] — same order as the reference decode path.
+        scale = jnp.asarray(cfg.d_head ** -0.5, qg.dtype)
+        s = _gqa_scores(qg * scale, kg)
+        Skv = kg.shape[1]
+        vis = jnp.arange(Skv)[None, None, :] <= gpos[:, :, None]  # [B,S,Skv]
+        s = jnp.where(vis[:, None, None], s, NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1)
+        o = jnp.moveaxis(_gqa_out(probs, vg), -2, 1).astype(dtype)
+    return o.reshape(B, S, cfg.n_heads * cfg.d_head)
+
+
+def _project_out(p, o):
+    # bit-reproducible layout (exact_tp): gather the head-sharded o (a
+    # concatenation — exact), multiply by the output-sharded wo with a
+    # fully replicated contraction dim (no psum), gather the result;
+    # identity when no exact_tp scope is active
+    o = constrain_tp_exact(o)
+    return constrain_tp_exact(o @ p["wo"])
+
+
 def attn_step_paged(p, cfg: ModelConfig, x, cos, sin, cache: dict,
                     lens: jax.Array, n_valid: jax.Array,
                     tables: jax.Array, block_size: int,
@@ -238,64 +312,56 @@ def attn_step_paged(p, cfg: ModelConfig, x, cos, sin, cache: dict,
     """
     B, S, _ = x.shape
     n_blocks = cache["k"].shape[0]
-    MB = tables.shape[1]
     q, k, v = _qkv(p, cfg, x)
     q = rope.apply_rope(q, cos, sin)
     k = rope.apply_rope(k, cos, sin)
-    j = jnp.arange(S)
-    gpos = lens[:, None] + j[None, :]                     # [B, S]
-    col = jnp.minimum(gpos // block_size, MB - 1)
-    blk = jnp.take_along_axis(tables, col, axis=1)        # [B, S]
-    blk = jnp.where((j[None, :] < n_valid[:, None])
-                    & (gpos // block_size < MB), blk, n_blocks)
-    off = gpos % block_size
+    gpos, blk, off = _paged_slots(tables, lens, n_valid, S, block_size,
+                                  n_blocks)
     new_cache = {**_store_paged(cache, "k", blk, off, k),
                  **_store_paged(cache, "v", blk, off, v)}
-    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
-    if backend == "flash":
-        from repro.kernels.decode_attn import paged_attention
-        # the Pallas interpreter is for hosts without a TPU only: on a
-        # TPU the kernel always compiles with Mosaic
-        o = paged_attention(
-            q.reshape(B, S, cfg.n_heads, cfg.d_head), new_cache["k"],
-            new_cache["v"], tables, lens, block_size=block_size,
-            interpret=jax.default_backend() != "tpu")
-        o = o.reshape(B, S, cfg.n_heads * cfg.d_head).astype(x.dtype)
-        return o @ p["wo"], new_cache
-    kg = _read_paged(new_cache, "k", tables, n_blocks)    # [B, MBbs, Kv, Dh]
-    vg = _read_paged(new_cache, "v", tables, n_blocks)
-    if S == 1:
-        scope = _kv_seq_scope(kg.shape[1])
-        if scope is not None:
-            # sequence-sharded decode (ShardingPolicy.shard_kv_seq): the
-            # gathered logical sequence shards over 'model' and each
-            # device softmaxes only its local KV slice; the partials
-            # merge with the LSE-combine collective — no device ever
-            # materializes a row's full KV (the long-context layout).
-            from repro.dist.collectives import lse_combine_decode_attention
-            o = lse_combine_decode_attention(scope, qg[:, 0], kg, vg,
-                                             lens + 1)[:, None]
-        else:
-            # single-token step: reference_attention keeps this
-            # bit-identical to the contiguous-cache decode (and
-            # GSPMD-shardable)
-            o = reference_attention(qg, kg, vg, causal=False,
-                                    kv_len=lens + 1)
-    else:
-        # per-(row, position) causal mask: kv position t visible to query
-        # j of row b iff t <= lens[b]+j. S is small, so full scores are
-        # [B, Kv, G, S, MB*bs] — same order as the reference decode path.
-        scale = jnp.asarray(cfg.d_head ** -0.5, qg.dtype)
-        s = _gqa_scores(qg * scale, kg)
-        Skv = kg.shape[1]
-        vis = jnp.arange(Skv)[None, None, :] <= gpos[:, :, None]  # [B,S,Skv]
-        s = jnp.where(vis[:, None, None], s, NEG_INF)
-        probs = jax.nn.softmax(s, axis=-1)
-        o = jnp.moveaxis(_gqa_out(probs, vg), -2, 1).astype(x.dtype)
-    o = o.reshape(B, S, cfg.n_heads * cfg.d_head)
-    # bit-reproducible layout (exact_tp): gather the head-sharded o (a
-    # concatenation — exact), multiply by the output-sharded wo with a
-    # fully replicated contraction dim (no psum), gather the result;
-    # identity when no exact_tp scope is active
-    o = constrain_tp_exact(o)
-    return constrain_tp_exact(o @ p["wo"]), new_cache
+    o = _attend_paged(cfg, q, new_cache, tables, lens, gpos, block_size,
+                      backend, x.dtype)
+    return _project_out(p, o), new_cache
+
+
+class PagedGroup(NamedTuple):
+    """One row group of a grouped step: ``width`` tokens for each of its
+    rows, row b at absolute positions lens[b]+j, n_valid[b] of them real,
+    reading and writing through tables[b] (all-sentinel when idle)."""
+    lens: jax.Array
+    n_valid: jax.Array
+    tables: jax.Array
+    width: int
+
+
+def attn_step_grouped(p, cfg: ModelConfig, x, cos, sin, cache: dict,
+                      groups, block_size: int, backend: str = "naive"):
+    """attn_step_paged over several row groups of different widths in one
+    pass: the projections run once on the packed tokens, the KV of every
+    group scatters into the pool in one store, and attention runs per
+    group at that group's own width (a width-1 decode group takes the
+    S == 1 path, a chunk-wide prefill group the causal one).
+
+    x: [1, N, d] — the groups' tokens packed in order, each group's rows
+    row-major (N = sum of rows x width); cos/sin packed the same way.
+    Returns (out [1, N, d], new_cache)."""
+    n_blocks = cache["k"].shape[0]
+    q, k, v = _qkv(p, cfg, x)
+    q = rope.apply_rope(q, cos, sin)
+    k = rope.apply_rope(k, cos, sin)
+    slots = [_paged_slots(g.tables, g.lens, g.n_valid, g.width,
+                          block_size, n_blocks) for g in groups]
+    blk = jnp.concatenate([b.reshape(-1) for _, b, _ in slots])
+    off = jnp.concatenate([o.reshape(-1) for _, _, o in slots])
+    new_cache = {**_store_paged(cache, "k", blk, off, k[0]),
+                 **_store_paged(cache, "v", blk, off, v[0])}
+    outs, i = [], 0
+    for g, (gpos, _, _) in zip(groups, slots):
+        rows = g.lens.shape[0]
+        n = rows * g.width
+        qg = q[0, i:i + n].reshape(rows, g.width, *q.shape[2:])
+        o = _attend_paged(cfg, qg, new_cache, g.tables, g.lens, gpos,
+                          block_size, backend, x.dtype)
+        outs.append(o.reshape(n, -1))
+        i += n
+    return _project_out(p, jnp.concatenate(outs)[None]), new_cache

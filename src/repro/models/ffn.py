@@ -44,7 +44,8 @@ def ffn_decode(p, cfg: ModelConfig, x):
         x, p["w_up"], p["w_down"], k=k, act="relu", w_gate=p.get("w_gate"))
 
 
-def ffn_step(p, cfg: ModelConfig, x, is_prefill, has_prefill: bool = True):
+def ffn_step(p, cfg: ModelConfig, x, is_prefill, has_prefill: bool = True,
+             n_decode: int | None = None):
     """Per-row FFN select for the unified batched step (ModelRunner):
     prefill rows take the dense path, decode/verify rows take the sparse
     decode path — in the SAME batch. x: [B, S, d]; is_prefill: bool[B].
@@ -58,6 +59,11 @@ def ffn_step(p, cfg: ModelConfig, x, is_prefill, has_prefill: bool = True):
     down-projection, not a second full FFN; each branch's expression is
     exactly ``dense_ffn`` / ``gathered_sparse_ffn``, which is what keeps
     unified-step output token-identical to the split per-phase engines.
+
+    ``n_decode`` (static) is the grouped step's split in place of the
+    per-row select: x is [1, N, d] with the decode group's n_decode
+    tokens first and the prefill group's after them; each part runs only
+    its own down-projection.
     """
     # bit-reproducible layout (exact_tp, identity off-scope): the hidden
     # activation all-gathers before the down-projection so the contraction
@@ -78,9 +84,14 @@ def ffn_step(p, cfg: ModelConfig, x, is_prefill, has_prefill: bool = True):
     with jax.named_scope("ffn_dense"):
         h = sparsity.ffn_hidden(x, p["w_up"], "relu", p.get("w_gate"))
         h = constrain_tp_exact(h)
-        down_d = sparsity.down_dense(h, p["w_down"])
+        h_d = h if n_decode is None else h[:, n_decode:]
+        down_d = sparsity.down_dense(h_d, p["w_down"])
     k = sparsity.active_fraction_to_k(cfg.d_ff, cfg.sparse_k_frac)
     with jax.named_scope("ffn_sparse"):
-        down_s = sparsity.down_sparse(h, p["w_down"], k)
+        h_s = h if n_decode is None else h[:, :n_decode]
+        down_s = sparsity.down_sparse(h_s, p["w_down"], k)
+    if n_decode is not None:
+        return constrain_tp_exact(
+            jnp.concatenate([down_s, down_d], axis=1))
     return constrain_tp_exact(
         jnp.where(is_prefill[:, None, None], down_d, down_s))

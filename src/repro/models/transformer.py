@@ -111,7 +111,9 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
     take the dense path, decode/verify rows the sparse-gather decode path
     — verify must score each position with EXACTLY the decode-step math
     (sparse gather under relu_sparse) or greedy spec output would drift
-    from the non-speculative engine.
+    from the non-speculative engine. A grouped step (ctx["groups"],
+    forward_step_grouped) selects by group instead: x is [1, N, d], the
+    decode group's ctx["n_decode"] tokens first.
 
     The ``jax.named_scope`` annotations ("attn", "ffn_dense",
     "ffn_sparse" inside ffn_step, "logits" in forward_step) are the
@@ -130,10 +132,16 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
         with jax.named_scope("attn"):
             h = constrain_tp_exact(layers.rms_norm(x, p["norm1"],
                                                    cfg.norm_eps))
-            a, new_cache = attention.attn_step_paged(
-                p["attn"], cfg, h, ctx["cos"], ctx["sin"], cache,
-                ctx["lens"], ctx["n_valid"], ctx["tables"],
-                ctx["block_size"], backend=ctx["backend"])
+            if "groups" in ctx:
+                a, new_cache = attention.attn_step_grouped(
+                    p["attn"], cfg, h, ctx["cos"], ctx["sin"], cache,
+                    ctx["groups"], ctx["block_size"],
+                    backend=ctx["backend"])
+            else:
+                a, new_cache = attention.attn_step_paged(
+                    p["attn"], cfg, h, ctx["cos"], ctx["sin"], cache,
+                    ctx["lens"], ctx["n_valid"], ctx["tables"],
+                    ctx["block_size"], backend=ctx["backend"])
             x = x + a
         h = constrain_tp_exact(layers.rms_norm(x, p["norm2"],
                                                cfg.norm_eps))
@@ -141,8 +149,9 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
             with jax.named_scope("ffn_dense"):
                 y, _ = moe.moe_forward(p["moe"], cfg, h)
         else:
-            y = ffn.ffn_step(p["ffn"], cfg, h, ctx["is_prefill"],
-                             has_prefill=ctx["has_prefill"])
+            y = ffn.ffn_step(p["ffn"], cfg, h, ctx.get("is_prefill"),
+                             has_prefill=ctx["has_prefill"],
+                             n_decode=ctx.get("n_decode"))
         return x + y, new_cache
     raise ValueError(f"paged step requires attention blocks, got {kind!r}")
 
@@ -460,12 +469,74 @@ def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
     Returns (logits [B, S, V] — or [B, S, nc, V] for codebook models —
     and the updated cache).
     """
+    x, cos, sin = _step_inputs(params, cfg, tokens, cache["lens"])
+    n_blocks = jax.tree.leaves(cache["units"])[0].shape[1]
+    tables = jnp.where(n_valid[:, None] > 0, cache["block_tables"],
+                       n_blocks)
+    ctx = {"cos": cos, "sin": sin, "lens": cache["lens"],
+           "n_valid": n_valid, "is_prefill": is_prefill,
+           "has_prefill": has_prefill, "tables": tables,
+           "block_size": block_size, "backend": backend,
+           "shared_params": params.get("shared")}
+    return _paged_stack(params, cfg, x, cache, ctx)
+
+
+def forward_step_grouped(params, cfg: ModelConfig, dec_tokens, pf_tokens,
+                         cache, dec_valid, pf_slot, pf_start, pf_valid,
+                         block_size: int, backend: str = "naive"):
+    """forward_step for a tick that carries prefill chunks, without
+    padding decode rows to the chunk: two row groups in ONE pass over the
+    layers.
+
+      * decode group: dec_tokens [B, 1], row b at slot b (position
+        cache["lens"][b]); dec_valid[b] is 1 for a decode row and 0 for
+        every other slot (idle here, sentinel tables);
+      * prefill group: pf_tokens [P, C], row i for slot pf_slot[i]
+        (an id >= B pads the group: sentinel tables, nothing written) at
+        positions pf_start[i] + j, pf_valid[i] of them real.
+
+    Norms, projections and the FFN run once on the B + P*C tokens packed
+    together; attention runs per group at the group's width; both
+    groups' KV lands in the pool in one store. The math of each row is
+    that of forward_step's. Returns (decode logits [B, 1, V], prefill
+    logits [P, C, V] — with an nc axis before V for codebook models —
+    and the updated cache).
+    """
+    B, P, C = dec_tokens.shape[0], pf_tokens.shape[0], pf_tokens.shape[1]
+    xd, cd, sd = _step_inputs(params, cfg, dec_tokens, cache["lens"])
+    xp, cp, sp = _step_inputs(params, cfg, pf_tokens, pf_start)
+
+    def pack(a, b):
+        return jnp.concatenate([a.reshape((1, -1) + a.shape[2:]),
+                                b.reshape((1, -1) + b.shape[2:])], axis=1)
+
+    n_blocks = jax.tree.leaves(cache["units"])[0].shape[1]
+    tables = cache["block_tables"]
+    pf_tables = jnp.take(tables, pf_slot, axis=0, mode="fill",
+                         fill_value=n_blocks)
+    groups = (
+        attention.PagedGroup(
+            cache["lens"], dec_valid,
+            jnp.where(dec_valid[:, None] > 0, tables, n_blocks), 1),
+        attention.PagedGroup(
+            pf_start, pf_valid,
+            jnp.where(pf_valid[:, None] > 0, pf_tables, n_blocks), C))
+    ctx = {"cos": pack(cd, cp), "sin": pack(sd, sp), "groups": groups,
+           "n_decode": B, "has_prefill": True, "block_size": block_size,
+           "backend": backend, "shared_params": params.get("shared")}
+    logits, cache = _paged_stack(params, cfg, pack(xd, xp), cache, ctx)
+    return (logits[0, :B].reshape((B, 1) + logits.shape[2:]),
+            logits[0, B:].reshape((P, C) + logits.shape[2:]), cache)
+
+
+def _step_inputs(params, cfg: ModelConfig, tokens, lens):
+    """Embedded tokens [B, S, d] of rows starting at positions ``lens``,
+    and their rotary tables."""
     # exact_tp: the embedding gather lands d-sharded (the table's output
     # dim is partitioned); gather it back to replicated — an exact
     # concatenation — before the residual stream starts
     x = constrain_tp_exact(_embed_inputs(params, cfg, {"tokens": tokens}))
     B, S = x.shape[0], x.shape[1]
-    lens = cache["lens"]
     positions = lens[:, None] + jnp.arange(S)[None, :]
     if cfg.mrope:
         positions = jnp.broadcast_to(positions[None], (3, B, S))
@@ -473,14 +544,12 @@ def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
     if cfg.pos_emb == "sin":
         p1 = positions[0] if cfg.mrope else positions
         x = x + layers.sinusoidal_positions(p1, cfg.d_model).astype(x.dtype)
+    return x, cos, sin
 
-    n_blocks = jax.tree.leaves(cache["units"])[0].shape[1]
-    tables = jnp.where(n_valid[:, None] > 0, cache["block_tables"],
-                       n_blocks)
-    ctx = {"cos": cos, "sin": sin, "lens": lens, "n_valid": n_valid,
-           "is_prefill": is_prefill, "has_prefill": has_prefill,
-           "tables": tables, "block_size": block_size, "backend": backend,
-           "shared_params": params.get("shared")}
+
+def _paged_stack(params, cfg: ModelConfig, x, cache, ctx):
+    """The layer stack of a paged step over residual ``x`` and its final
+    logits; returns (logits, the updated cache)."""
     unit = cfg.pattern_unit()
 
     def unit_body(x, xs):
@@ -516,7 +585,7 @@ def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
         x = constrain_tp_exact(
             layers.rms_norm(x, params["final_norm"], cfg.norm_eps))
         logits = project_logits(params, cfg, x)
-    return logits, {"lens": lens,
+    return logits, {"lens": cache["lens"],
                     "block_tables": cache["block_tables"],
                     "units": new_units}
 

@@ -2,7 +2,7 @@
 
 Every compile of a jitted function, and every load of one from the
 persistent compile cache, fires JAX's backend-compile event with the
-function's name (``fun_name``, e.g. ``jit(serve_step_w32_prefill)``).
+function's name (``fun_name``, e.g. ``jit(serve_step_w1_prefill_p1x32)``).
 The listener counts it in ``total`` — the process-wide count the
 tracer diffs across each tick (the tick's ``compiles`` stat) — and,
 while an engine tick runs, in that engine's ``engine_compiles_total``

@@ -25,8 +25,9 @@ Both sources therefore run against an UNROLLED twin of the step
 (``dataclasses.replace(cfg, unroll=True)`` — transformer.forward_step's
 loop-free branch, same math and cache layout): the totals become exact
 and every unit's dots appear individually in the text. The twin
-compiles once per (width bucket, has_prefill) pair and only when
-profiling is on (ObsConfig.profile); serving executables are untouched.
+compiles once per (width bucket, has_prefill, prefill_bucket) step
+shape and only when profiling is on (ObsConfig.profile); serving
+executables are untouched.
 
 The per-scope split covers dot (matmul/einsum) cost only — elementwise
 ops, norms, gathers land in "other" (total minus attributed). Tier-1
@@ -135,11 +136,12 @@ def executable_totals(compiled) -> Dict[str, float]:
 
 @dataclasses.dataclass
 class StepCost:
-    """Static cost of ONE execution of a (width, has_prefill) bucket of
-    the unified step: XLA totals plus the per-named_scope dot split
-    ("other" holds the unattributed remainder, floored at 0 — the split
-    always sums to the total by construction, and ``attributed_frac``
-    reports how much of it the scoped dots genuinely cover)."""
+    """Static cost of ONE execution of a (width, has_prefill,
+    prefill_bucket) step shape: XLA totals plus the per-named_scope dot
+    split ("other" holds the unattributed remainder, floored at 0 — the
+    split always sums to the total by construction, and
+    ``attributed_frac`` reports how much of it the scoped dots genuinely
+    cover)."""
 
     width: int
     has_prefill: bool
@@ -159,34 +161,38 @@ def _sds(tree):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
 
-def step_cost(runner, width: int, has_prefill: bool) -> StepCost:
-    """Lower + compile the unrolled twin of ``runner``'s (width,
-    has_prefill) step bucket from abstract args (no device work beyond
-    the compile) and extract its static cost."""
+def step_cost(runner, width: int, has_prefill: bool,
+              prefill_bucket: int = 0) -> StepCost:
+    """Lower + compile the unrolled twin of ``runner``'s step of this
+    shape from abstract args (no device work beyond the compile) and
+    extract its static cost."""
+    from repro.serve.runner import grouped_step, uniform_step
     cfg = runner.cfg
     scfg = runner.scfg
     twin_cfg = dataclasses.replace(cfg, unroll=True)
     twin = type(runner.model)(twin_cfg)
     bs, backend = scfg.block_size, scfg.attn_backend
-
-    def run(params, tokens, cache, n_valid, is_prefill):
-        logits, cache = twin.forward_step(
-            params, tokens, cache, n_valid, is_prefill, bs,
-            backend=backend, has_prefill=has_prefill)
-        idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
-        idx = idx.reshape((-1,) + (1,) * (logits.ndim - 1))
-        last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-        return logits, last, cache
-
     B = scfg.max_batch
-    tok_shape = (B, width, cfg.n_codebooks) if cfg.n_codebooks \
-        else (B, width)
-    compiled = jax.jit(run).lower(
-        _sds(runner.params),
-        jax.ShapeDtypeStruct(tok_shape, jnp.int32),
-        _sds(runner.cache),
-        jax.ShapeDtypeStruct((B,), jnp.int32),
-        jax.ShapeDtypeStruct((B,), jnp.bool_)).compile()
+
+    def toks(rows, w):
+        shape = (rows, w, cfg.n_codebooks) if cfg.n_codebooks \
+            else (rows, w)
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def ints(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32)
+
+    if prefill_bucket:
+        P = prefill_bucket
+        lowered = jax.jit(grouped_step(twin, bs, backend)).lower(
+            _sds(runner.params), toks(B, 1), toks(P, width),
+            _sds(runner.cache), ints(B), ints(P), ints(P), ints(P))
+    else:
+        lowered = jax.jit(uniform_step(twin, bs, backend,
+                                       has_prefill)).lower(
+            _sds(runner.params), toks(B, width), _sds(runner.cache),
+            ints(B), jax.ShapeDtypeStruct((B,), jnp.bool_))
+    compiled = lowered.compile()
     totals = executable_totals(compiled)
     by_scope = parse_hlo_dot_costs(compiled.as_text())
     attributed = sum(v["flops"] for v in by_scope.values())

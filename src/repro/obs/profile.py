@@ -3,16 +3,16 @@
 NeCTAr's evaluation judges every unit against its roofline — decode is
 weight-bandwidth-bound, prefill compute-bound — and reports efficiency
 (GOPs/W), not stopwatch time. This module is the serving-side analogue:
-per compiled width bucket of ``ModelRunner.step`` (decode=1, the
-prefill chunk, k_max+1 verify) it joins
+per compiled step shape of ``ModelRunner.step`` (decode=1, each grouped
+prefill bucket, k_max+1 verify) it joins
 
   * STATIC cost — FLOPs / bytes of the bucket's executable, total and
     per ``jax.named_scope`` (obs.costmodel: unrolled-twin
     ``cost_analysis()`` + HLO-text dot attribution), plus the sampler
     executable as the "sample" scope — with
   * MEASURED time — the tracer's per-tick fenced ``device_wait`` spans
-    (``tick_stats`` grouped by width and prefill-presence, exactly the
-    runner's jit key)
+    (``tick_stats`` grouped by width, prefill presence and prefill
+    bucket, exactly the runner's jit key)
 
 and emits per-bucket achieved GFLOP/s, GB/s, arithmetic intensity, and
 roofline ATTAINMENT: ``max(flops/peak, bytes/bw) / measured_s``, i.e.
@@ -48,9 +48,13 @@ _COLS = (
 )
 
 
-def bucket_label(width: int, has_prefill: bool) -> str:
-    """Human name for a (width, has_prefill) jit bucket: "decode",
-    "prefill<W>", "verify<K+1>" — stable keys for gauges/baselines."""
+def bucket_label(width: int, has_prefill: bool,
+                 prefill_bucket: int = 0) -> str:
+    """Human name for a (width, has_prefill, prefill_bucket) step shape:
+    "decode", "mixed_p<P>" (grouped), "prefill<W>", "verify<K+1>" —
+    stable keys for gauges/baselines."""
+    if prefill_bucket:
+        return f"mixed_p{prefill_bucket}"
     if has_prefill:
         return f"prefill{width}"
     return "decode" if width == 1 else f"verify{width}"
@@ -72,13 +76,13 @@ class ServingProfiler:
         self._sample: Optional[Dict[str, float]] = None
 
     # --- static side -----------------------------------------------------
-    def static_cost(self, width: int, has_prefill: bool
-                    ) -> costmodel.StepCost:
-        key = (width, has_prefill)
+    def static_cost(self, width: int, has_prefill: bool,
+                    prefill_bucket: int = 0) -> costmodel.StepCost:
+        key = (width, has_prefill, prefill_bucket)
         c = self._costs.get(key)
         if c is None:
             c = self._costs[key] = costmodel.step_cost(
-                self.runner, width, has_prefill)
+                self.runner, width, has_prefill, prefill_bucket)
         return c
 
     def sample_cost(self) -> Dict[str, float]:
@@ -88,10 +92,11 @@ class ServingProfiler:
                 scfg.max_batch, cfg.vocab, cfg.n_codebooks)
         return self._sample
 
-    def _bucket_totals(self, width: int, has_prefill: bool):
+    def _bucket_totals(self, width: int, has_prefill: bool,
+                       prefill_bucket: int = 0):
         """(flops, bytes, by_scope) of one tick of this bucket: the step
         executable plus the per-tick sampler call."""
-        cost = self.static_cost(width, has_prefill)
+        cost = self.static_cost(width, has_prefill, prefill_bucket)
         samp = self.sample_cost()
         by_scope = {k: dict(v) for k, v in cost.by_scope.items()}
         by_scope["sample"] = {"flops": samp["flops"],
@@ -102,16 +107,16 @@ class ServingProfiler:
     # --- measured join ---------------------------------------------------
     @staticmethod
     def _grouped(tick_stats: Iterable[dict]) -> Dict[tuple, List[float]]:
-        """device_ms samples per (width, has_prefill) — the runner's jit
-        key, recovered from each tick's recorded attrs. Ticks that ran
-        no device step (width absent or zero device time) don't belong
-        to any bucket."""
+        """device_ms samples per (width, has_prefill, prefill_bucket) —
+        the runner's jit key, recovered from each tick's recorded attrs.
+        Ticks that ran no device step (width absent or zero device time)
+        don't belong to any bucket."""
         groups: Dict[tuple, List[float]] = {}
         for t in tick_stats:
             w = t.get("width")
             if not w or t.get("device_ms", 0.0) <= 0.0:
                 continue
-            key = (int(w), bool(t.get("rows_prefill", 0)))
+            key = _step_key(t)
             groups.setdefault(key, []).append(float(t["device_ms"]))
         return groups
 
@@ -120,8 +125,8 @@ class ServingProfiler:
         attainment formula. Empty when nothing was measured (profiling
         needs tracing's fenced device_wait spans)."""
         rows = []
-        for (w, hp), dms in sorted(self._grouped(tick_stats).items()):
-            flops, byts, by_scope = self._bucket_totals(w, hp)
+        for (w, hp, pb), dms in sorted(self._grouped(tick_stats).items()):
+            flops, byts, by_scope = self._bucket_totals(w, hp, pb)
             dev_ms = sum(dms) / len(dms)
             dev_s = dev_ms / 1e3
             terms = hw.roofline_terms(flops, byts, 0.0, self.n_chips,
@@ -132,7 +137,7 @@ class ServingProfiler:
             scoped = sum(v["flops"] for k, v in by_scope.items()
                          if k != "other")
             rows.append({
-                "bucket": bucket_label(w, hp),
+                "bucket": bucket_label(w, hp, pb),
                 "width": w,
                 "has_prefill": hp,
                 "ticks": len(dms),
@@ -183,8 +188,7 @@ class ServingProfiler:
             dev_ms = t.get("device_ms", 0.0)
             if not w or dev_ms <= 0.0:
                 continue
-            flops, byts, _ = self._bucket_totals(
-                int(w), bool(t.get("rows_prefill", 0)))
+            flops, byts, _ = self._bucket_totals(*_step_key(t))
             dev_s = dev_ms / 1e3
             terms = hw.roofline_terms(flops, byts, 0.0, self.n_chips,
                                       chip=self.chip)
@@ -194,6 +198,12 @@ class ServingProfiler:
             out.append(("roofline_attainment", t0,
                         min(1.0, terms["step_s_lower_bound"] / dev_s)))
         return out
+
+
+def _step_key(t: dict) -> tuple:
+    """A tick's (width, has_prefill, prefill_bucket) from its attrs."""
+    return (int(t["width"]), bool(t.get("rows_prefill", 0)),
+            int(t.get("prefill_bucket", 0)))
 
 
 def attainment_table(rows: List[dict]) -> str:

@@ -673,7 +673,12 @@ class Engine:
             if cow:
                 self.runner.copy_blocks(cow)
             max_valid = max(len(r[2]) for r in rows)
-            batch = self.runner.new_batch(max_valid, self.pool.tables())
+            # prefill rows and no verify row: a grouped step, decode rows
+            # at width 1 beside a chunk-wide prefill group
+            verify = spec is not None and bool(run_rows)
+            batch = self.runner.new_batch(
+                max_valid, self.pool.tables(),
+                n_prefill=0 if verify else len(prefill_plan))
             for slot, phase, toks, start in rows:
                 batch.add_row(slot, phase, toks, start)
             valid_tokens = sum(len(r[2]) for r in rows)
@@ -681,9 +686,14 @@ class Engine:
             # max valid length: the device executes the padded bucket
             # shape, so pad_waste must charge bucket padding too, and
             # the roofline profiler joins tick time to static cost by
-            # exactly this (width, has_prefill) jit key
+            # exactly this (width, has_prefill, prefill_bucket) jit key
             width = batch.tokens.shape[1]
-            denom = self.scfg.max_batch * width
+            P = batch.prefill_bucket
+            denom = self.scfg.max_batch + P * width if P \
+                else self.scfg.max_batch * width
+            if P:
+                self.metrics.on_mixed_tick(P)
+                tr.tick_attrs(prefill_bucket=P, prefill_width=width)
             tr.tick_attrs(
                 rows_prefill=len(prefill_plan),
                 rows_decode=len(run_rows) if spec is None else 0,

@@ -229,6 +229,10 @@ class MetricsCollector:
         self.compiles = r.labeled_counter(
             "engine_compiles_total", "fun_name",
             "compiles and compile-cache loads during engine ticks")
+        self._c_mixed = r.labeled_counter(
+            "engine_mixed_ticks_total", "prefill_bucket",
+            "grouped ticks (decode rows at width 1 beside a prefill "
+            "group), by the prefill group's row bucket")
         # --- prefix cache (serve.prefix_cache) ---
         self._c_plook = r.counter("prefix_lookups_total",
                                   "admissions that consulted the index")
@@ -379,6 +383,9 @@ class MetricsCollector:
         r.admitted_at = self.clock()
         self._queue_wait.append(r.queue_wait)
         self._h_qwait.observe(r.queue_wait)
+
+    def on_mixed_tick(self, prefill_bucket: int):
+        self._c_mixed.inc(str(prefill_bucket))
 
     def on_tick(self, secs: float):
         self._h_tick.observe(secs)

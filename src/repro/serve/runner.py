@@ -9,14 +9,20 @@ rows, and K+1 verify rows in the same fixed-shape batch, so decode never
 stalls behind prefill ticks (continuous batching) and new phases are a
 new row kind, not a new model entry.
 
-Shape discipline: per-tick token width S is bucketed — {1} for pure
-decode ticks, the prefill chunk width, and k_max+1 under speculation —
-so each bucket compiles exactly once (one named jit per bucket and
-prefill presence: ``serve_step_w<S>[_prefill]``) and steady-state
-decode pays no padding. The runner owns the device cache; the engine
-republishes the host-truth ``lens`` and block tables before every step
-(the device never advances them — only the engine knows what actually
-committed, e.g. after speculative acceptance).
+Shape discipline: three kinds of tick, each compiled once per shape.
+A decode tick runs every row at width 1 (``serve_step_w1``). A tick
+that carries prefill chunks and no verify row is grouped
+(``serve_step_w1_prefill_p<P>x<C>``): a width-1 decode group of
+``max_batch`` rows, one per slot, and a prefill group of P rows of the
+chunk width C, P the number of prefill rows rounded up to a power of four
+capped at ``max_batch`` — so decode rows are never padded to the chunk,
+and the first grouped tick builds every P at once. Ticks with verify
+rows run every row at the widest row's bucket (k_max+1, or the chunk
+width when prefill rows ride along: ``serve_step_w<S>[_prefill]``). The
+runner owns the device cache; the engine republishes the host-truth
+``lens`` and block tables before every step (the device never advances
+them — only the engine knows what actually committed, e.g. after
+speculative acceptance).
 
 The attention read path is pluggable (``ServeConfig.attn_backend``):
 "naive" gathers blocks into a logical sequence (reference, shardable);
@@ -42,7 +48,7 @@ LSE-combine collective (dist.collectives).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +79,77 @@ STOP_L = 4
 STOP_NS = 2
 
 
+def prefill_buckets(max_batch: int) -> List[int]:
+    """Row counts a grouped step's prefill group compiles for: powers of
+    four below ``max_batch``, then ``max_batch``. Each bucket is one more
+    executable to lower at set-up (most of a second at Qwen3-4B on a
+    v5e host), against about 1.3 ms of device time per padded prefill
+    row in a tick: powers of two would double the set-up for little."""
+    out, p = [], 1
+    while p < max_batch:
+        out.append(p)
+        p *= 4
+    return out + [max_batch]
+
+
+def uniform_step(model, block_size: int, backend: str, has_prefill: bool):
+    """The step function of a uniform tick (every row at one width):
+    (params, tokens, cache, n_valid, is_prefill) -> (logits, last-valid
+    logits per row, cache)."""
+    def run(params, tokens, cache, n_valid, is_prefill):
+        logits, cache = model.forward_step(
+            params, tokens, cache, n_valid, is_prefill, block_size,
+            backend=backend, has_prefill=has_prefill)
+        idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
+        idx = idx.reshape((-1,) + (1,) * (logits.ndim - 1))
+        last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
+        return logits, last, cache
+    return run
+
+
+def grouped_step(model, block_size: int, backend: str):
+    """The step function of a grouped tick (Model.forward_step_grouped):
+    (params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot, pf_start,
+    pf_valid) -> (decode logits, prefill logits, last-valid logits by
+    slot, cache). A prefill row's last logits land in its slot's row; a
+    padding row's slot id is out of range and its row drops."""
+    def run(params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot,
+            pf_start, pf_valid):
+        dec, pf, cache = model.forward_step_grouped(
+            params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot,
+            pf_start, pf_valid, block_size, backend=backend)
+        idx = jnp.clip(pf_valid - 1, 0, pf.shape[1] - 1)
+        idx = idx.reshape((-1,) + (1,) * (pf.ndim - 1))
+        pf_last = jnp.take_along_axis(pf, idx, axis=1)[:, 0]
+        last = dec[:, 0].at[pf_slot].set(pf_last, mode="drop")
+        return dec, pf, last, cache
+    return run
+
+
+def _grouped_args(batch: "StepBatch", pf_slots: np.ndarray):
+    """A grouped batch's per-slot rows as the two groups' device inputs:
+    the decode group by slot, the prefill rows ``pf_slots`` packed first
+    into the prefill group and the rest of it padded with slot id B."""
+    B = batch.tokens.shape[0]
+    P = batch.prefill_bucket
+    n = len(pf_slots)
+    dec = batch.phase == DECODE
+    dec_tokens = np.where(
+        dec.reshape((B,) + (1,) * (batch.tokens.ndim - 1)),
+        batch.tokens[:, :1], 0)
+    pf_tokens = np.zeros((P,) + batch.tokens.shape[1:], np.int32)
+    pf_tokens[:n] = batch.tokens[pf_slots]
+    slot = np.full((P,), B, np.int32)
+    slot[:n] = pf_slots
+    start = np.zeros((P,), np.int32)
+    start[:n] = batch.row_start[pf_slots]
+    valid = np.zeros((P,), np.int32)
+    valid[:n] = batch.n_valid[pf_slots]
+    return tuple(jnp.asarray(a) for a in (
+        dec_tokens, pf_tokens, np.where(dec, batch.n_valid, 0), slot,
+        start, valid))
+
+
 @dataclasses.dataclass
 class StepBatch:
     """Host-side description of one unified step: flat tokens plus a
@@ -84,24 +161,31 @@ class StepBatch:
     row's first token (its committed context length; the prefill frontier
     for PREFILL rows). phase[b] routes per-row math: PREFILL rows use the
     dense FFN, DECODE/VERIFY the sparse decode path; IDLE rows are fully
-    masked (sentinel tables, garbage logits)."""
+    masked (sentinel tables, garbage logits).
+
+    ``prefill_bucket`` P > 0 makes the step grouped (S is then the chunk
+    width): the runner runs the DECODE rows as a width-1 group and the
+    PREFILL rows as a [P, S] group, and never pads a decode row to S."""
 
     tokens: np.ndarray
     row_start: np.ndarray
     n_valid: np.ndarray
     phase: np.ndarray
     tables: np.ndarray
+    prefill_bucket: int = 0
 
     @classmethod
     def empty(cls, max_batch: int, width: int, tables: np.ndarray,
-              n_codebooks: int = 0) -> "StepBatch":
+              n_codebooks: int = 0, prefill_bucket: int = 0
+              ) -> "StepBatch":
         shape = (max_batch, width, n_codebooks) if n_codebooks \
             else (max_batch, width)
         return cls(tokens=np.zeros(shape, np.int32),
                    row_start=np.zeros((max_batch,), np.int32),
                    n_valid=np.zeros((max_batch,), np.int32),
                    phase=np.full((max_batch,), IDLE, np.int32),
-                   tables=np.asarray(tables, np.int32))
+                   tables=np.asarray(tables, np.int32),
+                   prefill_bucket=prefill_bucket)
 
     def add_row(self, slot: int, phase: int, tokens, start: int) -> None:
         toks = np.asarray(tokens, np.int32)
@@ -122,9 +206,20 @@ class StepOutput:
 
     logits: jax.Array          # [B, S, V(, nc x V for codebooks)]
     last_logits: jax.Array     # [B, V] / [B, nc, V]
+    # grouped steps: ``logits`` is the decode group's [B, 1, V]; the
+    # prefill group's [P, C, V] and the slot of each of its rows
+    prefill_logits: Optional[jax.Array] = None
+    prefill_slots: Optional[np.ndarray] = None
     _np: Optional[np.ndarray] = None
+    _pf_np: Optional[np.ndarray] = None
 
     def row_logits(self, slot: int) -> np.ndarray:
+        if self.prefill_slots is not None:
+            hit = np.flatnonzero(self.prefill_slots == slot)
+            if hit.size:
+                if self._pf_np is None:
+                    self._pf_np = np.asarray(self.prefill_logits)
+                return self._pf_np[hit[0]]
         if self._np is None:
             self._np = np.asarray(self.logits)
         return self._np[slot]
@@ -185,7 +280,9 @@ class ModelRunner:
         self.buckets = sorted({1, scfg.prefill_chunk}
                               | ({scfg.spec.k_max + 1}
                                  if scfg.spec is not None else set()))
+        self.prefill_buckets = prefill_buckets(scfg.max_batch)
         self._fns: Dict[tuple, callable] = {}
+        self._grouped_built = False
         self.n_steps = 0            # device steps dispatched (async
         #                             engines add burst iterations on top)
 
@@ -199,42 +296,53 @@ class ModelRunner:
         self.buckets.sort()
         return max_valid
 
-    def new_batch(self, max_valid: int, tables: np.ndarray) -> StepBatch:
+    def prefill_bucket_for(self, n_prefill: int) -> int:
+        """Smallest prefill-group bucket holding ``n_prefill`` rows."""
+        return next(p for p in self.prefill_buckets if p >= n_prefill)
+
+    def new_batch(self, max_valid: int, tables: np.ndarray,
+                  n_prefill: int = 0) -> StepBatch:
+        """An empty batch for rows of at most ``max_valid`` tokens. With
+        ``n_prefill`` > 0 (the tick's prefill rows, when it has no verify
+        row) the batch is grouped: chunk-wide, decode rows at width 1."""
+        if n_prefill:
+            return StepBatch.empty(
+                self.scfg.max_batch, self.scfg.prefill_chunk, tables,
+                n_codebooks=self.cfg.n_codebooks,
+                prefill_bucket=self.prefill_bucket_for(n_prefill))
         return StepBatch.empty(self.scfg.max_batch,
                                self.width_for(max_valid), tables,
                                n_codebooks=self.cfg.n_codebooks)
 
     # --- the one step ------------------------------------------------------
-    def _fn(self, width: int, has_prefill: bool):
-        """One jit per (width bucket, prefill-present) pair, named for it
-        (``serve_step_w32_prefill``, ``serve_step_w1``): a compile event
-        and the profiler's dispatch span say which bucket ran. No-prefill
-        ticks — the serving steady state — compile to the pure sparse
-        decode FFN and never stream the dense W_down."""
-        key = (width, has_prefill)
+    def _fn(self, width: int, has_prefill: bool, prefill_bucket: int = 0):
+        """One jit per step shape, named for it: ``serve_step_w1``,
+        ``serve_step_w32_prefill`` (every row at width 32), or
+        ``serve_step_w1_prefill_p4x32`` (a grouped tick: decode rows at
+        width 1, four prefill rows of 32). A compile event and the
+        profiler's dispatch span say which shape ran. No-prefill ticks —
+        the serving steady state — compile to the pure sparse decode FFN
+        and never stream the dense W_down."""
+        key = (width, has_prefill, prefill_bucket)
         fn = self._fns.get(key)
         if fn is None:
             mdl, bs = self.model, self.scfg.block_size
             backend = self.scfg.attn_backend
             mesh, policy = self.mesh, self.policy
-            name = f"serve_step_w{width}" + ("_prefill" if has_prefill
-                                             else "")
+            if prefill_bucket:
+                name = f"serve_step_w1_prefill_p{prefill_bucket}x{width}"
+                run = grouped_step(mdl, bs, backend)
+            else:
+                name = f"serve_step_w{width}" + ("_prefill" if has_prefill
+                                                 else "")
+                run = uniform_step(mdl, bs, backend, has_prefill)
             if mesh is None:
                 # shared across runner instances
-                gkey = (mdl.cfg, bs, backend, width, has_prefill)
+                gkey = (mdl.cfg, bs, backend) + key
                 fn = _JIT_CACHE.get(gkey)
                 if fn is not None:
                     self._fns[key] = fn
                     return fn
-
-            def run(params, tokens, cache, n_valid, is_prefill):
-                logits, cache = mdl.forward_step(
-                    params, tokens, cache, n_valid, is_prefill, bs,
-                    backend=backend, has_prefill=has_prefill)
-                idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
-                idx = idx.reshape((-1,) + (1,) * (logits.ndim - 1))
-                last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-                return logits, last, cache
 
             if mesh is not None:
                 # trace under the activation-sharding scope (model code
@@ -244,20 +352,42 @@ class ModelRunner:
                 # chain read them host-side.
                 base = run
 
-                def run(params, tokens, cache, n_valid, is_prefill):
+                def run(*args):
                     with shd.activation_sharding_scope(mesh, policy):
-                        return base(params, tokens, cache, n_valid,
-                                    is_prefill)
+                        return base(*args)
 
                 run.__name__ = run.__qualname__ = name
+                n_logits = 3 if prefill_bucket else 2
                 fn = jax.jit(run, out_shardings=(
-                    self._repl, self._repl, self._cache_shardings))
+                    (self._repl,) * n_logits + (self._cache_shardings,)))
             else:
                 run.__name__ = run.__qualname__ = name
                 fn = jax.jit(run)
                 _JIT_CACHE[gkey] = fn
             self._fns[key] = fn
         return fn
+
+    def _build_grouped(self) -> None:
+        """Build the grouped executable of every prefill bucket, each run
+        once on an all-idle batch (every write drops at the sentinel, the
+        pool is left as it was): the first tick with a prefill row pays
+        for all of them, and a later tick with more prefill rows compiles
+        nothing."""
+        B, C = self.scfg.max_batch, self.scfg.prefill_chunk
+        nc = self.cfg.n_codebooks
+        n_blocks = jax.tree.leaves(self.cache["units"])[0].shape[1]
+        cache = dict(self.cache, block_tables=jnp.full_like(
+            self.cache["block_tables"], n_blocks))
+        for P in self.prefill_buckets:
+            dec = np.zeros((B, 1, nc) if nc else (B, 1), np.int32)
+            pf = np.zeros((P, C, nc) if nc else (P, C), np.int32)
+            out = self._fn(C, True, P)(
+                self.params, jnp.asarray(dec), jnp.asarray(pf), cache,
+                jnp.zeros((B,), jnp.int32), jnp.full((P,), B, jnp.int32),
+                jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.int32))
+            jax.block_until_ready(out)
+            del out
+        self._grouped_built = True
 
     def step(self, batch: StepBatch, fence: bool = True,
              tokens=None) -> StepOutput:
@@ -274,16 +404,28 @@ class ModelRunner:
         without a host round-trip."""
         width = batch.tokens.shape[1]
         has_prefill = bool(np.any(batch.phase == PREFILL))
+        P = batch.prefill_bucket
+        if P and not self._grouped_built:
+            self._build_grouped()
         tr = self.tracer
+        attrs = {"prefill_bucket": P} if P else {}
+        pf_slots = pf_logits = None
         with tr.span("device_dispatch", width=width,
-                     has_prefill=has_prefill):
+                     has_prefill=has_prefill, **attrs):
             self.cache["lens"] = jnp.asarray(batch.row_start)
             self.cache["block_tables"] = jnp.asarray(batch.tables)
-            toks = jnp.asarray(batch.tokens) if tokens is None else tokens
-            logits, last, self.cache = self._fn(width, has_prefill)(
-                self.params, toks, self.cache,
-                jnp.asarray(batch.n_valid),
-                jnp.asarray(batch.phase == PREFILL))
+            if P:
+                pf_slots = np.flatnonzero(batch.phase == PREFILL)
+                dec, pf, *rows = _grouped_args(batch, pf_slots)
+                logits, pf_logits, last, self.cache = self._fn(
+                    width, True, P)(self.params, dec, pf, self.cache, *rows)
+            else:
+                toks = jnp.asarray(batch.tokens) if tokens is None \
+                    else tokens
+                logits, last, self.cache = self._fn(width, has_prefill)(
+                    self.params, toks, self.cache,
+                    jnp.asarray(batch.n_valid),
+                    jnp.asarray(batch.phase == PREFILL))
         self.n_steps += 1
         if fence and tr.enabled and tr.cfg.fence_device:
             # fence so device_wait covers actual execution, not just
@@ -291,7 +433,8 @@ class ModelRunner:
             # untraced path never blocks (async dispatch preserved)
             with tr.span("device_wait"):
                 jax.block_until_ready((logits, last))
-        return StepOutput(logits=logits, last_logits=last)
+        return StepOutput(logits=logits, last_logits=last,
+                          prefill_logits=pf_logits, prefill_slots=pf_slots)
 
     # --- device-resident decode burst (async engine, docs/async.md) ---
     def decode_burst(self, sampled: bool, k_max: int):
@@ -397,4 +540,5 @@ class ModelRunner:
 
 
 __all__ = ["BACKENDS", "DECODE", "IDLE", "ModelRunner", "PREFILL",
-           "STOP_L", "STOP_NS", "StepBatch", "StepOutput", "VERIFY"]
+           "STOP_L", "STOP_NS", "StepBatch", "StepOutput", "VERIFY",
+           "grouped_step", "prefill_buckets", "uniform_step"]

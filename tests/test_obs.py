@@ -733,8 +733,11 @@ def test_compile_of_new_bucket_is_counted_and_named(nectar):
     reg = eng.metrics.registry
     snap = reg.collect()
     assert reg.value("engine_compiles_total") >= 1
-    assert snap['engine_compiles_total{fun_name="jit(serve_step_w13_'
-                'prefill)"}'] == 1
+    # the first tick with a prefill row builds the grouped step of every
+    # prefill bucket (max_batch 2: one and two rows of 13)
+    for p in (1, 2):
+        assert snap['engine_compiles_total{fun_name="jit(serve_step_w1_'
+                    f'prefill_p{p}x13)"}}'] == 1
     names = set(eng.metrics.compiles.values)
     assert any(n.startswith("jit(serve_step_w") for n in names)
     first = eng.tracer.tick_stats[0]
@@ -743,13 +746,73 @@ def test_compile_of_new_bucket_is_counted_and_named(nectar):
         == reg.value("engine_compiles_total")
     text = reg.prometheus_text()
     assert "# TYPE engine_compiles_total counter" in text
-    assert 'engine_compiles_total{fun_name="jit(serve_step_w13_prefill)"}' \
-        ' 1' in text
+    assert 'engine_compiles_total{fun_name="jit(serve_step_w1_prefill_' \
+        'p1x13)"} 1' in text
     # a fresh window starts the count again; warm buckets do not compile
     eng.reset_metrics()
     eng.run([Request(rid=eng.new_rid(), prompt=_prompts(cfg, [20])[0],
                      max_new=3)], max_steps=100)
     assert eng.metrics.registry.value("engine_compiles_total") == 0
+
+
+def test_grouped_buckets_build_at_first_prefill_tick(nectar):
+    """The first tick with a prefill row builds the grouped step of every
+    prefill bucket: later ticks with two, three and four prefill rows
+    compile nothing."""
+    cfg, _, params = nectar
+    # prefill_chunk 12: a bucket no other test compiles
+    _, eng = _serve(cfg, params, _prompts(cfg, [20]), max_new=3, obs=OBS,
+                    paged=True, max_batch=4, max_seq=96, block_size=8,
+                    prefill_chunk=12)
+    assert eng.runner.prefill_buckets == [1, 4]
+    assert {"jit(serve_step_w1_prefill_p1x12)",
+            "jit(serve_step_w1_prefill_p4x12)"} \
+        <= set(eng.metrics.compiles.values)
+    eng.reset_metrics()
+    # one to four chunks each: ticks with 4, 3, 2 and 1 prefill rows
+    eng.run([Request(rid=eng.new_rid(), prompt=p, max_new=3)
+             for p in _prompts(cfg, [12, 24, 36, 48], seed=3)],
+            max_steps=200)
+    reg = eng.metrics.registry
+    assert {2, 3, 4} <= {t["rows_prefill"] for t in eng.tracer.tick_stats}
+    assert reg.value("engine_compiles_total") == 0
+    mixed = reg.labeled_counter("engine_mixed_ticks_total",
+                                "prefill_bucket").values
+    assert set(mixed) == {"1", "4"}
+
+
+def test_grouped_ticks_carry_prefill_group(profiled):
+    """Grouped ticks name their prefill group on ``engine.step``
+    (prefill_bucket P, prefill_width C), charge pad waste against the
+    shape that ran (B + P*C token slots), and are counted by P in
+    engine_mixed_ticks_total."""
+    from repro.serve.runner import PREFILL
+    eng, batches, ev = profiled
+    B, C = eng.scfg.max_batch, eng.scfg.prefill_chunk
+    steps = sorted((st for _, st in ev["engine.step"] if "width" in st),
+                   key=lambda st: st["step_num"])
+    by_tick = {t["tick"]: t for t in eng.tracer.tick_stats}
+    counted = {}
+    for st, (n_valid, _, phase) in zip(steps, batches):
+        t = by_tick[st["step_num"]]
+        n_pf = int((phase == PREFILL).sum())
+        if not n_pf:
+            assert "prefill_bucket" not in st
+            assert t["pad_waste_frac"] == pytest.approx(
+                1 - n_valid.sum() / (B * st["width"]))
+            continue
+        P = eng.runner.prefill_bucket_for(n_pf)
+        assert st["prefill_bucket"] == t["prefill_bucket"] == P
+        assert st["prefill_width"] == t["prefill_width"] == C
+        assert t["pad_waste_frac"] == pytest.approx(
+            1 - n_valid.sum() / (B + P * C))
+        counted[str(P)] = counted.get(str(P), 0) + 1
+    assert counted
+    assert eng.metrics.registry.labeled_counter(
+        "engine_mixed_ticks_total", "prefill_bucket").values == counted
+    text = eng.metrics.registry.prometheus_text()
+    assert "# TYPE engine_mixed_ticks_total counter" in text
+    assert 'engine_mixed_ticks_total{prefill_bucket="1"}' in text
 
 
 def test_queue_wait_is_admission_minus_arrival(nectar):

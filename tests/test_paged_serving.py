@@ -90,6 +90,98 @@ def test_chunked_prefill_matches_whole_prompt_logits(nectar):
     assert int(jnp.argmax(last[0])) == int(jnp.argmax(ref[0, 0]))
 
 
+# each slot's (phase, tokens in this tick, context before it), on top of
+# contexts of 11, 8, 8 and 5 tokens: slot 1 prefills at the chunk seam
+# (its second chunk), slot 2 its one-token last chunk; 2 and 3 prefill
+# rows pad to the group bucket of 4
+_GROUPED_TICKS = {
+    1: [("D", 1, 11), ("P", 8, 8), ("D", 1, 8), None],
+    2: [("D", 1, 11), ("P", 8, 8), ("P", 1, 8), ("D", 1, 5)],
+    3: [("D", 1, 11), ("P", 8, 8), ("P", 1, 8), ("P", 3, 5)],
+}
+
+
+@pytest.fixture(scope="module")
+def qwen_smoke():
+    cfg = get_config("qwen3-4b-smoke")
+    model = Model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(1))
+
+
+@pytest.mark.parametrize("n_prefill", [1, 2, 3])
+@pytest.mark.parametrize("model_name,backend", [
+    ("nectar", "naive"), ("qwen_smoke", "naive"), ("nectar", "flash")])
+def test_grouped_step_matches_uniform_step(request, model_name, backend,
+                                           n_prefill):
+    """A grouped step (decode rows at width 1 beside a [P, C] prefill
+    group) gives the logits, last logits and pool of the uniform [B, C]
+    step in which every row is padded to the chunk."""
+    from repro.serve.runner import DECODE, PREFILL, ModelRunner
+    cfg, model, params = request.getfixturevalue(model_name)
+    C = 8
+    scfg = ServeConfig(max_batch=4, max_seq=64, paged=True, block_size=8,
+                       prefill_chunk=C, attn_backend=backend)
+    runner = ModelRunner(model, params, scfg)
+    MB = scfg.blocks_per_seq
+    tables = np.arange(4 * MB, dtype=np.int32).reshape(4, MB)
+    rng = np.random.default_rng(n_prefill)
+    ctx = [11, 8, 8, 5]
+    seqs = [rng.integers(0, cfg.vocab, n + C, dtype=np.int32) for n in ctx]
+    # the contexts, written by uniform prefill steps
+    for pos in range(0, max(ctx), C):
+        b = runner.new_batch(C, tables)
+        for slot, n in enumerate(ctx):
+            if pos < n:
+                b.add_row(slot, PREFILL, seqs[slot][pos:min(n, pos + C)],
+                          pos)
+        runner.step(b)
+    cache0 = runner.cache
+
+    def tick(n_prefill_rows):
+        runner.cache = cache0
+        b = runner.new_batch(C, tables, n_prefill=n_prefill_rows)
+        for slot, row in enumerate(_GROUPED_TICKS[n_prefill]):
+            if row is not None:
+                kind, n, start = row
+                b.add_row(slot, PREFILL if kind == "P" else DECODE,
+                          seqs[slot][start:start + n], start)
+        return b, runner.step(b), runner.cache
+
+    bu, uni, cache_u = tick(0)
+    bg, grp, cache_g = tick(n_prefill)
+    assert bu.prefill_bucket == 0 and bu.tokens.shape == (4, C)
+    assert bg.prefill_bucket == {1: 1, 2: 4, 3: 4}[n_prefill]
+    assert grp.prefill_logits.shape[:2] == (bg.prefill_bucket, C)
+    tol = dict(rtol=1e-5, atol=1e-5)
+    for slot, row in enumerate(_GROUPED_TICKS[n_prefill]):
+        if row is None:
+            continue
+        n = row[1]
+        np.testing.assert_allclose(np.asarray(grp.last_logits)[slot],
+                                   np.asarray(uni.last_logits)[slot], **tol)
+        np.testing.assert_allclose(grp.row_logits(slot)[:n],
+                                   uni.row_logits(slot)[:n], **tol)
+    for a, b in zip(jax.tree.leaves(cache_g["units"]),
+                    jax.tree.leaves(cache_u["units"])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+
+
+def test_grouped_engine_token_identical_to_contiguous(nectar):
+    """Overlapping prompts on four slots: ticks with one to four prefill
+    rows run grouped, and greedy output equals the contiguous-cache
+    engine's."""
+    cfg, _, params = nectar
+    prompts = _prompts(cfg, [5, 37, 9, 60, 3, 21, 30, 14], seed=11)
+    legacy, _ = _serve(cfg, params, prompts, max_batch=4, max_seq=96,
+                       paged=False)
+    paged, eng = _serve(cfg, params, prompts, max_batch=4, max_seq=96,
+                        paged=True, block_size=8, prefill_chunk=16)
+    assert legacy == paged
+    mixed = eng.metrics.registry.labeled_counter(
+        "engine_mixed_ticks_total", "prefill_bucket").values
+    assert set(mixed) == {"1", "4"}
+
+
 def test_preemption_on_block_exhaustion_preserves_output(nectar):
     """Pool too small for both requests: the scheduler evicts and replays,
     and greedy output is unchanged vs an unconstrained pool."""

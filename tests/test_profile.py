@@ -79,7 +79,8 @@ def test_buckets_attainment_in_unit_interval(profiled):
     GFLOP/s, GB/s, and attainment in (0, 1] vs the active chip."""
     _, eng = profiled
     rows = eng.profiler.report(eng.tracer.tick_stats)
-    assert {r["bucket"] for r in rows} == {"decode", "prefill16"}
+    assert {r["bucket"] for r in rows} == {"decode", "mixed_p1",
+                                           "mixed_p2"}
     for r in rows:
         assert r["ticks"] > 0 and r["dev_ms"] > 0
         assert r["GFLOP/s"] > 0 and r["GB/s"] > 0 and r["AI"] > 0
@@ -100,8 +101,8 @@ def test_scope_split_sums_to_bucket_total(profiled):
         assert 0.5 < r["scope_attributed_frac"] <= 1.0
         fracs = {k: s["flops_frac"] for k, s in r["scopes"].items()}
         assert sum(fracs.values()) == pytest.approx(1.0, rel=0.05)
-    # the heterogeneity story: decode runs the sparse FFN path, prefill
-    # the dense one — the split must show it
+    # the heterogeneity story: decode runs the sparse FFN path, a
+    # grouped tick's prefill group the dense one — the split must show it
     rows = {r["bucket"]: r for r in
             eng.profiler.report(eng.tracer.tick_stats)}
 
@@ -109,8 +110,8 @@ def test_scope_split_sums_to_bucket_total(profiled):
         return rows[bucket]["scopes"].get(scope, {}).get("flops", 0.0)
 
     assert flops("decode", "ffn_sparse") > flops("decode", "ffn_dense")
-    assert flops("prefill16", "ffn_dense") > flops("prefill16",
-                                                   "ffn_sparse")
+    assert flops("mixed_p1", "ffn_dense") > flops("mixed_p1",
+                                                  "ffn_sparse")
 
 
 def test_dot_parser_reads_operand_shapes_by_name():
@@ -176,7 +177,7 @@ def test_summary_and_prometheus_carry_bucket_attainment(profiled):
     text = eng.metrics.registry.prometheus_text()
     assert '# TYPE bucket_attainment_attainment gauge' in text
     assert 'bucket_attainment_attainment{bucket="decode"}' in text
-    assert 'bucket_attainment_achieved_gflops{bucket="prefill16"}' in text
+    assert 'bucket_attainment_achieved_gflops{bucket="mixed_p1"}' in text
 
 
 def test_perfetto_counter_tracks_validate(profiled, tmp_path):
@@ -201,7 +202,7 @@ def test_attainment_table_renders(profiled):
     from repro.obs import attainment_table
     _, eng = profiled
     table = attainment_table(eng.profiler.report(eng.tracer.tick_stats))
-    assert "decode" in table and "prefill16" in table
+    assert "decode" in table and "mixed_p1" in table
     assert "attain" in table and "flops:" in table
 
 
