@@ -34,11 +34,27 @@ class ModelConfig:
     mrope: bool = False             # qwen2-vl M-RoPE (3 position channels)
     pos_emb: str = "rope"           # rope | sin | none
 
-    # --- MoE ---
+    # --- latent attention (MLA, DeepSeek-V2/V3): kv_lora_rank > 0 caches
+    # one RMS-normed latent of that width plus one shared roped key of
+    # qk_rope_head_dim per token; d_head is then qk_nope + qk_rope ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # --- MoE (d_ff is the routed expert's width) ---
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
+    first_k_dense_replace: int = 0  # leading dense layers before the MoE
+    dense_d_ff: int = 0             # their FFN width (intermediate_size)
+    scoring_func: str = "softmax"   # router scores: softmax | sigmoid
+    router_bias: bool = False       # noaux_tc: a bias for selection only
+    norm_topk_prob: bool = True     # weights renormalised over the top-k
+    routed_scaling_factor: float = 1.0
+    # experts [lo, hi) held on this chip (expert parallelism); (0, 0) = all
+    held_experts: Tuple[int, int] = (0, 0)
 
     # --- hybrid / ssm ---
     ssm_state: int = 0
@@ -72,13 +88,36 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.d_head == 0:
-            object.__setattr__(self, "d_head", self.d_model // self.n_heads)
+            object.__setattr__(self, "d_head", self.qk_nope_head_dim
+                               + self.qk_rope_head_dim if self.mla
+                               else self.d_model // self.n_heads)
         assert self.n_heads % max(self.n_kv_heads, 1) == 0, \
             f"{self.name}: n_heads must be a multiple of n_kv_heads"
 
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Width each rotary table covers: the shared roped key under
+        MLA, the whole head otherwise."""
+        return self.qk_rope_head_dim if self.mla else self.d_head
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """Experts [lo, hi) whose weights this chip holds."""
+        lo, hi = self.held_experts
+        return (lo, hi) if hi else (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
 
     @property
     def is_recurrent(self) -> bool:
@@ -98,11 +137,20 @@ class ModelConfig:
         emb = v * d * (1 + self.n_codebooks if self.n_codebooks else 1)
         head = 0 if self.tie_embeddings else v * d * (self.n_codebooks or 1)
         total = emb + head + d  # final norm
-        for blk in self.layer_kinds():
+        attn = n_q * d + 2 * n_kv * d + n_q * d
+        if self.mla:
+            r, rd = self.kv_lora_rank, self.qk_rope_head_dim
+            h_qk = self.n_heads * (self.qk_nope_head_dim + rd)
+            h_v = self.n_heads * self.v_head_dim
+            attn = d * h_qk + d * (r + rd) + r \
+                + r * (h_qk - self.n_heads * rd + h_v) + h_v * d
+        for i, blk in enumerate(self.layer_kinds()):
             if blk in ("attn", "shared_attn"):
-                total += n_q * d + 2 * n_kv * d + n_q * d + d  # qkvo + norm
+                total += attn + d  # qkvo + norm
                 if blk == "attn":
-                    total += self._ffn_params() + d
+                    lead = i < self.first_k_dense_replace
+                    total += self._ffn_params(
+                        self.dense_d_ff if lead else 0) + d
             elif blk == "mamba2":
                 di = self.ssm_expand * d
                 heads = self.ssm_heads or di // 64
@@ -112,19 +160,20 @@ class ModelConfig:
                 di = 2 * d
                 total += d * 2 * di + di * d + 4 * di * 2 + d  # projs + gates
             elif blk == "moe":
-                total += n_q * d + 2 * n_kv * d + n_q * d + d
+                total += attn + d
                 total += d * self.n_experts  # router
+                total += self.n_experts if self.router_bias else 0
                 e_f = (2 if self.glu else 1) * d * f + f * d
-                total += self.n_experts * e_f + self.n_shared_experts * e_f + d
+                total += self.n_held * e_f + self.n_shared_experts * e_f + d
         # shared_attn params are counted once (they are shared)
         n_shared = sum(1 for b in self.layer_kinds() if b == "shared_attn")
         if n_shared > 1:
             total -= (n_shared - 1) * (n_q * d + 2 * n_kv * d + n_q * d + d)
         return int(total)
 
-    def _ffn_params(self) -> int:
-        return (2 if self.glu else 1) * self.d_model * self.d_ff \
-            + self.d_ff * self.d_model
+    def _ffn_params(self, d_ff: int = 0) -> int:
+        f = d_ff or self.d_ff
+        return (2 if self.glu else 1) * self.d_model * f + f * self.d_model
 
     def pattern_unit(self) -> Tuple[str, ...]:
         """The repeating block pattern; the stack scans over
@@ -141,15 +190,21 @@ class ModelConfig:
 
     @property
     def n_units(self) -> int:
+        """Copies of the pattern unit the stack scans, after the
+        ``first_k_dense_replace`` leading dense layers."""
         unit = self.pattern_unit()
-        assert self.n_layers % len(unit) == 0, \
-            f"{self.name}: n_layers {self.n_layers} % unit {len(unit)} != 0"
-        return self.n_layers // len(unit)
+        n = self.n_layers - self.first_k_dense_replace
+        assert n % len(unit) == 0, \
+            f"{self.name}: n_layers {n} % unit {len(unit)} != 0"
+        return n // len(unit)
 
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Per-layer block kind, resolved from the pattern/family."""
+        """Per-layer block kind, resolved from the pattern/family: the
+        leading dense layers ("attn"), then the pattern unit repeated."""
         pat = self.pattern_unit()
-        return tuple(pat[i % len(pat)] for i in range(self.n_layers))
+        lead = self.first_k_dense_replace
+        return ("attn",) * lead + tuple(
+            pat[i % len(pat)] for i in range(self.n_layers - lead))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -70,7 +70,7 @@ class Model:
 
     def forward_step(self, params, tokens, cache, n_valid, is_prefill,
                      block_size: int, backend: str = "naive",
-                     has_prefill: bool = True):
+                     has_prefill: bool = True, with_counts: bool = False):
         """THE paged serving entry: one fixed-shape batched step serving
         prefill, decode, and spec-verify rows together — everything the
         three per-phase entries (decode_step_paged / verify_step_paged /
@@ -79,18 +79,21 @@ class Model:
             return transformer.forward_step(params, self.cfg, tokens,
                                             cache, n_valid, is_prefill,
                                             block_size, backend=backend,
-                                            has_prefill=has_prefill)
+                                            has_prefill=has_prefill,
+                                            with_counts=with_counts)
 
     def forward_step_grouped(self, params, dec_tokens, pf_tokens, cache,
                              dec_valid, pf_slot, pf_start, pf_valid,
-                             block_size: int, backend: str = "naive"):
+                             block_size: int, backend: str = "naive",
+                             with_counts: bool = False):
         """A tick with prefill chunks as two row groups in one pass: a
         width-1 decode group by slot and a chunk-wide prefill group
         (transformer.forward_step_grouped)."""
         with jax.default_matmul_precision(self._serving_precision):
             return transformer.forward_step_grouped(
                 params, self.cfg, dec_tokens, pf_tokens, cache, dec_valid,
-                pf_slot, pf_start, pf_valid, block_size, backend=backend)
+                pf_slot, pf_start, pf_valid, block_size, backend=backend,
+                with_counts=with_counts)
 
     def decode_burst(self, params, cache, tables, tok0, lens0, alive0,
                      budget, stops, stop_len, hist0, sample_fn,

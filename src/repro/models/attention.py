@@ -1,4 +1,6 @@
-"""Multi-head attention (MHA/GQA/MQA) with KV cache, qk-norm, qkv-bias."""
+"""Multi-head attention (MHA/GQA/MQA) with KV cache, qk-norm, qkv-bias,
+and latent attention (MLA, DeepSeek-V2/V3) over a cache of one latent per
+token."""
 
 from __future__ import annotations
 
@@ -37,6 +39,9 @@ def init_attn(key, cfg: ModelConfig, dtype):
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
+    if cfg.mla:
+        return {"kv": jnp.zeros((batch, max_len, 1, latent_width(cfg)),
+                                dtype)}
     shape = (batch, max_len, cfg.n_kv_heads, cfg.d_head)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -51,7 +56,17 @@ def init_paged_kv_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
     ``int8_kv``: store 1 byte/element plus one f32 scale per (token, head)
     for each of K and V (kv_cache.quantize_kv layout) — halves the decode
     KV stream on top of the paper's weight-side savings. Byte accounting
-    in serve.paged_kv.kv_bytes_per_token matches this layout exactly."""
+    in serve.paged_kv.kv_bytes_per_token matches this layout exactly.
+
+    Latent attention (``cfg.mla``) keeps one leaf ``kv`` [n_blocks, bs,
+    1, kv_lora_rank + qk_rope_head_dim]: per token the RMS-normed latent
+    and the roped key its heads share, read like one KV head of MQA."""
+    if cfg.mla:
+        if int8_kv:
+            raise ValueError(f"{cfg.name}: the latent pool is float; "
+                             "int8 KV (kv_quant) covers k/v pools only")
+        return {"kv": jnp.zeros((n_blocks, block_size, 1,
+                                 latent_width(cfg)), dtype)}
     shape = (n_blocks, block_size, cfg.n_kv_heads, cfg.d_head)
     if not int8_kv:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -89,6 +104,16 @@ def attn_forward(p, cfg: ModelConfig, x, cos, sin,
     the current segment (prefill semantics: segment starts at pos=0).
     """
     B, S, _ = x.shape
+    if cfg.mla:
+        q, kv = _mla_qkv(p, cfg, x, cos, sin)
+        new_cache = None
+        if cache is not None:
+            new_cache = {"kv": jax.lax.dynamic_update_slice_in_dim(
+                cache["kv"], kv.astype(cache["kv"].dtype),
+                0 if pos is None else pos, axis=1)}
+        gpos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        return _mla_out(p, cfg, _mla_attend(cfg, q, kv, gpos),
+                        x.dtype), new_cache
     q, k, v = _qkv(p, cfg, x)
     q = rope.apply_rope(q, cos, sin)
     k = rope.apply_rope(k, cos, sin)
@@ -128,10 +153,16 @@ def attn_decode(p, cfg: ModelConfig, x, cos, sin, cache: dict,
     ``repro.dist.collectives``.
     """
     B = x.shape[0]
+    rows = jnp.arange(B)
+    if cfg.mla:
+        q, kv = _mla_qkv(p, cfg, x, cos, sin)
+        ckv = cache["kv"].at[rows, lens].set(
+            kv[:, 0].astype(cache["kv"].dtype), mode="drop")
+        o = _mla_attend(cfg, q, ckv, lens[:, None])
+        return _mla_out(p, cfg, o, x.dtype), {"kv": ckv}
     q, k, v = _qkv(p, cfg, x)
     q = rope.apply_rope(q, cos, sin)
     k = rope.apply_rope(k, cos, sin)
-    rows = jnp.arange(B)
     ck = cache["k"].at[rows, lens].set(k[:, 0].astype(cache["k"].dtype),
                                        mode="drop")
     cv = cache["v"].at[rows, lens].set(v[:, 0].astype(cache["v"].dtype),
@@ -310,7 +341,14 @@ def attn_step_paged(p, cfg: ModelConfig, x, cos, sin, cache: dict,
     x: [B, S, d]; lens/n_valid: i32[B]; tables: i32[B, MB] (inactive rows
     all-sentinel). Returns (out [B, S, d], new_cache).
     """
-    B, S, _ = x.shape
+    B, S, d = x.shape
+    if cfg.mla:
+        def packed(a):
+            return a.reshape((1, B * S) + a.shape[2:])
+        out, new_cache = _mla_step(
+            p, cfg, packed(x), packed(cos), packed(sin), cache,
+            (PagedGroup(lens, n_valid, tables, S),), block_size)
+        return out.reshape(B, S, d), new_cache
     n_blocks = cache["k"].shape[0]
     q, k, v = _qkv(p, cfg, x)
     q = rope.apply_rope(q, cos, sin)
@@ -345,6 +383,8 @@ def attn_step_grouped(p, cfg: ModelConfig, x, cos, sin, cache: dict,
     x: [1, N, d] — the groups' tokens packed in order, each group's rows
     row-major (N = sum of rows x width); cos/sin packed the same way.
     Returns (out [1, N, d], new_cache)."""
+    if cfg.mla:
+        return _mla_step(p, cfg, x, cos, sin, cache, groups, block_size)
     n_blocks = cache["k"].shape[0]
     q, k, v = _qkv(p, cfg, x)
     q = rope.apply_rope(q, cos, sin)
@@ -365,3 +405,97 @@ def attn_step_grouped(p, cfg: ModelConfig, x, cos, sin, cache: dict,
         outs.append(o.reshape(n, -1))
         i += n
     return _project_out(p, jnp.concatenate(outs)[None]), new_cache
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA): per token the pool holds [c | k_pe] — the
+# RMS-normed latent c = norm(x Wkv_a[:, :r]) and the roped key k_pe its
+# heads share. Attention runs in latent space: Wkv_b's key half folds into
+# each head's query (q_nope -> r wide), its value half into the output,
+# so one gathered column of r + rope values serves every head (MQA with
+# one KV head; the first r values are the value).
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def init_mla(key, cfg: ModelConfig, dtype):
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rd, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {"wq": layers.dense_init(ks[0], (d, H * (nope + rd)), dtype),
+            "wkv_a": layers.dense_init(ks[1], (d, r + rd), dtype),
+            "kv_norm": jnp.ones((r,), dtype),
+            "wkv_b": layers.dense_init(ks[2], (r, H * (nope + dv)), dtype),
+            "wo": layers.dense_init(ks[3], (H * dv, d), dtype)}
+
+
+def _wkv_b(p, cfg: ModelConfig):
+    """Wkv_b as [r, H, qk_nope + v]: per head its key half, then its
+    value half (the published kv_b_proj layout)."""
+    return p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+
+
+def _mla_qkv(p, cfg: ModelConfig, x, cos, sin):
+    """x [B, S, d] -> (q [B, S, H, r + rope]: the absorbed query
+    q_nope Wkv_b^k beside the roped q_pe; kv [B, S, 1, r + rope]: the
+    pool entry [norm(c) | rope(k_pe)]). cos/sin cover the rope width."""
+    B, S, _ = x.shape
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, -1)
+    ckv = x @ p["wkv_a"]
+    c = layers.rms_norm(ckv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope.apply_rope(ckv[..., None, r:], cos, sin)
+    q_pe = rope.apply_rope(q[..., nope:], cos, sin)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope],
+                       _wkv_b(p, cfg)[..., :nope])
+    return (jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], -1),
+            jnp.concatenate([c[:, :, None], k_pe], -1))
+
+
+def _mla_attend(cfg: ModelConfig, q, kv, gpos):
+    """Latent attention of queries q [B, S, H, r + rope] over latent rows
+    kv [B, T, 1, r + rope], query j of row b seeing rows t <= gpos[b, j];
+    softmax scale 1/sqrt(qk_nope + qk_rope) as published. Returns the
+    latent output f32[B, S, H, r]."""
+    s = _gqa_scores(q[:, :, None], kv) * (cfg.d_head ** -0.5)
+    vis = jnp.arange(kv.shape[1])[None, None, :] <= gpos[:, :, None]
+    s = jnp.where(vis[:, None, None], s, NEG_INF)     # [B, 1, H, S, T]
+    o = _gqa_out(jax.nn.softmax(s, axis=-1), kv[..., :cfg.kv_lora_rank])
+    return jnp.moveaxis(o[:, 0], 1, 2)
+
+
+def _mla_out(p, cfg: ModelConfig, o, dtype):
+    """Latent output [B, S, H, r] -> values through Wkv_b's value half,
+    then Wo: [B, S, d]."""
+    B, S = o.shape[:2]
+    v = jnp.einsum("bshr,rhv->bshv", o.astype(dtype),
+                   _wkv_b(p, cfg)[..., cfg.qk_nope_head_dim:])
+    return _project_out(p, v.reshape(B, S, -1))
+
+
+def _mla_step(p, cfg: ModelConfig, x, cos, sin, cache: dict, groups,
+              block_size: int):
+    """attn_step_grouped for latent attention: projections on the packed
+    tokens x [1, N, d], every group's latents into the pool leaf ``kv``
+    in one store, then per group one gather of latent columns for all
+    heads. Returns (out [1, N, d], new_cache)."""
+    q, kv = _mla_qkv(p, cfg, x, cos, sin)
+    n_blocks = cache["kv"].shape[0]
+    slots = [_paged_slots(g.tables, g.lens, g.n_valid, g.width,
+                          block_size, n_blocks) for g in groups]
+    blk = jnp.concatenate([b.reshape(-1) for _, b, _ in slots])
+    off = jnp.concatenate([o.reshape(-1) for _, _, o in slots])
+    new_cache = _store_paged(cache, "kv", blk, off, kv[0])
+    outs, i = [], 0
+    for g, (gpos, _, _) in zip(groups, slots):
+        rows = g.lens.shape[0]
+        n = rows * g.width
+        qg = q[0, i:i + n].reshape(rows, g.width, *q.shape[2:])
+        ctx = _read_paged(new_cache, "kv", g.tables, n_blocks)
+        outs.append(_mla_attend(cfg, qg, ctx, gpos).reshape(
+            n, *q.shape[2:3], cfg.kv_lora_rank))
+        i += n
+    return _mla_out(p, cfg, jnp.concatenate(outs)[None], x.dtype), \
+        new_cache

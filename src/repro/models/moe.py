@@ -10,6 +10,9 @@ mesh axis.
 MoE routing is itself structured activation sparsity — the paper's C2 at
 expert granularity; with ``relu_sparse`` the ReLU gather applies *inside*
 the routed expert as well (composed byte savings, see DESIGN.md §5).
+
+Serving takes ``moe_serve`` instead: drop-free, over the experts this chip
+holds (``ModelConfig.held_experts``), with the router over all of them.
 """
 
 from __future__ import annotations
@@ -26,15 +29,18 @@ from repro.models.ffn import init_ffn
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
+    """Router over all ``n_experts``; weights of the held experts only."""
     d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
     ks = jax.random.split(key, 5)
     p = {
         "router": layers.dense_init(ks[0], (d, E), jnp.float32),
-        "w_up": layers.dense_init(ks[1], (E, d, f), dtype),
-        "w_down": layers.dense_init(ks[2], (E, f, d), dtype),
+        "w_up": layers.dense_init(ks[1], (cfg.n_held, d, f), dtype),
+        "w_down": layers.dense_init(ks[2], (cfg.n_held, f, d), dtype),
     }
     if cfg.glu:
-        p["w_gate"] = layers.dense_init(ks[3], (E, d, f), dtype)
+        p["w_gate"] = layers.dense_init(ks[3], (cfg.n_held, d, f), dtype)
+    if cfg.router_bias:
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
     if cfg.n_shared_experts:
         p["shared"] = init_ffn(ks[4], cfg, dtype,
                                d_ff=cfg.d_ff * cfg.n_shared_experts)
@@ -152,3 +158,69 @@ def moe_reference(p, cfg: ModelConfig, x):
         out = out + sp.dense_ffn(xt, sh["w_up"], sh["w_down"], act=act,
                                  w_gate=sh.get("w_gate"))
     return out.reshape(B, S, d)
+
+
+# ---------------------------------------------------------------------------
+# Serving dispatch: drop-free over the held experts
+
+
+def route_serve(p, cfg: ModelConfig, x):
+    """The router in float32 (x [T, d] -> ids i32[T, k], weights f32[T, k]):
+    scores over all ``n_experts`` (sigmoid or softmax); the top-k chosen
+    by score plus the selection bias where the config has one (noaux_tc),
+    weighted by the unbiased scores, renormalised over the top-k
+    (``norm_topk_prob``) and scaled by ``routed_scaling_factor``."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits) if cfg.scoring_func == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    sel = s + p["router_bias"].astype(jnp.float32) if cfg.router_bias else s
+    _, idx = jax.lax.top_k(sel, cfg.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scaling_factor
+
+
+def moe_serve(p, cfg: ModelConfig, x, valid):
+    """The MoE layer of a serving step: x [..., d], valid bool[...] (False
+    on padding positions). Each (valid token, held expert) assignment is
+    computed exactly once — no capacity, no drops — so a token's output
+    does not depend on the other tokens of its tick. Tokens routed to
+    experts held on other chips get nothing from those here; the gate
+    weights still normalise over all k choices. The held experts run as
+    one dense product over every token, with the gate zero where a token
+    did not choose the expert (at decode batch sizes the expert weights'
+    stream, not these FLOPs, sets the time).
+
+    Returns (y [..., d], stats i32[3]: held assignments, assignments to
+    experts held elsewhere, the most tokens on one held expert)."""
+    shape = x.shape
+    xt = x.reshape(-1, shape[-1])
+    valid = valid.reshape(-1)
+    lo, hi = cfg.held_range
+    with jax.named_scope("moe_router"):
+        idx, w = route_serve(p, cfg, xt)
+        mine = (idx[..., None] == jnp.arange(lo, hi)) \
+            & valid[:, None, None]                          # [T, k, held]
+        gates = jnp.sum(jnp.where(mine, w[..., None], 0.0), axis=1)
+        load = jnp.sum(mine, axis=(0, 1), dtype=jnp.int32)
+        held = jnp.sum(load)
+        stats = jnp.stack([held, jnp.sum(valid, dtype=jnp.int32) * cfg.top_k
+                           - held, jnp.max(load)])
+    act = "relu" if cfg.relu_sparse else cfg.act
+    with jax.named_scope("moe_experts"):
+        up = jnp.einsum("td,edf->etf", xt, p["w_up"])
+        if cfg.glu:
+            h = sp.apply_act(jnp.einsum("td,edf->etf", xt, p["w_gate"]),
+                             act) * up
+        else:
+            h = sp.apply_act(up, act)
+        ye = jnp.einsum("etf,efd->etd", h, p["w_down"])
+        y = jnp.einsum("etd,te->td", ye.astype(jnp.float32), gates)
+    if cfg.n_shared_experts:
+        with jax.named_scope("ffn_dense"):
+            sh = p["shared"]
+            y = y + sp.dense_ffn(xt, sh["w_up"], sh["w_down"], act=act,
+                                 w_gate=sh.get("w_gate"))
+    return y.astype(x.dtype).reshape(shape), stats

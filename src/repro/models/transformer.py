@@ -3,7 +3,10 @@
 A config's ``pattern_unit()`` (e.g. zamba2: 5x mamba2 + shared_attn) is the
 scan body; the stack runs ``n_units`` copies with stacked per-unit params —
 the Chipyard-style generator at the model level. Shared blocks (zamba2's
-shared attention) live outside the scan and are closed over.
+shared attention) live outside the scan and are closed over. Leading dense
+layers (``first_k_dense_replace``, DeepSeek-V3 style) run before the scan,
+their params and caches stacked under "lead" as the units' are under
+"units".
 """
 
 from __future__ import annotations
@@ -23,17 +26,19 @@ from repro.models import attention, ffn, layers, moe, rope, ssm, xlstm
 # Per-block init
 
 
-def init_block(key, kind: str, cfg: ModelConfig, dtype):
+def init_block(key, kind: str, cfg: ModelConfig, dtype,
+               d_ff: Optional[int] = None):
+    init_attn = attention.init_mla if cfg.mla else attention.init_attn
     if kind in ("attn", "shared_attn"):
         k1, k2, k3 = jax.random.split(key, 3)
         return {"norm1": jnp.ones((cfg.d_model,), dtype),
-                "attn": attention.init_attn(k1, cfg, dtype),
+                "attn": init_attn(k1, cfg, dtype),
                 "norm2": jnp.ones((cfg.d_model,), dtype),
-                "ffn": ffn.init_ffn(k2, cfg, dtype)}
+                "ffn": ffn.init_ffn(k2, cfg, dtype, d_ff=d_ff)}
     if kind == "moe":
         k1, k2 = jax.random.split(key)
         return {"norm1": jnp.ones((cfg.d_model,), dtype),
-                "attn": attention.init_attn(k1, cfg, dtype),
+                "attn": init_attn(k1, cfg, dtype),
                 "norm2": jnp.ones((cfg.d_model,), dtype),
                 "moe": moe.init_moe(k2, cfg, dtype)}
     if kind == "mamba2":
@@ -115,8 +120,14 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
     forward_step_grouped) selects by group instead: x is [1, N, d], the
     decode group's ctx["n_decode"] tokens first.
 
+    An MoE block takes the drop-free serving dispatch over the held
+    experts (moe.moe_serve), padding positions (ctx["valid"] False)
+    assigned nothing; its per-tick routing counts come back as the third
+    value (None for other blocks).
+
     The ``jax.named_scope`` annotations ("attn", "ffn_dense",
-    "ffn_sparse" inside ffn_step, "logits" in forward_step) are the
+    "ffn_sparse" inside ffn_step, "moe_router" / "moe_experts" /
+    "ffn_dense" inside moe_serve, "logits" in forward_step) are the
     profiling contract (obs.costmodel): scope names survive into the
     compiled HLO op metadata, which is how per-scope FLOP/byte
     attribution in the roofline attainment report is computed. They add
@@ -145,14 +156,14 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
             x = x + a
         h = constrain_tp_exact(layers.rms_norm(x, p["norm2"],
                                                cfg.norm_eps))
+        stats = None
         if kind == "moe":
-            with jax.named_scope("ffn_dense"):
-                y, _ = moe.moe_forward(p["moe"], cfg, h)
+            y, stats = moe.moe_serve(p["moe"], cfg, h, ctx["valid"])
         else:
             y = ffn.ffn_step(p["ffn"], cfg, h, ctx.get("is_prefill"),
                              has_prefill=ctx["has_prefill"],
                              n_decode=ctx.get("n_decode"))
-        return x + y, new_cache
+        return x + y, new_cache, stats
     raise ValueError(f"paged step requires attention blocks, got {kind!r}")
 
 
@@ -183,6 +194,23 @@ def block_decode(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
 
 # ---------------------------------------------------------------------------
 # Stack
+
+
+def _lead_layers(params, cache, body, x):
+    """Run the leading dense layers in order: ``body(x, (layer params,
+    layer cache)) -> (x, new layer cache)``, each layer sliced out of the
+    "lead" stack. Returns (x, the new "lead" stack, or None without a
+    cache)."""
+    n = jax.tree.leaves(params["lead"])[0].shape[0]
+    outs = []
+    for i in range(n):
+        lc = None if cache is None else jax.tree.map(lambda a: a[i],
+                                                      cache["lead"])
+        x, nc = body(x, (jax.tree.map(lambda a: a[i], params["lead"]), lc))
+        outs.append(nc)
+    if cache is None:
+        return x, None
+    return x, jax.tree.map(lambda *a: jnp.stack(a), *outs)
 
 
 def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
@@ -216,6 +244,11 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
 
     unit_keys = jax.random.split(k_units, n_units)
     params["units"] = jax.vmap(init_unit)(unit_keys)
+    if cfg.first_k_dense_replace:
+        params["lead"] = jax.vmap(lambda k: {"b0": init_block(
+            k, "attn", cfg, dtype, d_ff=cfg.dense_d_ff or None)})(
+            jax.random.split(jax.random.fold_in(k_units, 1),
+                             cfg.first_k_dense_replace))
     if "shared_attn" in unit:
         params["shared"] = init_block(k_shared, "shared_attn", cfg, dtype)
     return params
@@ -232,7 +265,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *units)
     # per-row context lengths (continuous batching: slots advance
     # independently)
-    return {"lens": jnp.zeros((batch,), jnp.int32), "units": stacked}
+    out = {"lens": jnp.zeros((batch,), jnp.int32), "units": stacked}
+    if cfg.first_k_dense_replace:
+        out["lead"] = jax.tree.map(
+            lambda a: jnp.stack([a] * cfg.first_k_dense_replace),
+            {"b0": init_block_cache("attn", cfg, batch, max_len, dtype)})
+    return out
 
 
 def init_paged_cache(cfg: ModelConfig, batch: int, n_blocks: int,
@@ -249,16 +287,26 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_blocks: int,
             f"{cfg.name}: paged KV needs an attention-only pattern "
             f"(found {bad}); serve this family with ServeConfig(paged=False)")
 
-    def one_unit():
-        return {f"b{j}": attention.init_paged_kv_cache(
+    def pools(n: int, kinds):
+        if cfg.mla:
+            # latent leaves made at their stacked shape, with no
+            # per-layer copies to stack
+            leaf = jax.eval_shape(lambda: attention.init_paged_kv_cache(
+                cfg, n_blocks, block_size, dtype, int8_kv=int8_kv))["kv"]
+            return {f"b{j}": {"kv": jnp.zeros((n,) + leaf.shape,
+                                              leaf.dtype)}
+                    for j in range(len(kinds))}
+        units = [{f"b{j}": attention.init_paged_kv_cache(
                     cfg, n_blocks, block_size, dtype, int8_kv=int8_kv)
-                for j, kind in enumerate(unit)}
+                  for j in range(len(kinds))} for _ in range(n)]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *units)
 
-    units = [one_unit() for _ in range(cfg.n_units)]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *units)
     tables = jnp.full((batch, max_blocks_per_seq), n_blocks, jnp.int32)
-    return {"lens": jnp.zeros((batch,), jnp.int32),
-            "block_tables": tables, "units": stacked}
+    out = {"lens": jnp.zeros((batch,), jnp.int32), "block_tables": tables,
+           "units": pools(cfg.n_units, unit)}
+    if cfg.first_k_dense_replace:
+        out["lead"] = pools(cfg.first_k_dense_replace, ("attn",))
+    return out
 
 
 def _embed_inputs(params, cfg: ModelConfig, batch: dict):
@@ -301,12 +349,12 @@ def _rope_tables(cfg: ModelConfig, positions):
         if cfg.mrope:
             positions = positions[0]
         B, S = positions.shape
-        return (jnp.ones((B, S, cfg.d_head // 2), jnp.float32),
-                jnp.zeros((B, S, cfg.d_head // 2), jnp.float32))
+        return (jnp.ones((B, S, cfg.rope_dim // 2), jnp.float32),
+                jnp.zeros((B, S, cfg.rope_dim // 2), jnp.float32))
     if cfg.mrope:
         return rope.mrope_cos_sin(positions, cfg.d_head, cfg.rope_theta,
                                   sections=_mrope_sections(cfg))
-    return rope.rope_cos_sin(positions, cfg.d_head, cfg.rope_theta)
+    return rope.rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta)
 
 
 def _mrope_sections(cfg: ModelConfig):
@@ -342,6 +390,14 @@ def forward(params, cfg: ModelConfig, batch: dict, cache=None):
         ctx["decode_mask"] = (jnp.arange(S)[None, :]
                               >= batch["decode_from"][:, None])
     unit = cfg.pattern_unit()
+    new_lead = None
+    if "lead" in params:
+        def lead_body(x, xs):
+            bp, bc = xs
+            x, nc, _ = block_forward("attn", bp["b0"], cfg, x, ctx,
+                                     None if bc is None else bc["b0"])
+            return constrain_residual(x), {"b0": nc}
+        x, new_lead = _lead_layers(params, cache, lead_body, x)
 
     def unit_body(carry, xs):
         x, aux = carry
@@ -389,6 +445,8 @@ def forward(params, cfg: ModelConfig, batch: dict, cache=None):
             (params["units"], cache["units"]))
         new_cache = {"lens": jnp.full_like(cache["lens"], S),
                      "units": new_units}
+    if new_lead is not None:
+        new_cache["lead"] = new_lead
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux, new_cache
@@ -413,6 +471,13 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, batch_extra=None):
     ctx = {"cos": cos, "sin": sin, "lens": lens,
            "shared_params": params.get("shared")}
     unit = cfg.pattern_unit()
+    new_lead = None
+    if "lead" in params:
+        def lead_body(x, xs):
+            bp, bc = xs
+            x, nc = block_decode("attn", bp["b0"], cfg, x, ctx, bc["b0"])
+            return constrain_residual(x), {"b0": nc}
+        x, new_lead = _lead_layers(params, cache, lead_body, x)
 
     def unit_body(x, xs):
         unit_p, unit_cache = xs
@@ -437,12 +502,15 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, batch_extra=None):
                                     (params["units"], cache["units"]))
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = project_logits(params, cfg, x)
-    return logits, {"lens": lens + 1, "units": new_units}
+    new_cache = {"lens": lens + 1, "units": new_units}
+    if new_lead is not None:
+        new_cache["lead"] = new_lead
+    return logits, new_cache
 
 
 def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
                  is_prefill, block_size: int, backend: str = "naive",
-                 has_prefill: bool = True):
+                 has_prefill: bool = True, with_counts: bool = False):
     """THE serving entry point: one fixed-shape batched step through block
     tables serving chunked-prefill rows, decode rows, and speculative
     K+1 verify rows in the SAME batch (the ModelRunner contract).
@@ -467,7 +535,8 @@ def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
     decode, no dense W_down stream). ``backend`` selects the attention
     read path ("naive" | "flash", see attention.attn_step_paged).
     Returns (logits [B, S, V] — or [B, S, nc, V] for codebook models —
-    and the updated cache).
+    and the updated cache); with ``with_counts``, the step's routing
+    counts third (``_paged_stack``).
     """
     x, cos, sin = _step_inputs(params, cfg, tokens, cache["lens"])
     n_blocks = jax.tree.leaves(cache["units"])[0].shape[1]
@@ -478,12 +547,17 @@ def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
            "has_prefill": has_prefill, "tables": tables,
            "block_size": block_size, "backend": backend,
            "shared_params": params.get("shared")}
-    return _paged_stack(params, cfg, x, cache, ctx)
+    if "moe" in cfg.pattern_unit():
+        ctx["valid"] = (jnp.arange(tokens.shape[1])[None, :]
+                        < n_valid[:, None])
+    logits, cache, counts = _paged_stack(params, cfg, x, cache, ctx)
+    return (logits, cache, counts) if with_counts else (logits, cache)
 
 
 def forward_step_grouped(params, cfg: ModelConfig, dec_tokens, pf_tokens,
                          cache, dec_valid, pf_slot, pf_start, pf_valid,
-                         block_size: int, backend: str = "naive"):
+                         block_size: int, backend: str = "naive",
+                         with_counts: bool = False):
     """forward_step for a tick that carries prefill chunks, without
     padding decode rows to the chunk: two row groups in ONE pass over the
     layers.
@@ -500,7 +574,8 @@ def forward_step_grouped(params, cfg: ModelConfig, dec_tokens, pf_tokens,
     groups' KV lands in the pool in one store. The math of each row is
     that of forward_step's. Returns (decode logits [B, 1, V], prefill
     logits [P, C, V] — with an nc axis before V for codebook models —
-    and the updated cache).
+    and the updated cache); with ``with_counts``, the step's routing
+    counts fourth.
     """
     B, P, C = dec_tokens.shape[0], pf_tokens.shape[0], pf_tokens.shape[1]
     xd, cd, sd = _step_inputs(params, cfg, dec_tokens, cache["lens"])
@@ -524,9 +599,15 @@ def forward_step_grouped(params, cfg: ModelConfig, dec_tokens, pf_tokens,
     ctx = {"cos": pack(cd, cp), "sin": pack(sd, sp), "groups": groups,
            "n_decode": B, "has_prefill": True, "block_size": block_size,
            "backend": backend, "shared_params": params.get("shared")}
-    logits, cache = _paged_stack(params, cfg, pack(xd, xp), cache, ctx)
-    return (logits[0, :B].reshape((B, 1) + logits.shape[2:]),
-            logits[0, B:].reshape((P, C) + logits.shape[2:]), cache)
+    if "moe" in cfg.pattern_unit():
+        ctx["valid"] = jnp.concatenate([
+            dec_valid > 0,
+            (jnp.arange(C)[None, :] < pf_valid[:, None]).reshape(-1)])[None]
+    logits, cache, counts = _paged_stack(params, cfg, pack(xd, xp), cache,
+                                         ctx)
+    out = (logits[0, :B].reshape((B, 1) + logits.shape[2:]),
+           logits[0, B:].reshape((P, C) + logits.shape[2:]), cache)
+    return out + (counts,) if with_counts else out
 
 
 def _step_inputs(params, cfg: ModelConfig, tokens, lens):
@@ -549,19 +630,34 @@ def _step_inputs(params, cfg: ModelConfig, tokens, lens):
 
 def _paged_stack(params, cfg: ModelConfig, x, cache, ctx):
     """The layer stack of a paged step over residual ``x`` and its final
-    logits; returns (logits, the updated cache)."""
+    logits; returns (logits, the updated cache, counts). ``counts`` is
+    None for a model without MoE layers, else i32[3], this step's
+    routing counts over its layers: held assignments and assignments
+    held elsewhere (summed), and the most tokens on one held expert of
+    one layer."""
     unit = cfg.pattern_unit()
+    new_cache = {"lens": cache["lens"],
+                 "block_tables": cache["block_tables"]}
+    if "lead" in params:
+        def lead_body(x, xs):
+            bp, bc = xs
+            x, nc, _ = block_step_paged("attn", bp["b0"], cfg, x, ctx,
+                                        bc["b0"])
+            return constrain_residual(x), {"b0": nc}
+        x, new_cache["lead"] = _lead_layers(params, cache, lead_body, x)
 
     def unit_body(x, xs):
         unit_p, unit_cache = xs
-        new_caches = {}
+        new_caches, stats = {}, []
         for j, kind in enumerate(unit):
             bp = unit_p.get(f"b{j}")
-            x, nc = block_step_paged(kind, bp, cfg, x, ctx,
-                                     unit_cache[f"b{j}"])
+            x, nc, st = block_step_paged(kind, bp, cfg, x, ctx,
+                                         unit_cache[f"b{j}"])
             x = constrain_residual(x)
             new_caches[f"b{j}"] = nc
-        return x, new_caches
+            if st is not None:
+                stats.append(st)
+        return x, (new_caches, jnp.stack(stats) if stats else None)
 
     if cfg.unroll:
         # loop-free twin of the scan below (same math, same cache
@@ -576,18 +672,22 @@ def _paged_stack(params, cfg: ModelConfig, x, cache, ctx):
             u_c = jax.tree.map(lambda a: a[i], cache["units"])
             x, nc = unit_body(x, (u_p, u_c))
             new_unit_list.append(nc)
-        new_units = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                 *new_unit_list)
+        new_units, stats = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                        *new_unit_list)
     else:
-        x, new_units = jax.lax.scan(unit_body, x,
-                                    (params["units"], cache["units"]))
+        x, (new_units, stats) = jax.lax.scan(
+            unit_body, x, (params["units"], cache["units"]))
+    new_cache["units"] = new_units
+    counts = None
+    if stats is not None:
+        st = stats.reshape(-1, 3)
+        counts = jnp.stack(
+            [jnp.sum(st[:, 0]), jnp.sum(st[:, 1]), jnp.max(st[:, 2])])
     with jax.named_scope("logits"):
         x = constrain_tp_exact(
             layers.rms_norm(x, params["final_norm"], cfg.norm_eps))
         logits = project_logits(params, cfg, x)
-    return logits, {"lens": cache["lens"],
-                    "block_tables": cache["block_tables"],
-                    "units": new_units}
+    return logits, new_cache, counts
 
 
 def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
@@ -615,7 +715,10 @@ def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
     length up to the static ``k_max``, the emitted-buffer width).
 
     Returns (emitted i32[B, k_max] — -1 past each row's last live step,
-    logprobs f32[B, k_max], new_cache, final lens, n_emitted i32[B]).
+    logprobs f32[B, k_max], new_cache, final lens, n_emitted i32[B],
+    counts): ``counts`` is None for a model without MoE layers, else the
+    routing counts of ``_paged_stack`` over every step of the burst
+    (assignments summed, the largest load kept).
     The loop never advances the engine's committed state: the host
     replays ``emitted`` through the exact synchronous commit path, which
     is what keeps greedy output token-identical to the per-tick engine.
@@ -623,6 +726,7 @@ def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
     B = tok0.shape[0]
     L = hist0.shape[1]
     zeros_b = jnp.zeros((B,), bool)
+    moe = "moe" in cfg.pattern_unit()
     col_ids = jnp.arange(k_max)[None, :]
     pos_mask = jnp.arange(L)[None, None, :] < (L - stop_len[:, :, None])
 
@@ -633,9 +737,14 @@ def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
         cache = dict(c["cache"])
         cache["lens"] = c["lens"]
         cache["block_tables"] = tables
-        logits, cache = forward_step(
+        logits, cache, counts = forward_step(
             params, cfg, c["tok"][:, None], cache, c["alive"], zeros_b,
-            block_size, backend=backend, has_prefill=False)
+            block_size, backend=backend, has_prefill=False,
+            with_counts=True)
+        if moe:
+            counts = jnp.concatenate([c["counts"][:2] + counts[:2],
+                                      jnp.maximum(c["counts"][2:],
+                                                  counts[2:])])
         last = logits[:, 0].astype(jnp.float32)
         ntok, nlp = sample_fn(last, c["i"])
         ntok = ntok.astype(jnp.int32)
@@ -657,18 +766,20 @@ def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
                 "tok": jnp.where(live, ntok, c["tok"]),
                 "lens": c["lens"] + live.astype(c["lens"].dtype),
                 "hist": hist, "emitted": emitted, "lp": lp,
-                "alive": alive, "n_emit": n_emit, "i": c["i"] + 1}
+                "alive": alive, "n_emit": n_emit, "i": c["i"] + 1,
+                "counts": counts}
 
     init = {"cache": cache, "tok": tok0, "lens": lens0, "hist": hist0,
             "emitted": jnp.full((B, k_max), -1, jnp.int32),
             "lp": jnp.zeros((B, k_max), jnp.float32),
             "alive": alive0, "n_emit": jnp.zeros((B,), jnp.int32),
-            "i": jnp.asarray(0, jnp.int32)}
+            "i": jnp.asarray(0, jnp.int32),
+            "counts": jnp.zeros((3,), jnp.int32) if moe else None}
     fin = jax.lax.while_loop(cond, body, init)
     new_cache = dict(fin["cache"])
     new_cache["lens"] = fin["lens"]
     return (fin["emitted"], fin["lp"], new_cache, fin["lens"],
-            fin["n_emit"])
+            fin["n_emit"], fin["counts"])
 
 
 def project_logits(params, cfg: ModelConfig, x):

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 # scope names the model annotates (models/transformer.py,
 # models/ffn.py); "sample" is the sampler executable, "other" is the
 # unattributed remainder
-SCOPES = ("attn", "ffn_dense", "ffn_sparse", "logits", "sample")
+SCOPES = ("attn", "ffn_dense", "ffn_sparse", "moe_router", "moe_experts",
+          "logits", "sample")
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

@@ -408,6 +408,9 @@ class Engine:
             self.metrics.profiler = self.profiler
         self._kv_per_tok = paged_kv.kv_bytes_per_token(self.cfg,
                                                        scfg.kv_quant)
+        # MoE models: routing counts of steps not yet fetched (device
+        # i32[3] each; fetched with the next sampled tokens)
+        self._moe_pending: List = []
         if self.spec is not None:
             from repro import spec as spec_mod
             self.drafter = drafter if drafter is not None else \
@@ -702,6 +705,7 @@ class Engine:
                 pad_waste_frac=1.0 - valid_tokens / denom if denom
                 else 0.0)
         out = self.runner.step(batch)
+        self._note_moe_counts(out.moe_counts)
 
         # ---- 4) sample + commit ---------------------------------------
         sample_pairs: List[Tuple[int, Request]] = []
@@ -727,6 +731,7 @@ class Engine:
             with tr.span("sample_sync", rows=len(sample_pairs)):
                 tok_np, lp_np = self._sample_rows(sample_pairs,
                                                   out.last_logits)
+                self._fetch_moe_counts()
 
         # prefill rows: advance the frontier; a completing row emits its
         # first token (sampled with ITS params — no more greedy-only)
@@ -774,6 +779,30 @@ class Engine:
             else:
                 self._commit_verify(run_rows, proposals, out, finished)
         return finished
+
+    def _note_moe_counts(self, counts) -> None:
+        """Keep a dispatched step's routing counts (MoE models; None
+        otherwise) on the device until the next fetch."""
+        if counts is not None:
+            self._moe_pending.append(counts)
+
+    def _fetch_moe_counts(self) -> None:
+        """Fold the routing counts of the steps since the last fetch
+        into the metrics. Called right after the sampled tokens came
+        back, so the steps have finished and nothing waits: one small
+        transfer. A tick that samples nothing leaves its counts to the
+        next tick that does; the ``moe_held_assignments`` tick attribute
+        covers every step fetched (a burst's counts cover all of its
+        device steps)."""
+        if not self._moe_pending:
+            return
+        counts = jax.device_get(self._moe_pending)
+        self._moe_pending = []
+        for held, elsewhere, load in counts:
+            self.metrics.on_moe_counts(int(held), int(elsewhere),
+                                       int(load))
+        self.tracer.tick_attrs(moe_held_assignments=int(
+            sum(int(c[0]) for c in counts)))
 
     def _record_prompt_logprobs(self, e: SchedEntry, out, pos: int,
                                 valid: int) -> None:
@@ -1035,6 +1064,7 @@ class Engine:
             with tr.span("device_wait"):
                 tok_np = np.asarray(pend["tok"])
             lp_np = np.asarray(pend["lp"])
+            self._fetch_moe_counts()
         live = [e for e in pend["entries"]
                 if self.sched.active.get(e.req.rid) is e]
         prev = self._tick_overlap
@@ -1134,6 +1164,8 @@ class Engine:
                     "tick": getattr(tr, "n_ticks", 0),
                     "overlap": self._tick_overlap}
         finished = self._reconcile_pending()
+        # after the reconcile's fetch, which must not wait for this step
+        self._note_moe_counts(out.moe_counts)
         self._pending = new_pend
         self._async_stats["overlap_ticks"] += 1
         return finished
@@ -1236,8 +1268,9 @@ class Engine:
                     jnp.asarray(keys), jnp.asarray(temp),
                     jnp.asarray(top_k), jnp.asarray(top_p),
                     jnp.asarray(k_burst, jnp.int32))
-            em, lp, cache, _, n_emit = fn(*args)
+            em, lp, cache, _, n_emit, counts = fn(*args)
             self.runner.cache = cache
+            self._note_moe_counts(counts)
         finished: List[int] = []
         with tr.span("sample_sync", rows=len(rows),
                      reconciles_tick=getattr(tr, "n_ticks", 0)):
@@ -1245,6 +1278,7 @@ class Engine:
                 em_np = np.asarray(em)
             lp_np = np.asarray(lp)
             n_dev = np.asarray(n_emit)
+            self._fetch_moe_counts()
         iters_dev = int(n_dev.max()) if rows else 0
         self._loop_device_ticks += iters_dev
         self._async_stats["loop_bursts"] += 1
@@ -1509,14 +1543,16 @@ class Engine:
         return True
 
     def _merge_slot(self, cache, tmp, slot: int, prompt_len: int):
-        """Write tmp's single row into ``cache`` row ``slot``. Every unit
-        cache leaf has batch at axis 1 ([U, B, ...])."""
+        """Write tmp's single row into ``cache`` row ``slot``. Every
+        stacked cache leaf ("units", and "lead" for leading dense layers)
+        has batch at axis 1 ([U, B, ...])."""
         def one(c, t):
             return c.at[:, slot].set(t[:, 0].astype(c.dtype))
 
-        units = jax.tree.map(one, cache["units"], tmp["units"])
-        lens = cache["lens"].at[slot].set(prompt_len)
-        return {"lens": lens, "units": units}
+        out = {k: jax.tree.map(one, cache[k], tmp[k])
+               for k in ("units", "lead") if k in cache}
+        out["lens"] = cache["lens"].at[slot].set(prompt_len)
+        return out
 
     def _step_slots(self) -> List[int]:
         """One batched decode step across all active slots."""

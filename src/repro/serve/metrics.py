@@ -233,6 +233,15 @@ class MetricsCollector:
             "engine_mixed_ticks_total", "prefill_bucket",
             "grouped ticks (decode rows at width 1 beside a prefill "
             "group), by the prefill group's row bucket")
+        # --- MoE routing (models.moe.moe_serve; MoE models only) ---
+        self._c_moe = r.labeled_counter(
+            "engine_moe_assignments_total", "held",
+            "routed assignments of valid tokens, summed over MoE layers, "
+            "by whether this chip holds the expert")
+        self._g_moe_load = r.gauge(
+            "engine_moe_max_expert_load",
+            "most tokens on one held expert of one layer in the last "
+            "tick fetched")
         # --- prefix cache (serve.prefix_cache) ---
         self._c_plook = r.counter("prefix_lookups_total",
                                   "admissions that consulted the index")
@@ -386,6 +395,12 @@ class MetricsCollector:
 
     def on_mixed_tick(self, prefill_bucket: int):
         self._c_mixed.inc(str(prefill_bucket))
+
+    def on_moe_counts(self, held: int, elsewhere: int, max_load: int):
+        """One tick's routing counts (the step's ``moe_counts``)."""
+        self._c_moe.inc("true", held)
+        self._c_moe.inc("false", elsewhere)
+        self._g_moe_load.set(max_load)
 
     def on_tick(self, secs: float):
         self._h_tick.observe(secs)

@@ -37,10 +37,15 @@ from repro.configs.base import ModelConfig
 def kv_bytes_per_token(cfg: ModelConfig, int8_kv: bool = False) -> float:
     """Off-chip KV bytes one token adds across all attention layers.
     int8 KV (kv_cache.quantize_kv) stores 1 byte/element plus one f32
-    scale per (token, head) for each of K and V."""
+    scale per (token, head) for each of K and V. Latent attention
+    (``cfg.mla``) stores one latent of kv_lora_rank + qk_rope_head_dim
+    per layer in place of K and V."""
     n_attn = sum(1 for k in cfg.layer_kinds()
                  if k in ("attn", "shared_attn", "moe"))
     per_el = 1 if int8_kv else 2
+    if cfg.mla:
+        return float(n_attn * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                     * per_el)
     el = 2 * n_attn * cfg.n_kv_heads * cfg.d_head * per_el
     scales = 2 * n_attn * cfg.n_kv_heads * 4 if int8_kv else 0
     return float(el + scales)

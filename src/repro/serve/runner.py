@@ -95,15 +95,15 @@ def prefill_buckets(max_batch: int) -> List[int]:
 def uniform_step(model, block_size: int, backend: str, has_prefill: bool):
     """The step function of a uniform tick (every row at one width):
     (params, tokens, cache, n_valid, is_prefill) -> (logits, last-valid
-    logits per row, cache)."""
+    logits per row, cache, routing counts — None without MoE layers)."""
     def run(params, tokens, cache, n_valid, is_prefill):
-        logits, cache = model.forward_step(
+        logits, cache, counts = model.forward_step(
             params, tokens, cache, n_valid, is_prefill, block_size,
-            backend=backend, has_prefill=has_prefill)
+            backend=backend, has_prefill=has_prefill, with_counts=True)
         idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
         idx = idx.reshape((-1,) + (1,) * (logits.ndim - 1))
         last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-        return logits, last, cache
+        return logits, last, cache, counts
     return run
 
 
@@ -111,18 +111,20 @@ def grouped_step(model, block_size: int, backend: str):
     """The step function of a grouped tick (Model.forward_step_grouped):
     (params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot, pf_start,
     pf_valid) -> (decode logits, prefill logits, last-valid logits by
-    slot, cache). A prefill row's last logits land in its slot's row; a
-    padding row's slot id is out of range and its row drops."""
+    slot, cache, routing counts). A prefill row's last logits land in its
+    slot's row; a padding row's slot id is out of range and its row
+    drops."""
     def run(params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot,
             pf_start, pf_valid):
-        dec, pf, cache = model.forward_step_grouped(
+        dec, pf, cache, counts = model.forward_step_grouped(
             params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot,
-            pf_start, pf_valid, block_size, backend=backend)
+            pf_start, pf_valid, block_size, backend=backend,
+            with_counts=True)
         idx = jnp.clip(pf_valid - 1, 0, pf.shape[1] - 1)
         idx = idx.reshape((-1,) + (1,) * (pf.ndim - 1))
         pf_last = jnp.take_along_axis(pf, idx, axis=1)[:, 0]
         last = dec[:, 0].at[pf_slot].set(pf_last, mode="drop")
-        return dec, pf, last, cache
+        return dec, pf, last, cache, counts
     return run
 
 
@@ -210,6 +212,9 @@ class StepOutput:
     # prefill group's [P, C, V] and the slot of each of its rows
     prefill_logits: Optional[jax.Array] = None
     prefill_slots: Optional[np.ndarray] = None
+    # MoE models: the step's routing counts, i32[3] on the device
+    # (transformer._paged_stack's ``counts``)
+    moe_counts: Optional[jax.Array] = None
     _np: Optional[np.ndarray] = None
     _pf_np: Optional[np.ndarray] = None
 
@@ -248,6 +253,10 @@ class ModelRunner:
             raise ValueError(
                 "attn_backend='flash' reads fp block pools; int8 KV "
                 "(kv_quant) needs the naive dequantizing gather")
+        if cfg.mla and scfg.attn_backend == "flash":
+            raise ValueError(
+                "attn_backend='flash' reads k/v block pools; latent "
+                "attention's pool holds one latent leaf (naive only)")
         if mesh is not None and scfg.attn_backend != "naive":
             raise ValueError(
                 "sharded serving (ServeConfig.mesh) needs the GSPMD-"
@@ -349,7 +358,7 @@ class ModelRunner:
                 # consults it for the seq-sharded LSE decode path) and pin
                 # the cache's layout so it never drifts across ticks;
                 # logits come back replicated — sampling and the verify
-                # chain read them host-side.
+                # chain read them host-side, as do the routing counts.
                 base = run
 
                 def run(*args):
@@ -359,7 +368,8 @@ class ModelRunner:
                 run.__name__ = run.__qualname__ = name
                 n_logits = 3 if prefill_bucket else 2
                 fn = jax.jit(run, out_shardings=(
-                    (self._repl,) * n_logits + (self._cache_shardings,)))
+                    (self._repl,) * n_logits
+                    + (self._cache_shardings, self._repl)))
             else:
                 run.__name__ = run.__qualname__ = name
                 fn = jax.jit(run)
@@ -417,12 +427,13 @@ class ModelRunner:
             if P:
                 pf_slots = np.flatnonzero(batch.phase == PREFILL)
                 dec, pf, *rows = _grouped_args(batch, pf_slots)
-                logits, pf_logits, last, self.cache = self._fn(
+                logits, pf_logits, last, self.cache, counts = self._fn(
                     width, True, P)(self.params, dec, pf, self.cache, *rows)
             else:
                 toks = jnp.asarray(batch.tokens) if tokens is None \
                     else tokens
-                logits, last, self.cache = self._fn(width, has_prefill)(
+                logits, last, self.cache, counts = self._fn(
+                    width, has_prefill)(
                     self.params, toks, self.cache,
                     jnp.asarray(batch.n_valid),
                     jnp.asarray(batch.phase == PREFILL))
@@ -434,7 +445,8 @@ class ModelRunner:
             with tr.span("device_wait"):
                 jax.block_until_ready((logits, last))
         return StepOutput(logits=logits, last_logits=last,
-                          prefill_logits=pf_logits, prefill_slots=pf_slots)
+                          prefill_logits=pf_logits, prefill_slots=pf_slots,
+                          moe_counts=counts)
 
     # --- device-resident decode burst (async engine, docs/async.md) ---
     def decode_burst(self, sampled: bool, k_max: int):
@@ -491,9 +503,7 @@ class ModelRunner:
         sharding — the gather runs along the unsharded block axis, so
         every shard permutes its local head slice identically."""
         p = jnp.asarray(perm)
-        self.cache["units"] = jax.tree.map(
-            lambda a: jnp.take(a, p, axis=1), self.cache["units"])
-        self._pin_cache_sharding()
+        self._map_pools(lambda a: jnp.take(a, p, axis=1))
 
     def copy_blocks(self, pairs) -> None:
         """Copy-on-write: duplicate pool storage rows src -> dst across
@@ -507,9 +517,7 @@ class ModelRunner:
             return
         src = jnp.asarray([p[0] for p in pairs], jnp.int32)
         dst = jnp.asarray([p[1] for p in pairs], jnp.int32)
-        self.cache["units"] = jax.tree.map(
-            lambda a: a.at[:, dst].set(a[:, src]), self.cache["units"])
-        self._pin_cache_sharding()
+        self._map_pools(lambda a: a.at[:, dst].set(a[:, src]))
 
     def import_blocks_from(self, src: "ModelRunner", src_ids,
                            dst_ids) -> None:
@@ -524,19 +532,24 @@ class ModelRunner:
             return
         s = jnp.asarray(np.asarray(src_ids, np.int32))
         d = jnp.asarray(np.asarray(dst_ids, np.int32))
-        self.cache["units"] = jax.tree.map(
-            lambda a, b: a.at[:, d].set(b[:, s].astype(a.dtype)),
-            self.cache["units"], src.cache["units"])
-        self._pin_cache_sharding()
+        self._map_pools(lambda a, b: a.at[:, d].set(b[:, s].astype(a.dtype)),
+                        src.cache)
 
-    def _pin_cache_sharding(self) -> None:
-        """Re-commit the pool leaves to their mesh shardings after an
-        eager block-maintenance op (a no-op when GSPMD already kept the
-        layout, and on single-device runners)."""
-        if self._cache_shardings is not None:
-            self.cache["units"] = jax.tree.map(
-                jax.device_put, self.cache["units"],
-                self._cache_shardings["units"])
+    def _map_pools(self, fn, *others) -> None:
+        """Apply ``fn`` to every block-pool leaf (block axis 1 of each
+        stack: "units", and "lead" where the model has leading dense
+        layers), with the same leaves of ``others`` as extra arguments;
+        then re-commit the leaves to their mesh shardings (a no-op when
+        GSPMD already kept the layout, and on single-device runners)."""
+        for key in ("units", "lead"):
+            if key not in self.cache:
+                continue
+            self.cache[key] = jax.tree.map(
+                fn, self.cache[key], *(o[key] for o in others))
+            if self._cache_shardings is not None:
+                self.cache[key] = jax.tree.map(
+                    jax.device_put, self.cache[key],
+                    self._cache_shardings[key])
 
 
 __all__ = ["BACKENDS", "DECODE", "IDLE", "ModelRunner", "PREFILL",

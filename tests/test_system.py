@@ -103,7 +103,7 @@ def test_full_configs_match_assignment():
         "qwen3-4b": (36, 2560, 32, 8, 9728, 151936),
         "qwen2.5-3b": (36, 2048, 16, 2, 11008, 151936),
         "llama4-maverick-400b-a17b": (48, 5120, 40, 8, 8192, 202048),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonshot-v1-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
         "qwen2-vl-72b": (80, 8192, 64, 8, 29568, 152064),
         "zamba2-2.7b": (54, 2560, 32, 32, 10240, 32000),
         "musicgen-medium": (48, 1536, 24, 24, 6144, 2048),
@@ -119,6 +119,11 @@ def test_full_configs_match_assignment():
     assert (l4.n_experts, l4.top_k) == (128, 1)
     ms = get_config("moonshot-v1-16b-a3b")
     assert (ms.n_experts, ms.top_k) == (64, 6)
+    assert (ms.kv_lora_rank, ms.qk_nope_head_dim, ms.qk_rope_head_dim,
+            ms.v_head_dim) == (512, 128, 64, 128)
+    assert (ms.first_k_dense_replace, ms.dense_d_ff) == (1, 11264)
+    assert (ms.scoring_func, ms.router_bias, ms.routed_scaling_factor) \
+        == ("sigmoid", True, 2.446)
     assert get_config("zamba2-2.7b").ssm_state == 64
     assert get_config("qwen3-4b").qk_norm
     assert get_config("qwen2.5-3b").qkv_bias
