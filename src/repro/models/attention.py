@@ -237,6 +237,65 @@ def _read_paged(cache: dict, name: str, tables, n_blocks: int):
     return g.astype(jnp.float32) * s
 
 
+def _pool_leaf(cache: dict, name: str):
+    """Pool leaf ``name`` whole, int8 pools dequantized to f32 the way
+    _read_paged dequantizes what it gathers."""
+    if f"{name}_scale" not in cache:
+        return cache[name]
+    return cache[name].astype(jnp.float32) * cache[f"{name}_scale"]
+
+
+def reads_pool(cfg: ModelConfig, rows: int, table_width: int,
+               n_blocks: int, block_size: int, backend: str) -> bool:
+    """Whether a width-1 group of ``rows`` rows, each with a block table
+    of ``table_width`` columns, attends over the whole pool leaf
+    (_attend_pool) instead of gathering its tables: when the tables
+    span more blocks than the pool holds. The gather stays for the flash
+    kernel, for latent attention, and under a kv-seq mesh scope (the
+    LSE-combine path shards the gathered sequence). Static shapes only,
+    so the runner can tell which read a step executable takes."""
+    return (backend != "flash" and not cfg.mla
+            and rows * table_width > n_blocks
+            and _kv_seq_scope(table_width * block_size) is None)
+
+
+def _attend_pool(cfg: ModelConfig, qg, cache: dict, tables, lens,
+                 block_size: int):
+    """Single-token attention of qg [B, Kv, G, Dh] over every slot of the
+    pool: slot (blk, off) sits at position c * bs + off of row b when blk
+    is column c of tables[b], and is visible iff that position is below
+    lens[b] + 1, the gather path's kv_len. The map is per row, so a block
+    two rows share is visible to both. The pool enters the einsums with no
+    row axis: each layer reads it once for all rows, where the gather
+    reads rows x table columns blocks, most of them sentinels. Scores,
+    softmax and the output dot are reference_attention's. An all-sentinel
+    row sees nothing and gets a finite, discarded output. Returns o
+    [B, Kv, G, Dh] in qg's dtype."""
+    B, MB = tables.shape
+    k = _pool_leaf(cache, "k")
+    v = _pool_leaf(cache, "v")
+    n_blocks = k.shape[0]
+    # col[b, blk]: blk's column in row b's table; MB where the row holds
+    # no such block, which puts its slots past any row's kv_len
+    # (sentinel entries are out of range and drop)
+    col = jnp.full((B, n_blocks), MB, jnp.int32).at[
+        jnp.arange(B)[:, None], tables].set(
+        jnp.broadcast_to(jnp.arange(MB, dtype=jnp.int32), (B, MB)),
+        mode="drop")
+    pos = col[:, :, None] * block_size + jnp.arange(block_size)
+    vis = pos < (lens + 1)[:, None, None]
+    kf = k.reshape((n_blocks * block_size,) + k.shape[2:])
+    vf = v.reshape(kf.shape)
+    s = jnp.einsum("bkgd,nkd->bkgn",
+                   qg * jnp.asarray(cfg.d_head ** -0.5, qg.dtype), kf,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(vis.reshape(B, 1, 1, -1), s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgn,nkd->bkgd", probs.astype(vf.dtype), vf,
+                   preferred_element_type=jnp.float32)
+    return o.astype(qg.dtype)
+
+
 def _paged_slots(tables, lens, n_valid, width: int, block_size: int,
                  n_blocks: int):
     """Where a group's queries sit: ``gpos`` i32[B, W] absolute positions
@@ -269,6 +328,10 @@ def _attend_paged(cfg: ModelConfig, q, cache: dict, tables, lens, gpos,
             interpret=jax.default_backend() != "tpu")
         return o.reshape(B, S, cfg.n_heads * cfg.d_head).astype(dtype)
     qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
+    if S == 1 and reads_pool(cfg, B, tables.shape[1], n_blocks,
+                             block_size, backend):
+        o = _attend_pool(cfg, qg[:, 0], cache, tables, lens, block_size)
+        return o.reshape(B, S, cfg.n_heads * cfg.d_head)
     kg = _read_paged(cache, "k", tables, n_blocks)        # [B, MBbs, Kv, Dh]
     vg = _read_paged(cache, "v", tables, n_blocks)
     if S == 1:
@@ -332,8 +395,10 @@ def attn_step_paged(p, cfg: ModelConfig, x, cos, sin, cache: dict,
 
     ``backend`` picks the read path for EVERY row width: "naive" gathers
     each row's blocks into a logical sequence on the host-visible path
-    (the reference, GSPMD-shardable); "flash" hands q + the block pools +
-    the tables straight to the Pallas paged-attention kernel, which DMAs
+    (the reference, GSPMD-shardable), except that single-token rows whose
+    tables span more blocks than the pool holds attend over the whole
+    pool once (reads_pool, _attend_pool); "flash" hands q + the block
+    pools + the tables straight to the Pallas paged-attention kernel, which DMAs
     KV blocks via the table (kernels.decode_attn.paged_attention) with a
     per-query causal limit — no [B, MB*bs] gather materializes for
     decode (S=1), verify (S=K+1), or prefill-chunk rows alike.

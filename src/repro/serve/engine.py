@@ -697,6 +697,8 @@ class Engine:
             if P:
                 self.metrics.on_mixed_tick(P)
                 tr.tick_attrs(prefill_bucket=P, prefill_width=width)
+            if width == 1 or P:
+                self._note_kv_read()
             tr.tick_attrs(
                 rows_prefill=len(prefill_plan),
                 rows_decode=len(run_rows) if spec is None else 0,
@@ -779,6 +781,13 @@ class Engine:
             else:
                 self._commit_verify(run_rows, proposals, out, finished)
         return finished
+
+    def _note_kv_read(self) -> None:
+        """Count a tick whose step has a width-1 group under the way that
+        group reads the KV pool (runner.kv_read), and name it on the
+        tick."""
+        self.metrics.on_kv_read(self.runner.kv_read)
+        self.tracer.tick_attrs(kv_read=self.runner.kv_read)
 
     def _note_moe_counts(self, counts) -> None:
         """Keep a dispatched step's routing counts (MoE models; None
@@ -1131,6 +1140,7 @@ class Engine:
                           valid_tokens=len(rows),
                           pad_waste_frac=1.0 - len(rows) / denom,
                           device_ticks=1, async_mode="overlap")
+            self._note_kv_read()
         tokens = None
         if pend is not None and chain.any():
             tokens = jnp.where(jnp.asarray(chain)[:, None],
@@ -1251,6 +1261,7 @@ class Engine:
                           valid_tokens=len(rows),
                           pad_waste_frac=1.0 - len(rows) / denom,
                           async_mode="loop")
+            self._note_kv_read()
         fn = self.runner.decode_burst(sampled, K)
         with tr.span("device_dispatch", width=1, has_prefill=False,
                      loop_k=k_burst):

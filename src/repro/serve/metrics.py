@@ -233,6 +233,11 @@ class MetricsCollector:
             "engine_mixed_ticks_total", "prefill_bucket",
             "grouped ticks (decode rows at width 1 beside a prefill "
             "group), by the prefill group's row bucket")
+        self._c_kv_read = r.labeled_counter(
+            "engine_decode_kv_reads_total", "read",
+            "ticks with a width-1 group, by how it read the KV pool: "
+            "\"pool\" (the whole leaf once), \"gather\" (every "
+            "table column of every row) or \"kernel\" (flash)")
         # --- MoE routing (models.moe.moe_serve; MoE models only) ---
         self._c_moe = r.labeled_counter(
             "engine_moe_assignments_total", "held",
@@ -395,6 +400,9 @@ class MetricsCollector:
 
     def on_mixed_tick(self, prefill_bucket: int):
         self._c_mixed.inc(str(prefill_bucket))
+
+    def on_kv_read(self, read: str):
+        self._c_kv_read.inc(read)
 
     def on_moe_counts(self, held: int, elsewhere: int, max_load: int):
         """One tick's routing counts (the step's ``moe_counts``)."""

@@ -25,11 +25,13 @@ them — only the engine knows what actually committed, e.g. after
 speculative acceptance).
 
 The attention read path is pluggable (``ServeConfig.attn_backend``):
-"naive" gathers blocks into a logical sequence (reference, shardable);
-"flash" hands the block pools + tables to the Pallas paged-attention
-kernel (kernels.decode_attn.paged_attention), which covers every row
-width of the unified step — single-token decode, K+1 verify, and
-prefill chunks — with per-row causal masking resolved in-kernel.
+"naive" gathers blocks into a logical sequence (reference, shardable),
+except that a width-1 group whose tables span more blocks than the pool
+holds attends over the whole pool once (``kv_read``); "flash" hands the
+block pools + tables to the Pallas paged-attention kernel
+(kernels.decode_attn.paged_attention), which covers every row width of
+the unified step — single-token decode, K+1 verify, and prefill chunks
+— with per-row causal masking resolved in-kernel.
 
 Sharded serving (``mesh`` + ``policy``): the runner is the mesh-aware
 boundary. Weights shard over the 'model' axis (dist.sharding.
@@ -47,6 +49,7 @@ LSE-combine collective (dist.collectives).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional
 
@@ -56,6 +59,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.dist import sharding as shd
+from repro.models import attention
 from repro.obs.trace import NULL_TRACER
 
 # row phases (StepBatch.phase values)
@@ -286,6 +290,19 @@ class ModelRunner:
                                       self._cache_shardings)
             self._repl = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
+        # how every width-1 group (decode ticks, the decode group of a
+        # grouped tick, bursts) reads the pool: "pool" attends over the
+        # whole leaf, "gather" gathers every table column, "kernel" is
+        # the flash kernel's table walk. Fixed by the static shapes
+        # (attention.reads_pool), under the mesh scope the steps are
+        # traced in.
+        with shd.activation_sharding_scope(mesh, self.policy) \
+                if mesh is not None else contextlib.nullcontext():
+            pool = attention.reads_pool(
+                cfg, scfg.max_batch, scfg.blocks_per_seq, scfg.pool_blocks,
+                scfg.block_size, scfg.attn_backend)
+        self.kv_read = "kernel" if scfg.attn_backend == "flash" \
+            else "pool" if pool else "gather"
         self.buckets = sorted({1, scfg.prefill_chunk}
                               | ({scfg.spec.k_max + 1}
                                  if scfg.spec is not None else set()))

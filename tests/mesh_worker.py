@@ -90,6 +90,23 @@ def check_greedy(model: int, kv_seq: bool = False):
     assert bs["mesh"] == {}
 
 
+def check_pool_read(model: int):
+    """A pool smaller than max_batch x blocks_per_seq: width-1 groups
+    read the whole head-sharded pool under GSPMD, token-identical to the
+    single device and to the gathering default pool; under the kv-seq
+    scope the gather (and the LSE combine) stays."""
+    prompts = _prompts([5, 23, 9, 14], seed=0)
+    kw = dict(max_batch=3, max_seq=64, n_kv_blocks=16)
+    gathered, _ = _serve(prompts, max_batch=3, max_seq=64)
+    base, beng = _serve(prompts, **kw)
+    out, eng = _serve(prompts, mesh=MeshConfig(model=model), **kw)
+    assert beng.runner.kv_read == eng.runner.kv_read == "pool"
+    assert out == base == gathered, (gathered, base, out)
+    _assert_shard_consistent(eng, model)
+    seq = _engine(mesh=MeshConfig(model=model, shard_kv_seq=True), **kw)
+    assert seq.runner.kv_read == "gather"
+
+
 def check_spec_prefix(model: int):
     """Speculation (ngram drafter) + radix prefix cache on shared-prefix
     traffic: verify/rollback through SHARED blocks stays token-identical
@@ -199,6 +216,7 @@ CHECKS = {
     "greedy4_kvseq": lambda: (check_greedy(4), check_greedy(4,
                                                             kv_seq=True)),
     "spec_prefix4": lambda: check_spec_prefix(4),
+    "pool_read2": lambda: check_pool_read(2),
     "cow_int8_2": lambda: (check_cow(2), check_int8(2)),
     "bf16_norm4": lambda: check_bf16_norm(4),
 }

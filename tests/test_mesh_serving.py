@@ -1,7 +1,8 @@
 """Sharded serving (ServeConfig.mesh): greedy token-identity to the
 single-device paged engine on a forced 4-device host mesh — plain
-decode, speculation + prefix sharing, copy-on-write, int8 KV, and the
-seq-sharded LSE-combine decode path — plus metrics shard-consistency.
+decode, speculation + prefix sharing, copy-on-write, int8 KV, the
+seq-sharded LSE-combine decode path, and the whole-pool width-1 read —
+plus metrics shard-consistency.
 
 Each case runs tests/mesh_worker.py in a subprocess so the forced device
 count doesn't leak into other tests (same pattern as test_dist.py); the
@@ -20,7 +21,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.mark.parametrize("check", ["greedy2", "greedy4_kvseq",
                                    "spec_prefix4", "cow_int8_2",
-                                   "bf16_norm4"])
+                                   "bf16_norm4", "pool_read2"])
 def test_mesh_serving_4dev(check):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")

@@ -166,6 +166,104 @@ def test_grouped_step_matches_uniform_step(request, model_name, backend,
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
 
 
+# ---------------------------------------------------------------------------
+# width-1 read path: the whole pool against the gathered tables
+
+_S = 99    # stands for the sentinel block id (the case's n_blocks)
+# block tables (3 rows x 4 columns of 4 tokens), each row's lens (its
+# query's position) and the pool size; 3 x 4 = 12 > 10 blocks takes the
+# pool read, 12 blocks the gather
+_POOL_CASES = {
+    "f32": ([[0, 1, 2, _S], [3, 4, _S, _S], [5, 6, 7, 8]], [9, 5, 15], 10),
+    "int8": ([[0, 1, 2, _S], [3, 4, _S, _S], [5, 6, 7, 8]], [9, 5, 15], 10),
+    "shared_blocks": ([[0, 1, 2, _S], [0, 1, 3, _S], [4, 5, _S, _S]],
+                      [10, 9, 6], 10),
+    "idle_row": ([[0, 1, _S, _S], [_S] * 4, [2, 3, 4, _S]], [6, 0, 11], 10),
+    "lens_at_edges": ([[0, _S, _S, _S], [1, 2, _S, _S], [3, 4, 5, 6]],
+                      [3, 4, 15], 10),
+    "span_equals_pool": ([[0, 1, 2, _S], [3, 4, _S, _S], [5, 6, 7, 8]],
+                         [9, 5, 15], 12),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_POOL_CASES))
+def test_pool_read_matches_gather_read(qwen_smoke, case):
+    """A width-1 group whose tables span more blocks than the pool holds
+    attends over the whole pool leaf with a per-row visibility mask; its
+    output equals the gathered read's (f32 tolerance), for f32 and int8
+    pools, blocks two rows share, and lens at bs - 1, bs and the last
+    column. An all-sentinel row gives a finite output. When the tables
+    span exactly the pool, the gather stays."""
+    from repro.models import attention
+    from repro.models.flash import reference_attention
+    from repro.serve.kv_cache import quantize_kv
+    cfg, _, _ = qwen_smoke
+    rows, lens, n_blocks = _POOL_CASES[case]
+    bs, B, MB = 4, len(rows), len(rows[0])
+    tables = jnp.asarray(np.where(np.asarray(rows) == _S, n_blocks, rows),
+                         jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
+    shape = (n_blocks, bs, cfg.n_kv_heads, cfg.d_head)
+    k = jax.random.normal(kk, shape)
+    v = jax.random.normal(kv, shape)
+    if case == "int8":
+        (k8, ks), (v8, vs) = quantize_kv(k, v)
+        cache = {"k": k8, "v": v8, "k_scale": ks, "v_scale": vs}
+    else:
+        cache = {"k": k, "v": v}
+    q = jax.random.normal(kq, (B, 1, cfg.n_heads, cfg.d_head))
+
+    pool = attention.reads_pool(cfg, B, MB, n_blocks, bs, "naive")
+    assert pool == (case != "span_equals_pool")
+    o = attention._attend_paged(cfg, q, cache, tables, lens, lens[:, None],
+                                bs, "naive", q.dtype)
+    kg = attention._read_paged(cache, "k", tables, n_blocks)
+    vg = attention._read_paged(cache, "v", tables, n_blocks)
+    qg = q.reshape(B, 1, cfg.n_kv_heads, cfg.q_per_kv, cfg.d_head)
+    ref = reference_attention(qg, kg, vg, causal=False,
+                              kv_len=lens + 1).reshape(o.shape)
+    live = np.asarray([r != [_S] * MB for r in rows])
+    assert np.all(np.isfinite(np.asarray(o)))
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(ref)[live],
+                               rtol=1e-5, atol=1e-5)
+
+
+def _decode_step_hlo(runner):
+    """The lowered text of the runner's width-1 decode step."""
+    B = runner.scfg.max_batch
+    return runner._fn(1, False).lower(
+        runner.params, jnp.zeros((B, 1), jnp.int32), runner.cache,
+        jnp.ones((B,), jnp.int32), jnp.zeros((B,), bool)).as_text()
+
+
+@pytest.mark.parametrize("n_kv_blocks,read", [(30, "pool"), (0, "gather")])
+def test_pool_read_engine_token_identical_to_contiguous(nectar, n_kv_blocks,
+                                                        read):
+    """A pool smaller than max_batch x blocks_per_seq (and large enough
+    that nothing is preempted): every width-1 group reads the whole pool,
+    greedy output equals the contiguous-cache engine's, the engine counts
+    each tick with a width-1 group under its read, and the lowered decode
+    step gathers no [B, MB*bs, Kv, Dh] K/V. The default pool keeps the
+    gather."""
+    cfg, _, params = nectar
+    prompts = _prompts(cfg, [5, 37, 9, 60, 3, 21])
+    kw = dict(max_batch=3, max_seq=96, block_size=8, prefill_chunk=16)
+    legacy, _ = _serve(cfg, params, prompts, paged=False, **kw)
+    paged, eng = _serve(cfg, params, prompts, paged=True,
+                        n_kv_blocks=n_kv_blocks, **kw)
+    assert eng.sched.n_preemptions == 0
+    assert legacy == paged
+    assert eng.runner.kv_read == read
+    # every step of this engine has a width-1 group (decode or grouped)
+    reads = eng.metrics.registry.labeled_counter(
+        "engine_decode_kv_reads_total", "read").values
+    assert reads == {read: eng.runner.n_steps} and eng.runner.n_steps > 0
+    B, MB, bs = 3, eng.scfg.blocks_per_seq, 8
+    gathered = f"tensor<{B}x{MB * bs}x{cfg.n_kv_heads}x{cfg.d_head}xf32>"
+    assert (gathered in _decode_step_hlo(eng.runner)) == (read == "gather")
+
+
 def test_grouped_engine_token_identical_to_contiguous(nectar):
     """Overlapping prompts on four slots: ticks with one to four prefill
     rows run grouped, and greedy output equals the contiguous-cache

@@ -66,13 +66,10 @@ def test_paged_attention_compiles_for_v5e(one_chip, kv, group, dh, dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("backend", ["naive", "flash"])
-def test_llama_paged_step_fits_one_v5e(one_chip, backend, monkeypatch):
+def _compile_llama_step(one_chip, backend, n_blocks):
     """The llama3.2-1b decode step (S=1, batch 8, max_seq 2048, block 16,
-    the engine's f32 pool) compiles for one chip and fits its HBM."""
-    # the attention call site picks the Pallas interpreter from the
-    # process's backend, which is the CPU here: steer it to the chip's
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    the engine's f32 pool of ``n_blocks`` blocks) compiled for one chip;
+    returns it with the bytes it needs."""
     cfg = get_config("llama3.2-1b")
     model = Model(cfg)
     B, bs, max_seq = 8, 16, 2048
@@ -84,7 +81,7 @@ def test_llama_paged_step_fits_one_v5e(one_chip, backend, monkeypatch):
 
     params = placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     cache = placed(jax.eval_shape(
-        lambda: model.init_paged_cache(B, B * mb, bs, mb, jnp.float32)))
+        lambda: model.init_paged_cache(B, n_blocks, bs, mb, jnp.float32)))
 
     def step(params, tokens, cache, n_valid, is_prefill):
         return model.forward_step(params, tokens, cache, n_valid,
@@ -98,6 +95,27 @@ def test_llama_paged_step_fits_one_v5e(one_chip, backend, monkeypatch):
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    return compiled, total
+
+
+@pytest.mark.parametrize("backend", ["naive", "flash"])
+def test_llama_paged_step_fits_one_v5e(one_chip, backend, monkeypatch):
+    """The llama3.2-1b decode step at the default pool (batch x
+    blocks_per_seq blocks) compiles for one chip and fits its HBM."""
+    # the attention call site picks the Pallas interpreter from the
+    # process's backend, which is the CPU here: steer it to the chip's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, total = _compile_llama_step(one_chip, backend, 8 * 128)
     assert total < HBM_BYTES, total
     if backend == "flash":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_llama_pool_read_step_fits_one_v5e(one_chip):
+    """With a pool of a quarter of batch x blocks_per_seq blocks the
+    decode step reads the whole pool once a layer: it compiles for one
+    chip, fits, and holds no [B*MB, bs, Kv, Dh] gather of the tables."""
+    compiled, total = _compile_llama_step(one_chip, "naive", 256)
+    assert total < HBM_BYTES, total
+    # the gather's [B*MB, bs, Kv, Dh] blocks, in f32 or converted to bf16
+    assert "[1024,16,8,64]" not in compiled.as_text()
