@@ -198,7 +198,7 @@ def current_scope() -> Optional[Tuple[Mesh, ShardingPolicy]]:
     """The active (mesh, policy) activation-sharding scope, or None.
 
     Model code consults this at TRACE time to pick sharded code paths
-    (e.g. attention.attn_step_paged routes single-token decode through
+    (e.g. attention.attn_step_grouped routes single-token decode through
     the LSE-combine collective when policy.shard_kv_seq) — the scope is a
     host-side global, so whatever is active while jit traces is what the
     compiled program bakes in."""

@@ -68,32 +68,17 @@ class Model:
                                             block_size, max_blocks_per_seq,
                                             dtype, int8_kv=int8_kv)
 
-    def forward_step(self, params, tokens, cache, n_valid, is_prefill,
-                     block_size: int, backend: str = "naive",
-                     has_prefill: bool = True, with_counts: bool = False):
-        """THE paged serving entry: one fixed-shape batched step serving
-        prefill, decode, and spec-verify rows together — everything the
-        three per-phase entries (decode_step_paged / verify_step_paged /
-        prefill_chunk) used to do, behind serve.runner.ModelRunner."""
+    def forward_step(self, params, tokens, pf_tokens, cache, n_valid,
+                     pf_slot, pf_start, pf_valid, block_size: int,
+                     backend: str = "naive"):
+        """THE paged serving entry: one step of a slot-indexed generation
+        group (decode and verify rows) and an optional prefill group in
+        one pass (transformer.forward_step), behind
+        serve.runner.ModelRunner."""
         with jax.default_matmul_precision(self._serving_precision):
-            return transformer.forward_step(params, self.cfg, tokens,
-                                            cache, n_valid, is_prefill,
-                                            block_size, backend=backend,
-                                            has_prefill=has_prefill,
-                                            with_counts=with_counts)
-
-    def forward_step_grouped(self, params, dec_tokens, pf_tokens, cache,
-                             dec_valid, pf_slot, pf_start, pf_valid,
-                             block_size: int, backend: str = "naive",
-                             with_counts: bool = False):
-        """A tick with prefill chunks as two row groups in one pass: a
-        width-1 decode group by slot and a chunk-wide prefill group
-        (transformer.forward_step_grouped)."""
-        with jax.default_matmul_precision(self._serving_precision):
-            return transformer.forward_step_grouped(
-                params, self.cfg, dec_tokens, pf_tokens, cache, dec_valid,
-                pf_slot, pf_start, pf_valid, block_size, backend=backend,
-                with_counts=with_counts)
+            return transformer.forward_step(
+                params, self.cfg, tokens, pf_tokens, cache, n_valid,
+                pf_slot, pf_start, pf_valid, block_size, backend=backend)
 
     def decode_burst(self, params, cache, tables, tok0, lens0, alive0,
                      budget, stops, stop_len, hist0, sample_fn,

@@ -374,62 +374,9 @@ def _project_out(p, o):
     return constrain_tp_exact(o @ p["wo"])
 
 
-def attn_step_paged(p, cfg: ModelConfig, x, cos, sin, cache: dict,
-                    lens: jax.Array, n_valid: jax.Array,
-                    tables: jax.Array, block_size: int,
-                    backend: str = "naive"):
-    """ONE attention entry for every serving phase, through block tables.
-
-    Row b's queries sit at absolute positions lens[b]+j for j in [0, S);
-    their KV scatters through the row's block table (positions j >=
-    n_valid[b] are padding: sentinel writes drop) and each query attends
-    causally to [0, lens[b]+j] — prior context plus the in-flight prefix
-    before it. The same masking serves all three phases:
-
-      * decode row   (S row slice = 1 valid token): queries at lens[b],
-        attends to lens[b]+1 keys — the classic paged decode step;
-      * verify row   (n_valid = 1 + K drafts): K+1 token scores per
-        target weight-stream read (speculative decode, paper Table II);
-      * prefill row  (n_valid = chunk valid length, lens[b] = chunk pos):
-        chunked prefill attending to earlier chunks plus its own prefix.
-
-    ``backend`` picks the read path for EVERY row width: "naive" gathers
-    each row's blocks into a logical sequence on the host-visible path
-    (the reference, GSPMD-shardable), except that single-token rows whose
-    tables span more blocks than the pool holds attend over the whole
-    pool once (reads_pool, _attend_pool); "flash" hands q + the block
-    pools + the tables straight to the Pallas paged-attention kernel, which DMAs
-    KV blocks via the table (kernels.decode_attn.paged_attention) with a
-    per-query causal limit — no [B, MB*bs] gather materializes for
-    decode (S=1), verify (S=K+1), or prefill-chunk rows alike.
-
-    x: [B, S, d]; lens/n_valid: i32[B]; tables: i32[B, MB] (inactive rows
-    all-sentinel). Returns (out [B, S, d], new_cache).
-    """
-    B, S, d = x.shape
-    if cfg.mla:
-        def packed(a):
-            return a.reshape((1, B * S) + a.shape[2:])
-        out, new_cache = _mla_step(
-            p, cfg, packed(x), packed(cos), packed(sin), cache,
-            (PagedGroup(lens, n_valid, tables, S),), block_size)
-        return out.reshape(B, S, d), new_cache
-    n_blocks = cache["k"].shape[0]
-    q, k, v = _qkv(p, cfg, x)
-    q = rope.apply_rope(q, cos, sin)
-    k = rope.apply_rope(k, cos, sin)
-    gpos, blk, off = _paged_slots(tables, lens, n_valid, S, block_size,
-                                  n_blocks)
-    new_cache = {**_store_paged(cache, "k", blk, off, k),
-                 **_store_paged(cache, "v", blk, off, v)}
-    o = _attend_paged(cfg, q, new_cache, tables, lens, gpos, block_size,
-                      backend, x.dtype)
-    return _project_out(p, o), new_cache
-
-
 class PagedGroup(NamedTuple):
-    """One row group of a grouped step: ``width`` tokens for each of its
-    rows, row b at absolute positions lens[b]+j, n_valid[b] of them real,
+    """One row group of a step: ``width`` tokens for each of its rows,
+    row b at absolute positions lens[b]+j, n_valid[b] of them real,
     reading and writing through tables[b] (all-sentinel when idle)."""
     lens: jax.Array
     n_valid: jax.Array
@@ -437,39 +384,78 @@ class PagedGroup(NamedTuple):
     width: int
 
 
+def _split_groups(t, groups):
+    """Each group's [rows, width, ...] part of ``t``: ``t`` itself for
+    one group, else a slice of the packed [1, N, ...] tokens."""
+    if len(groups) == 1:
+        return [t]
+    out, i = [], 0
+    for g in groups:
+        rows = g.lens.shape[0]
+        n = rows * g.width
+        out.append(t[0, i:i + n].reshape((rows, g.width) + t.shape[2:]))
+        i += n
+    return out
+
+
+def _join_groups(parts):
+    """The groups' [rows, width, ...] parts in the step's token layout:
+    one group's part as it is, several flattened row-major and
+    concatenated in order ([N, ...])."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([a.reshape((-1,) + a.shape[2:]) for a in parts])
+
+
 def attn_step_grouped(p, cfg: ModelConfig, x, cos, sin, cache: dict,
                       groups, block_size: int, backend: str = "naive"):
-    """attn_step_paged over several row groups of different widths in one
-    pass: the projections run once on the packed tokens, the KV of every
-    group scatters into the pool in one store, and attention runs per
-    group at that group's own width (a width-1 decode group takes the
-    S == 1 path, a chunk-wide prefill group the causal one).
+    """ONE attention entry for every serving tick, through block tables.
 
-    x: [1, N, d] — the groups' tokens packed in order, each group's rows
-    row-major (N = sum of rows x width); cos/sin packed the same way.
-    Returns (out [1, N, d], new_cache)."""
+    Row b of a group feeds ``width`` queries at absolute positions
+    lens[b]+j; their KV scatters through the row's block table
+    (positions j >= n_valid[b] are padding: sentinel writes drop) and
+    each query attends causally to [0, lens[b]+j] — prior context plus
+    the in-flight prefix before it. The same masking serves a decode row
+    (one token), a verify row (1 + K drafts, K+1 scores per weight-stream
+    read) and a prefill chunk attending to earlier chunks plus its own
+    prefix.
+
+    The projections run once on every group's tokens, all groups' KV
+    lands in the pool in one store, and attention runs per group at the
+    group's own width (_attend_paged: a width-1 group reads the pool
+    whole or gathers its tables, a wider group attends causally;
+    ``backend`` "flash" hands every width to the Pallas kernel).
+
+    x: [rows, width, d] for one group, or [1, N, d] with several groups'
+    tokens packed in order, each group's rows row-major (N = sum of rows
+    x width); cos/sin laid out the same way. Returns (out shaped like x,
+    new_cache)."""
     if cfg.mla:
-        return _mla_step(p, cfg, x, cos, sin, cache, groups, block_size)
+        def packed(a):
+            return a.reshape((1, -1) + a.shape[2:])
+        out, new_cache = _mla_step(p, cfg, packed(x), packed(cos),
+                                   packed(sin), cache, groups, block_size)
+        return out.reshape(x.shape), new_cache
     n_blocks = cache["k"].shape[0]
     q, k, v = _qkv(p, cfg, x)
     q = rope.apply_rope(q, cos, sin)
     k = rope.apply_rope(k, cos, sin)
     slots = [_paged_slots(g.tables, g.lens, g.n_valid, g.width,
                           block_size, n_blocks) for g in groups]
-    blk = jnp.concatenate([b.reshape(-1) for _, b, _ in slots])
-    off = jnp.concatenate([o.reshape(-1) for _, _, o in slots])
-    new_cache = {**_store_paged(cache, "k", blk, off, k[0]),
-                 **_store_paged(cache, "v", blk, off, v[0])}
-    outs, i = [], 0
-    for g, (gpos, _, _) in zip(groups, slots):
-        rows = g.lens.shape[0]
-        n = rows * g.width
-        qg = q[0, i:i + n].reshape(rows, g.width, *q.shape[2:])
-        o = _attend_paged(cfg, qg, new_cache, g.tables, g.lens, gpos,
+    blk = _join_groups([b for _, b, _ in slots])
+    off = _join_groups([o for _, _, o in slots])
+    new_cache = {
+        **_store_paged(cache, "k", blk, off,
+                       k.reshape(blk.shape + k.shape[2:])),
+        **_store_paged(cache, "v", blk, off,
+                       v.reshape(blk.shape + v.shape[2:]))}
+    outs = [_attend_paged(cfg, qg, new_cache, g.tables, g.lens, gpos,
                           block_size, backend, x.dtype)
-        outs.append(o.reshape(n, -1))
-        i += n
-    return _project_out(p, jnp.concatenate(outs)[None]), new_cache
+            for g, qg, (gpos, _, _) in zip(groups, _split_groups(q, groups),
+                                          slots)]
+    o = _join_groups(outs)
+    return _project_out(p, o.reshape(x.shape[:2] + o.shape[-1:])), \
+        new_cache
 
 
 # ---------------------------------------------------------------------------

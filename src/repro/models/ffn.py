@@ -44,26 +44,22 @@ def ffn_decode(p, cfg: ModelConfig, x):
         x, p["w_up"], p["w_down"], k=k, act="relu", w_gate=p.get("w_gate"))
 
 
-def ffn_step(p, cfg: ModelConfig, x, is_prefill, has_prefill: bool = True,
-             n_decode: int | None = None):
-    """Per-row FFN select for the unified batched step (ModelRunner):
-    prefill rows take the dense path, decode/verify rows take the sparse
-    decode path — in the SAME batch. x: [B, S, d]; is_prefill: bool[B].
+def ffn_step(p, cfg: ModelConfig, x, n_gen: int | None = None):
+    """The FFN of the serving step (ModelRunner), split by row group:
+    generation tokens (decode and verify rows) take the sparse decode
+    path — verify must score each position with EXACTLY the decode-step
+    math or greedy spec output would drift from the non-speculative
+    engine — and prefill tokens the dense one.
 
-    ``has_prefill`` is STATIC (the runner keys its jit on it): ticks with
-    no prefill row — the serving steady state — compile to the pure
-    sparse decode path and never touch the dense W_down stream, which is
-    the weight traffic the paper's sparsity exists to avoid. Mixed ticks
-    compute both branches from one shared hidden activation ``h`` (the
-    up/gate matmuls are common), so the select costs one extra
-    down-projection, not a second full FFN; each branch's expression is
-    exactly ``dense_ffn`` / ``gathered_sparse_ffn``, which is what keeps
-    unified-step output token-identical to the split per-phase engines.
-
-    ``n_decode`` (static) is the grouped step's split in place of the
-    per-row select: x is [1, N, d] with the decode group's n_decode
-    tokens first and the prefill group's after them; each part runs only
-    its own down-projection.
+    ``n_gen`` (static) is None when x holds generation tokens only (no
+    prefill group): the step then compiles to the pure sparse decode
+    path and never streams the dense W_down, which is the weight traffic
+    the paper's sparsity exists to avoid. Otherwise x is [1, N, d], the
+    generation group's n_gen tokens first and the prefill group's after
+    them: both parts share the up/gate hidden activation and each runs
+    only its own down-projection, each exactly ``gathered_sparse_ffn`` /
+    ``dense_ffn``, which keeps a step with both groups token-identical to
+    ticks run phase by phase.
     """
     # bit-reproducible layout (exact_tp, identity off-scope): the hidden
     # activation all-gathers before the down-projection so the contraction
@@ -73,25 +69,19 @@ def ffn_step(p, cfg: ModelConfig, x, is_prefill, has_prefill: bool = True,
     #
     # named_scope: the profiling contract (obs.costmodel attributes HLO
     # op cost by scope name). "ffn_dense" covers the dense MXU path plus
-    # the mixed-tick shared up/gate hidden; "ffn_sparse" the gathered
+    # the two groups' shared up/gate hidden; "ffn_sparse" the gathered
     # decode path. Metadata only — no math change.
     if not cfg.relu_sparse:
         with jax.named_scope("ffn_dense"):
             return constrain_tp_exact(ffn_forward(p, cfg, x))
-    if not has_prefill:
+    if n_gen is None:
         with jax.named_scope("ffn_sparse"):
             return constrain_tp_exact(ffn_decode(p, cfg, x))
     with jax.named_scope("ffn_dense"):
         h = sparsity.ffn_hidden(x, p["w_up"], "relu", p.get("w_gate"))
         h = constrain_tp_exact(h)
-        h_d = h if n_decode is None else h[:, n_decode:]
-        down_d = sparsity.down_dense(h_d, p["w_down"])
+        down_d = sparsity.down_dense(h[:, n_gen:], p["w_down"])
     k = sparsity.active_fraction_to_k(cfg.d_ff, cfg.sparse_k_frac)
     with jax.named_scope("ffn_sparse"):
-        h_s = h if n_decode is None else h[:, :n_decode]
-        down_s = sparsity.down_sparse(h_s, p["w_down"], k)
-    if n_decode is not None:
-        return constrain_tp_exact(
-            jnp.concatenate([down_s, down_d], axis=1))
-    return constrain_tp_exact(
-        jnp.where(is_prefill[:, None, None], down_d, down_s))
+        down_s = sparsity.down_sparse(h[:, :n_gen], p["w_down"], k)
+    return constrain_tp_exact(jnp.concatenate([down_s, down_d], axis=1))

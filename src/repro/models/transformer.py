@@ -108,17 +108,14 @@ def block_forward(kind, p, cfg: ModelConfig, x, ctx,
 
 
 def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
-    """ONE per-block body for every serving phase through block tables
-    (the unified ModelRunner step). Only attention-family blocks carry a
-    paged cache; recurrent blocks (O(1) state) have nothing to page.
+    """ONE per-block body for every serving tick through block tables
+    (the ModelRunner step). Only attention-family blocks carry a paged
+    cache; recurrent blocks (O(1) state) have nothing to page.
 
-    The FFN path is selected PER ROW (ctx["is_prefill"]): prefill rows
-    take the dense path, decode/verify rows the sparse-gather decode path
-    — verify must score each position with EXACTLY the decode-step math
-    (sparse gather under relu_sparse) or greedy spec output would drift
-    from the non-speculative engine. A grouped step (ctx["groups"],
-    forward_step_grouped) selects by group instead: x is [1, N, d], the
-    decode group's ctx["n_decode"] tokens first.
+    ctx["groups"] are the step's row groups (attention.attn_step_grouped);
+    the FFN splits by group (ffn.ffn_step): the generation group's
+    ctx["n_gen"] tokens come first in a packed x, and None means x holds
+    the generation group alone.
 
     An MoE block takes the drop-free serving dispatch over the held
     experts (moe.moe_serve), padding positions (ctx["valid"] False)
@@ -127,7 +124,7 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
 
     The ``jax.named_scope`` annotations ("attn", "ffn_dense",
     "ffn_sparse" inside ffn_step, "moe_router" / "moe_experts" /
-    "ffn_dense" inside moe_serve, "logits" in forward_step) are the
+    "ffn_dense" inside moe_serve, "logits" in _paged_stack) are the
     profiling contract (obs.costmodel): scope names survive into the
     compiled HLO op metadata, which is how per-scope FLOP/byte
     attribution in the roofline attainment report is computed. They add
@@ -143,16 +140,9 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
         with jax.named_scope("attn"):
             h = constrain_tp_exact(layers.rms_norm(x, p["norm1"],
                                                    cfg.norm_eps))
-            if "groups" in ctx:
-                a, new_cache = attention.attn_step_grouped(
-                    p["attn"], cfg, h, ctx["cos"], ctx["sin"], cache,
-                    ctx["groups"], ctx["block_size"],
-                    backend=ctx["backend"])
-            else:
-                a, new_cache = attention.attn_step_paged(
-                    p["attn"], cfg, h, ctx["cos"], ctx["sin"], cache,
-                    ctx["lens"], ctx["n_valid"], ctx["tables"],
-                    ctx["block_size"], backend=ctx["backend"])
+            a, new_cache = attention.attn_step_grouped(
+                p["attn"], cfg, h, ctx["cos"], ctx["sin"], cache,
+                ctx["groups"], ctx["block_size"], backend=ctx["backend"])
             x = x + a
         h = constrain_tp_exact(layers.rms_norm(x, p["norm2"],
                                                cfg.norm_eps))
@@ -160,9 +150,7 @@ def block_step_paged(kind, p, cfg: ModelConfig, x, ctx, cache: dict):
         if kind == "moe":
             y, stats = moe.moe_serve(p["moe"], cfg, h, ctx["valid"])
         else:
-            y = ffn.ffn_step(p["ffn"], cfg, h, ctx.get("is_prefill"),
-                             has_prefill=ctx["has_prefill"],
-                             n_decode=ctx.get("n_decode"))
+            y = ffn.ffn_step(p["ffn"], cfg, h, ctx["n_gen"])
         return x + y, new_cache, stats
     raise ValueError(f"paged step requires attention blocks, got {kind!r}")
 
@@ -508,106 +496,87 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, batch_extra=None):
     return logits, new_cache
 
 
-def forward_step(params, cfg: ModelConfig, tokens, cache, n_valid,
-                 is_prefill, block_size: int, backend: str = "naive",
-                 has_prefill: bool = True, with_counts: bool = False):
-    """THE serving entry point: one fixed-shape batched step through block
-    tables serving chunked-prefill rows, decode rows, and speculative
-    K+1 verify rows in the SAME batch (the ModelRunner contract).
+def forward_step(params, cfg: ModelConfig, tokens, pf_tokens, cache,
+                 n_valid, pf_slot, pf_start, pf_valid, block_size: int,
+                 backend: str = "naive"):
+    """THE serving entry point: one fixed-shape step through block tables
+    (the ModelRunner contract), as two row groups in ONE pass over the
+    layers.
 
-    Row b feeds ``n_valid[b]`` tokens (0 = inactive row) at absolute
-    positions cache["lens"][b] + j; their KV scatters through the row's
-    block table (padding past n_valid drops at the sentinel) and every
-    position's logits come back: logits[b, j] is the model's distribution
-    for the token FOLLOWING tokens[b, j]. So
+      * generation group: tokens [B, W], row b at slot b, at positions
+        cache["lens"][b] + j, n_valid[b] of them real (0 for every slot
+        that is idle here: sentinel tables, nothing written). W is 1 for
+        decode rows and K+1 for verify rows;
+      * prefill group (absent when ``pf_tokens`` is None; the pf_*
+        arguments are then None too): pf_tokens [P, C], row i for slot
+        pf_slot[i] (an id >= B pads the group: sentinel tables, nothing
+        written) at positions pf_start[i] + j, pf_valid[i] of them real.
 
-      * a decode row reads its next-token logits at j = 0,
-      * a prefill row that just finished its prompt reads first-token
-        logits at j = n_valid[b]-1,
-      * a verify row reads the whole [0, K] chain and the engine commits
-        the accepted prefix host-side (``lens`` never advances on device
-        — only the engine knows how much of a row actually committed, so
-        it republishes lens and tables before every step).
+    Every position's logits come back: logits[b, j] is the model's
+    distribution for the token FOLLOWING tokens[b, j]. So a decode row
+    reads its next-token logits at j = 0, a verify row the whole [0, K]
+    chain (the engine commits the accepted prefix host-side: ``lens``
+    never advances on device, only the engine knows how much of a row
+    committed, so it republishes lens and tables before every step), and
+    a prefill row that finished its prompt reads first-token logits at
+    j = pf_valid[i] - 1.
 
-    ``is_prefill`` bool[B] routes each row's FFN: dense for prefill rows,
-    sparse-gather decode math for decode/verify rows (ffn.ffn_step);
-    ``has_prefill`` is the STATIC no-prefill-rows fast path (pure sparse
-    decode, no dense W_down stream). ``backend`` selects the attention
-    read path ("naive" | "flash", see attention.attn_step_paged).
-    Returns (logits [B, S, V] — or [B, S, nc, V] for codebook models —
-    and the updated cache); with ``with_counts``, the step's routing
-    counts third (``_paged_stack``).
+    With a prefill group, norms, projections and the FFN run once on the
+    B*W + P*C tokens packed together; attention runs per group at the
+    group's width; both groups' KV lands in the pool in one store. The
+    generation group takes the sparse decode FFN, the prefill group the
+    dense one (ffn.ffn_step); without a prefill group the residual stream
+    stays [B, W, d] and no dense down-projection is traced. ``backend``
+    selects the attention read path ("naive" | "flash", see
+    attention.attn_step_grouped).
+
+    Returns (generation logits [B, W, V], prefill logits [P, C, V] or
+    None — with an nc axis before V for codebook models — the updated
+    cache, and the step's routing counts: None for a model without MoE
+    layers, see ``_paged_stack``).
     """
+    B, W = tokens.shape[0], tokens.shape[1]
     x, cos, sin = _step_inputs(params, cfg, tokens, cache["lens"])
     n_blocks = jax.tree.leaves(cache["units"])[0].shape[1]
-    tables = jnp.where(n_valid[:, None] > 0, cache["block_tables"],
-                       n_blocks)
-    ctx = {"cos": cos, "sin": sin, "lens": cache["lens"],
-           "n_valid": n_valid, "is_prefill": is_prefill,
-           "has_prefill": has_prefill, "tables": tables,
+    tables = cache["block_tables"]
+    groups = [attention.PagedGroup(
+        cache["lens"], n_valid,
+        jnp.where(n_valid[:, None] > 0, tables, n_blocks), W)]
+    n_gen = None
+    if pf_tokens is not None:
+        P, C = pf_tokens.shape[0], pf_tokens.shape[1]
+        xp, cp, sp = _step_inputs(params, cfg, pf_tokens, pf_start)
+        pf_tables = jnp.take(tables, pf_slot, axis=0, mode="fill",
+                             fill_value=n_blocks)
+        groups.append(attention.PagedGroup(
+            pf_start, pf_valid,
+            jnp.where(pf_valid[:, None] > 0, pf_tables, n_blocks), C))
+        x, cos, sin = _pack(x, xp), _pack(cos, cp), _pack(sin, sp)
+        n_gen = B * W
+    ctx = {"cos": cos, "sin": sin, "groups": tuple(groups), "n_gen": n_gen,
            "block_size": block_size, "backend": backend,
            "shared_params": params.get("shared")}
     if "moe" in cfg.pattern_unit():
-        ctx["valid"] = (jnp.arange(tokens.shape[1])[None, :]
-                        < n_valid[:, None])
+        valid = jnp.arange(W)[None, :] < n_valid[:, None]
+        if n_gen is not None:
+            valid = jnp.concatenate([
+                valid.reshape(-1),
+                (jnp.arange(C)[None, :] < pf_valid[:, None]).reshape(-1)
+            ])[None]
+        ctx["valid"] = valid
     logits, cache, counts = _paged_stack(params, cfg, x, cache, ctx)
-    return (logits, cache, counts) if with_counts else (logits, cache)
+    if n_gen is None:
+        return logits, None, cache, counts
+    return (logits[0, :n_gen].reshape((B, W) + logits.shape[2:]),
+            logits[0, n_gen:].reshape((P, C) + logits.shape[2:]), cache,
+            counts)
 
 
-def forward_step_grouped(params, cfg: ModelConfig, dec_tokens, pf_tokens,
-                         cache, dec_valid, pf_slot, pf_start, pf_valid,
-                         block_size: int, backend: str = "naive",
-                         with_counts: bool = False):
-    """forward_step for a tick that carries prefill chunks, without
-    padding decode rows to the chunk: two row groups in ONE pass over the
-    layers.
-
-      * decode group: dec_tokens [B, 1], row b at slot b (position
-        cache["lens"][b]); dec_valid[b] is 1 for a decode row and 0 for
-        every other slot (idle here, sentinel tables);
-      * prefill group: pf_tokens [P, C], row i for slot pf_slot[i]
-        (an id >= B pads the group: sentinel tables, nothing written) at
-        positions pf_start[i] + j, pf_valid[i] of them real.
-
-    Norms, projections and the FFN run once on the B + P*C tokens packed
-    together; attention runs per group at the group's width; both
-    groups' KV lands in the pool in one store. The math of each row is
-    that of forward_step's. Returns (decode logits [B, 1, V], prefill
-    logits [P, C, V] — with an nc axis before V for codebook models —
-    and the updated cache); with ``with_counts``, the step's routing
-    counts fourth.
-    """
-    B, P, C = dec_tokens.shape[0], pf_tokens.shape[0], pf_tokens.shape[1]
-    xd, cd, sd = _step_inputs(params, cfg, dec_tokens, cache["lens"])
-    xp, cp, sp = _step_inputs(params, cfg, pf_tokens, pf_start)
-
-    def pack(a, b):
-        return jnp.concatenate([a.reshape((1, -1) + a.shape[2:]),
-                                b.reshape((1, -1) + b.shape[2:])], axis=1)
-
-    n_blocks = jax.tree.leaves(cache["units"])[0].shape[1]
-    tables = cache["block_tables"]
-    pf_tables = jnp.take(tables, pf_slot, axis=0, mode="fill",
-                         fill_value=n_blocks)
-    groups = (
-        attention.PagedGroup(
-            cache["lens"], dec_valid,
-            jnp.where(dec_valid[:, None] > 0, tables, n_blocks), 1),
-        attention.PagedGroup(
-            pf_start, pf_valid,
-            jnp.where(pf_valid[:, None] > 0, pf_tables, n_blocks), C))
-    ctx = {"cos": pack(cd, cp), "sin": pack(sd, sp), "groups": groups,
-           "n_decode": B, "has_prefill": True, "block_size": block_size,
-           "backend": backend, "shared_params": params.get("shared")}
-    if "moe" in cfg.pattern_unit():
-        ctx["valid"] = jnp.concatenate([
-            dec_valid > 0,
-            (jnp.arange(C)[None, :] < pf_valid[:, None]).reshape(-1)])[None]
-    logits, cache, counts = _paged_stack(params, cfg, pack(xd, xp), cache,
-                                         ctx)
-    out = (logits[0, :B].reshape((B, 1) + logits.shape[2:]),
-           logits[0, B:].reshape((P, C) + logits.shape[2:]), cache)
-    return out + (counts,) if with_counts else out
+def _pack(a, b):
+    """Two groups' [rows, width, ...] arrays as one [1, N, ...], row-major
+    and in order."""
+    return jnp.concatenate([a.reshape((1, -1) + a.shape[2:]),
+                            b.reshape((1, -1) + b.shape[2:])], axis=1)
 
 
 def _step_inputs(params, cfg: ModelConfig, tokens, lens):
@@ -725,7 +694,6 @@ def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
     """
     B = tok0.shape[0]
     L = hist0.shape[1]
-    zeros_b = jnp.zeros((B,), bool)
     moe = "moe" in cfg.pattern_unit()
     col_ids = jnp.arange(k_max)[None, :]
     pos_mask = jnp.arange(L)[None, None, :] < (L - stop_len[:, :, None])
@@ -737,10 +705,9 @@ def decode_burst(params, cfg: ModelConfig, cache, tables, tok0, lens0,
         cache = dict(c["cache"])
         cache["lens"] = c["lens"]
         cache["block_tables"] = tables
-        logits, cache, counts = forward_step(
-            params, cfg, c["tok"][:, None], cache, c["alive"], zeros_b,
-            block_size, backend=backend, has_prefill=False,
-            with_counts=True)
+        logits, _, cache, counts = forward_step(
+            params, cfg, c["tok"][:, None], None, cache, c["alive"], None,
+            None, None, block_size, backend=backend)
         if moe:
             counts = jnp.concatenate([c["counts"][:2] + counts[:2],
                                       jnp.maximum(c["counts"][2:],

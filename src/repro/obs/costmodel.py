@@ -22,12 +22,13 @@ Two sources, cross-checked:
     dims); attribution = the scope segment of the op_name path.
 
 Both sources therefore run against an UNROLLED twin of the step
-(``dataclasses.replace(cfg, unroll=True)`` — transformer.forward_step's
+(``dataclasses.replace(cfg, unroll=True)`` — transformer._paged_stack's
 loop-free branch, same math and cache layout): the totals become exact
-and every unit's dots appear individually in the text. The twin
-compiles once per (width bucket, has_prefill, prefill_bucket) step
-shape and only when profiling is on (ObsConfig.profile); serving
-executables are untouched.
+and every unit's dots appear individually in the text. The twin is the
+runner's own step function (runner.step_fn) lowered on the runner's own
+abstract arguments (ModelRunner.step_args), once per (W, P) step shape
+and only when profiling is on (ObsConfig.profile); serving executables
+are untouched.
 
 The per-scope split covers dot (matmul/einsum) cost only — elementwise
 ops, norms, gathers land in "other" (total minus attributed). Tier-1
@@ -40,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -137,15 +138,14 @@ def executable_totals(compiled) -> Dict[str, float]:
 
 @dataclasses.dataclass
 class StepCost:
-    """Static cost of ONE execution of a (width, has_prefill,
-    prefill_bucket) step shape: XLA totals plus the per-named_scope dot
-    split ("other" holds the unattributed remainder, floored at 0 — the
-    split always sums to the total by construction, and
-    ``attributed_frac`` reports how much of it the scoped dots genuinely
-    cover)."""
+    """Static cost of ONE execution of a (width, prefill_bucket) step
+    shape: XLA totals plus the per-named_scope dot split ("other" holds
+    the unattributed remainder, floored at 0 — the split always sums to
+    the total by construction, and ``attributed_frac`` reports how much
+    of it the scoped dots genuinely cover)."""
 
     width: int
-    has_prefill: bool
+    prefill_bucket: int
     flops: float
     hbm_bytes: float
     by_scope: Dict[str, Dict[str, float]]
@@ -162,38 +162,19 @@ def _sds(tree):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
 
-def step_cost(runner, width: int, has_prefill: bool,
-              prefill_bucket: int = 0) -> StepCost:
-    """Lower + compile the unrolled twin of ``runner``'s step of this
-    shape from abstract args (no device work beyond the compile) and
+def step_cost(runner, width: int, prefill_bucket: int = 0) -> StepCost:
+    """Lower + compile the unrolled twin of ``runner``'s step of shape
+    (W, P) from abstract args (no device work beyond the compile) and
     extract its static cost."""
-    from repro.serve.runner import grouped_step, uniform_step
-    cfg = runner.cfg
+    from repro.serve.runner import step_fn
     scfg = runner.scfg
-    twin_cfg = dataclasses.replace(cfg, unroll=True)
-    twin = type(runner.model)(twin_cfg)
-    bs, backend = scfg.block_size, scfg.attn_backend
-    B = scfg.max_batch
-
-    def toks(rows, w):
-        shape = (rows, w, cfg.n_codebooks) if cfg.n_codebooks \
-            else (rows, w)
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    def ints(n):
-        return jax.ShapeDtypeStruct((n,), jnp.int32)
-
-    if prefill_bucket:
-        P = prefill_bucket
-        lowered = jax.jit(grouped_step(twin, bs, backend)).lower(
-            _sds(runner.params), toks(B, 1), toks(P, width),
-            _sds(runner.cache), ints(B), ints(P), ints(P), ints(P))
-    else:
-        lowered = jax.jit(uniform_step(twin, bs, backend,
-                                       has_prefill)).lower(
-            _sds(runner.params), toks(B, width), _sds(runner.cache),
-            ints(B), jax.ShapeDtypeStruct((B,), jnp.bool_))
-    compiled = lowered.compile()
+    twin = type(runner.model)(dataclasses.replace(runner.cfg, unroll=True))
+    toks, pf, n_valid, *rows = runner.step_args(
+        width, prefill_bucket, jax.ShapeDtypeStruct)
+    compiled = jax.jit(step_fn(twin, scfg.block_size,
+                               scfg.attn_backend)).lower(
+        _sds(runner.params), toks, pf, _sds(runner.cache), n_valid,
+        *rows).compile()
     totals = executable_totals(compiled)
     by_scope = parse_hlo_dot_costs(compiled.as_text())
     attributed = sum(v["flops"] for v in by_scope.values())
@@ -204,7 +185,7 @@ def step_cost(runner, width: int, has_prefill: bool,
     other = by_scope.setdefault("other", {"flops": 0.0, "bytes": 0.0})
     other["flops"] += max(totals["flops"] - attributed, 0.0)
     other["bytes"] += max(totals["bytes"] - attr_bytes, 0.0)
-    return StepCost(width=width, has_prefill=has_prefill,
+    return StepCost(width=width, prefill_bucket=prefill_bucket,
                     flops=totals["flops"], hbm_bytes=totals["bytes"],
                     by_scope=by_scope)
 

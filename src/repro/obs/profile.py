@@ -3,16 +3,17 @@
 NeCTAr's evaluation judges every unit against its roofline — decode is
 weight-bandwidth-bound, prefill compute-bound — and reports efficiency
 (GOPs/W), not stopwatch time. This module is the serving-side analogue:
-per compiled step shape of ``ModelRunner.step`` (decode=1, each grouped
-prefill bucket, k_max+1 verify) it joins
+per compiled step shape (W, P) of ``ModelRunner.step`` (the generation
+group's width W: 1 for decode, k_max+1 for verify; the prefill group's
+rows P, 0 for none) it joins
 
   * STATIC cost — FLOPs / bytes of the bucket's executable, total and
     per ``jax.named_scope`` (obs.costmodel: unrolled-twin
     ``cost_analysis()`` + HLO-text dot attribution), plus the sampler
     executable as the "sample" scope — with
   * MEASURED time — the tracer's per-tick fenced ``device_wait`` spans
-    (``tick_stats`` grouped by width, prefill presence and prefill
-    bucket, exactly the runner's jit key)
+    (``tick_stats`` grouped by ``gen_width`` and ``prefill_bucket``,
+    exactly the runner's jit key)
 
 and emits per-bucket achieved GFLOP/s, GB/s, arithmetic intensity, and
 roofline ATTAINMENT: ``max(flops/peak, bytes/bw) / measured_s``, i.e.
@@ -48,16 +49,15 @@ _COLS = (
 )
 
 
-def bucket_label(width: int, has_prefill: bool,
-                 prefill_bucket: int = 0) -> str:
-    """Human name for a (width, has_prefill, prefill_bucket) step shape:
-    "decode", "mixed_p<P>" (grouped), "prefill<W>", "verify<K+1>" —
-    stable keys for gauges/baselines."""
-    if prefill_bucket:
-        return f"mixed_p{prefill_bucket}"
-    if has_prefill:
-        return f"prefill{width}"
-    return "decode" if width == 1 else f"verify{width}"
+def bucket_label(width: int, prefill_bucket: int = 0) -> str:
+    """Human name for a (W, P) step shape: "decode", "verify<K+1>", and
+    with a prefill group "mixed_p<P>" and "verify<K+1>_p<P>" — stable
+    keys for gauges/baselines."""
+    label = "decode" if width == 1 else f"verify{width}"
+    if not prefill_bucket:
+        return label
+    return f"mixed_p{prefill_bucket}" if width == 1 \
+        else f"{label}_p{prefill_bucket}"
 
 
 class ServingProfiler:
@@ -76,13 +76,13 @@ class ServingProfiler:
         self._sample: Optional[Dict[str, float]] = None
 
     # --- static side -----------------------------------------------------
-    def static_cost(self, width: int, has_prefill: bool,
+    def static_cost(self, width: int,
                     prefill_bucket: int = 0) -> costmodel.StepCost:
-        key = (width, has_prefill, prefill_bucket)
+        key = (width, prefill_bucket)
         c = self._costs.get(key)
         if c is None:
             c = self._costs[key] = costmodel.step_cost(
-                self.runner, width, has_prefill, prefill_bucket)
+                self.runner, width, prefill_bucket)
         return c
 
     def sample_cost(self) -> Dict[str, float]:
@@ -92,11 +92,10 @@ class ServingProfiler:
                 scfg.max_batch, cfg.vocab, cfg.n_codebooks)
         return self._sample
 
-    def _bucket_totals(self, width: int, has_prefill: bool,
-                       prefill_bucket: int = 0):
+    def _bucket_totals(self, width: int, prefill_bucket: int = 0):
         """(flops, bytes, by_scope) of one tick of this bucket: the step
         executable plus the per-tick sampler call."""
-        cost = self.static_cost(width, has_prefill, prefill_bucket)
+        cost = self.static_cost(width, prefill_bucket)
         samp = self.sample_cost()
         by_scope = {k: dict(v) for k, v in cost.by_scope.items()}
         by_scope["sample"] = {"flops": samp["flops"],
@@ -107,14 +106,13 @@ class ServingProfiler:
     # --- measured join ---------------------------------------------------
     @staticmethod
     def _grouped(tick_stats: Iterable[dict]) -> Dict[tuple, List[float]]:
-        """device_ms samples per (width, has_prefill, prefill_bucket) —
-        the runner's jit key, recovered from each tick's recorded attrs.
-        Ticks that ran no device step (width absent or zero device time)
-        don't belong to any bucket."""
+        """device_ms samples per (gen_width, prefill_bucket) — the
+        runner's jit key, recovered from each tick's recorded attrs.
+        Ticks that ran no device step (gen_width absent or zero device
+        time) don't belong to any bucket."""
         groups: Dict[tuple, List[float]] = {}
         for t in tick_stats:
-            w = t.get("width")
-            if not w or t.get("device_ms", 0.0) <= 0.0:
+            if not t.get("gen_width") or t.get("device_ms", 0.0) <= 0.0:
                 continue
             key = _step_key(t)
             groups.setdefault(key, []).append(float(t["device_ms"]))
@@ -125,8 +123,8 @@ class ServingProfiler:
         attainment formula. Empty when nothing was measured (profiling
         needs tracing's fenced device_wait spans)."""
         rows = []
-        for (w, hp, pb), dms in sorted(self._grouped(tick_stats).items()):
-            flops, byts, by_scope = self._bucket_totals(w, hp, pb)
+        for (w, pb), dms in sorted(self._grouped(tick_stats).items()):
+            flops, byts, by_scope = self._bucket_totals(w, pb)
             dev_ms = sum(dms) / len(dms)
             dev_s = dev_ms / 1e3
             terms = hw.roofline_terms(flops, byts, 0.0, self.n_chips,
@@ -137,9 +135,9 @@ class ServingProfiler:
             scoped = sum(v["flops"] for k, v in by_scope.items()
                          if k != "other")
             rows.append({
-                "bucket": bucket_label(w, hp, pb),
+                "bucket": bucket_label(w, pb),
                 "width": w,
-                "has_prefill": hp,
+                "prefill_bucket": pb,
                 "ticks": len(dms),
                 "dev_ms": dev_ms,
                 "flops": flops,
@@ -201,9 +199,8 @@ class ServingProfiler:
 
 
 def _step_key(t: dict) -> tuple:
-    """A tick's (width, has_prefill, prefill_bucket) from its attrs."""
-    return (int(t["width"]), bool(t.get("rows_prefill", 0)),
-            int(t.get("prefill_bucket", 0)))
+    """A tick's (gen_width, prefill_bucket) from its attrs."""
+    return int(t["gen_width"]), int(t.get("prefill_bucket", 0))
 
 
 def attainment_table(rows: List[dict]) -> str:

@@ -675,37 +675,36 @@ class Engine:
                 cow.extend(copies)
             if cow:
                 self.runner.copy_blocks(cow)
-            max_valid = max(len(r[2]) for r in rows)
-            # prefill rows and no verify row: a grouped step, decode rows
-            # at width 1 beside a chunk-wide prefill group
-            verify = spec is not None and bool(run_rows)
+            # the generation group at the narrowest width holding its
+            # decode / verify rows, beside a prefill group of the tick's
+            # prefill rows
             batch = self.runner.new_batch(
-                max_valid, self.pool.tables(),
-                n_prefill=0 if verify else len(prefill_plan))
+                max((len(toks) for _, phase, toks, _ in rows
+                     if phase != PREFILL), default=1),
+                self.pool.tables(), n_prefill=len(prefill_plan))
             for slot, phase, toks, start in rows:
                 batch.add_row(slot, phase, toks, start)
             valid_tokens = sum(len(r[2]) for r in rows)
-            # width = the COMPILED bucket (batch token width), not the
-            # max valid length: the device executes the padded bucket
-            # shape, so pad_waste must charge bucket padding too, and
-            # the roofline profiler joins tick time to static cost by
-            # exactly this (width, has_prefill, prefill_bucket) jit key
-            width = batch.tokens.shape[1]
-            P = batch.prefill_bucket
-            denom = self.scfg.max_batch + P * width if P \
-                else self.scfg.max_batch * width
+            # the shape that ran, not the valid lengths: the device
+            # executes the padded groups, so pad_waste charges their
+            # padding too, and the roofline profiler joins tick time to
+            # static cost by exactly this (gen_width, prefill_bucket)
+            # jit key
+            W, P, C = batch.width, batch.prefill_bucket, \
+                self.scfg.prefill_chunk
+            denom = self.scfg.max_batch * W + P * C
             if P:
                 self.metrics.on_mixed_tick(P)
-                tr.tick_attrs(prefill_bucket=P, prefill_width=width)
-            if width == 1 or P:
+                tr.tick_attrs(prefill_bucket=P, prefill_width=C)
+            if W == 1:
                 self._note_kv_read()
             tr.tick_attrs(
                 rows_prefill=len(prefill_plan),
                 rows_decode=len(run_rows) if spec is None else 0,
                 rows_verify=len(run_rows) if spec is not None else 0,
-                width=width, valid_tokens=valid_tokens,
-                pad_waste_frac=1.0 - valid_tokens / denom if denom
-                else 0.0)
+                width=batch.tokens.shape[1], gen_width=W,
+                valid_tokens=valid_tokens,
+                pad_waste_frac=1.0 - valid_tokens / denom)
         out = self.runner.step(batch)
         self._note_moe_counts(out.moe_counts)
 
@@ -1136,7 +1135,7 @@ class Engine:
                     batch.add_row(e.slot, DECODE,
                                   [int(e.req.tokens_out[-1])], e.ctx_len)
             denom = B * batch.tokens.shape[1]
-            tr.tick_attrs(rows_decode=len(rows), width=1,
+            tr.tick_attrs(rows_decode=len(rows), width=1, gen_width=1,
                           valid_tokens=len(rows),
                           pad_waste_frac=1.0 - len(rows) / denom,
                           device_ticks=1, async_mode="overlap")
@@ -1257,7 +1256,7 @@ class Engine:
                         keys[k, s] = sampling.request_key(
                             sp.seed, e.req.rid, ctr0[s] + k)
             denom = B * 1
-            tr.tick_attrs(rows_decode=len(rows), width=1,
+            tr.tick_attrs(rows_decode=len(rows), width=1, gen_width=1,
                           valid_tokens=len(rows),
                           pad_waste_frac=1.0 - len(rows) / denom,
                           async_mode="loop")

@@ -231,8 +231,8 @@ class MetricsCollector:
             "compiles and compile-cache loads during engine ticks")
         self._c_mixed = r.labeled_counter(
             "engine_mixed_ticks_total", "prefill_bucket",
-            "grouped ticks (decode rows at width 1 beside a prefill "
-            "group), by the prefill group's row bucket")
+            "ticks with a prefill group beside the generation group, "
+            "by the prefill group's row bucket")
         self._c_kv_read = r.labeled_counter(
             "engine_decode_kv_reads_total", "read",
             "ticks with a width-1 group, by how it read the KV pool: "

@@ -19,7 +19,7 @@ copy-on-write (``cow_for_write``) so sharing is invisible to correctness.
 Host-side bookkeeping lives here (free list, per-slot block lists,
 refcounts, eviction, defrag, byte accounting); the device-side storage
 and the gather/scatter decode path live in models.attention
-(attn_step_paged). Block index ``n_blocks`` is the invalid sentinel
+(attn_step_grouped). Block index ``n_blocks`` is the invalid sentinel
 understood by the device path: writes through it drop, reads through it
 fill zeros.
 """

@@ -3,35 +3,35 @@
 The engine used to juggle three separately-jitted model entries (prefill
 chunk, paged decode, spec verify) plus a greedy-only sampling helper —
 every new scenario multiplied code paths. The runner collapses them onto
-the paper's one-matvec-datapath shape: a single jitted function
+the paper's one-matvec-datapath shape: a single model entry
 (models.transformer.forward_step) serves chunked-prefill rows, decode
-rows, and K+1 verify rows in the same fixed-shape batch, so decode never
-stalls behind prefill ticks (continuous batching) and new phases are a
-new row kind, not a new model entry.
+rows, and K+1 verify rows in the same step, so decode never stalls
+behind prefill ticks (continuous batching) and new phases are a new row
+kind, not a new model entry.
 
-Shape discipline: three kinds of tick, each compiled once per shape.
-A decode tick runs every row at width 1 (``serve_step_w1``). A tick
-that carries prefill chunks and no verify row is grouped
-(``serve_step_w1_prefill_p<P>x<C>``): a width-1 decode group of
-``max_batch`` rows, one per slot, and a prefill group of P rows of the
-chunk width C, P the number of prefill rows rounded up to a power of four
-capped at ``max_batch`` — so decode rows are never padded to the chunk,
-and the first grouped tick builds every P at once. Ticks with verify
-rows run every row at the widest row's bucket (k_max+1, or the chunk
-width when prefill rows ride along: ``serve_step_w<S>[_prefill]``). The
-runner owns the device cache; the engine republishes the host-truth
-``lens`` and block tables before every step (the device never advances
-them — only the engine knows what actually committed, e.g. after
-speculative acceptance).
+Shape discipline: one step shape, compiled once per (W, P). Every tick
+is a generation group of ``max_batch`` rows, one per slot, at width W
+(1 for decode, k_max+1 when a verify or resync row rides), plus a
+prefill group of P rows of the chunk width C, P the number of prefill
+rows rounded up to a power of four capped at ``max_batch`` (P = 0: no
+prefill group is traced). Idle slots carry sentinel tables. The
+executables are named for their shape: ``serve_step_w<W>`` and
+``serve_step_w<W>_p<P>x<C>``; decode rows are never padded to the
+chunk, verify rows never to more than k_max+1, and the first tick with
+a prefill group builds every P of its W at once. The runner owns the
+device cache; the engine republishes the host-truth ``lens`` and block
+tables before every step (the device never advances them — only the
+engine knows what actually committed, e.g. after speculative
+acceptance).
 
 The attention read path is pluggable (``ServeConfig.attn_backend``):
 "naive" gathers blocks into a logical sequence (reference, shardable),
 except that a width-1 group whose tables span more blocks than the pool
 holds attends over the whole pool once (``kv_read``); "flash" hands the
 block pools + tables to the Pallas paged-attention kernel
-(kernels.decode_attn.paged_attention), which covers every row width of
-the unified step — single-token decode, K+1 verify, and prefill chunks
-— with per-row causal masking resolved in-kernel.
+(kernels.decode_attn.paged_attention), which covers every group width
+— single-token decode, K+1 verify, and prefill chunks — with per-row
+causal masking resolved in-kernel.
 
 Sharded serving (``mesh`` + ``policy``): the runner is the mesh-aware
 boundary. Weights shard over the 'model' axis (dist.sharding.
@@ -84,7 +84,7 @@ STOP_NS = 2
 
 
 def prefill_buckets(max_batch: int) -> List[int]:
-    """Row counts a grouped step's prefill group compiles for: powers of
+    """Row counts a step's prefill group compiles for: powers of
     four below ``max_batch``, then ``max_batch``. Each bucket is one more
     executable to lower at set-up (most of a second at Qwen3-4B on a
     v5e host), against about 1.3 ms of device time per padded prefill
@@ -96,102 +96,98 @@ def prefill_buckets(max_batch: int) -> List[int]:
     return out + [max_batch]
 
 
-def uniform_step(model, block_size: int, backend: str, has_prefill: bool):
-    """The step function of a uniform tick (every row at one width):
-    (params, tokens, cache, n_valid, is_prefill) -> (logits, last-valid
-    logits per row, cache, routing counts — None without MoE layers)."""
-    def run(params, tokens, cache, n_valid, is_prefill):
-        logits, cache, counts = model.forward_step(
-            params, tokens, cache, n_valid, is_prefill, block_size,
-            backend=backend, has_prefill=has_prefill, with_counts=True)
-        idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
-        idx = idx.reshape((-1,) + (1,) * (logits.ndim - 1))
-        last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-        return logits, last, cache, counts
+def prefill_bucket_for(n_prefill: int, max_batch: int) -> int:
+    """Smallest prefill-group bucket holding ``n_prefill`` rows (0 for
+    none)."""
+    if not n_prefill:
+        return 0
+    return next(p for p in prefill_buckets(max_batch) if p >= n_prefill)
+
+
+def _last_valid(logits, n_valid):
+    """Each row's logits at its last valid position: [rows, V]."""
+    idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
+    idx = idx.reshape((-1,) + (1,) * (logits.ndim - 1))
+    return jnp.take_along_axis(logits, idx, axis=1)[:, 0]
+
+
+def step_fn(model, block_size: int, backend: str):
+    """The step function of every tick (Model.forward_step): (params,
+    tokens, pf_tokens, cache, n_valid, pf_slot, pf_start, pf_valid) ->
+    (generation logits [B, W, V], prefill logits [P, C, V] or None,
+    last-valid logits by slot, cache, routing counts — None without MoE
+    layers). A prefill row's last logits land in its slot's row; a
+    padding row's slot id is out of range and its row drops.
+
+    Beside a prefill group a width-1 generation group's one position is
+    sliced out; the decode-only step keeps the gather at index 0, so its
+    executable stays the program the benchmark measured (a slice there
+    would drop the gather fusions over the [B, 1, V] logits, a gain not
+    yet measured on the chip)."""
+    def run(params, tokens, pf_tokens, cache, n_valid, pf_slot, pf_start,
+            pf_valid):
+        gen, pf, cache, counts = model.forward_step(
+            params, tokens, pf_tokens, cache, n_valid, pf_slot, pf_start,
+            pf_valid, block_size, backend=backend)
+        if pf is None:
+            return gen, None, _last_valid(gen, n_valid), cache, counts
+        last = gen[:, 0] if gen.shape[1] == 1 else _last_valid(gen, n_valid)
+        last = last.at[pf_slot].set(_last_valid(pf, pf_valid), mode="drop")
+        return gen, pf, last, cache, counts
     return run
 
 
-def grouped_step(model, block_size: int, backend: str):
-    """The step function of a grouped tick (Model.forward_step_grouped):
-    (params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot, pf_start,
-    pf_valid) -> (decode logits, prefill logits, last-valid logits by
-    slot, cache, routing counts). A prefill row's last logits land in its
-    slot's row; a padding row's slot id is out of range and its row
-    drops."""
-    def run(params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot,
-            pf_start, pf_valid):
-        dec, pf, cache, counts = model.forward_step_grouped(
-            params, dec_tokens, pf_tokens, cache, dec_valid, pf_slot,
-            pf_start, pf_valid, block_size, backend=backend,
-            with_counts=True)
-        idx = jnp.clip(pf_valid - 1, 0, pf.shape[1] - 1)
-        idx = idx.reshape((-1,) + (1,) * (pf.ndim - 1))
-        pf_last = jnp.take_along_axis(pf, idx, axis=1)[:, 0]
-        last = dec[:, 0].at[pf_slot].set(pf_last, mode="drop")
-        return dec, pf, last, cache, counts
-    return run
-
-
-def _grouped_args(batch: "StepBatch", pf_slots: np.ndarray):
-    """A grouped batch's per-slot rows as the two groups' device inputs:
-    the decode group by slot, the prefill rows ``pf_slots`` packed first
-    into the prefill group and the rest of it padded with slot id B."""
-    B = batch.tokens.shape[0]
-    P = batch.prefill_bucket
+def _batch_inputs(batch: "StepBatch", pf_slots: np.ndarray, C: int,
+                  tokens=None):
+    """A batch's per-slot rows as the step's device inputs after params:
+    (tokens, pf_tokens, n_valid, pf_slot, pf_start, pf_valid). The
+    generation group is every slot at width W, its prefill slots idle
+    (no valid token); the prefill rows ``pf_slots`` are packed first into
+    the prefill group and the rest of it padded with slot id B (all four
+    prefill inputs None when P = 0). ``tokens`` overrides the generation
+    tokens with a device array."""
+    B, W, P = batch.tokens.shape[0], batch.width, batch.prefill_bucket
+    if tokens is None:
+        tokens = jnp.asarray(batch.tokens[:, :W])
+    n_valid = jnp.asarray(np.where(batch.phase != PREFILL, batch.n_valid, 0))
+    if not P:
+        return tokens, None, n_valid, None, None, None
     n = len(pf_slots)
-    dec = batch.phase == DECODE
-    dec_tokens = np.where(
-        dec.reshape((B,) + (1,) * (batch.tokens.ndim - 1)),
-        batch.tokens[:, :1], 0)
-    pf_tokens = np.zeros((P,) + batch.tokens.shape[1:], np.int32)
-    pf_tokens[:n] = batch.tokens[pf_slots]
+    pf_tokens = np.zeros((P, C) + batch.tokens.shape[2:], np.int32)
+    pf_tokens[:n, :batch.tokens.shape[1]] = batch.tokens[pf_slots, :C]
     slot = np.full((P,), B, np.int32)
     slot[:n] = pf_slots
     start = np.zeros((P,), np.int32)
     start[:n] = batch.row_start[pf_slots]
     valid = np.zeros((P,), np.int32)
     valid[:n] = batch.n_valid[pf_slots]
-    return tuple(jnp.asarray(a) for a in (
-        dec_tokens, pf_tokens, np.where(dec, batch.n_valid, 0), slot,
-        start, valid))
+    return (tokens, jnp.asarray(pf_tokens), n_valid, jnp.asarray(slot),
+            jnp.asarray(start), jnp.asarray(valid))
 
 
 @dataclasses.dataclass
 class StepBatch:
-    """Host-side description of one unified step: flat tokens plus a
-    per-row (phase, start, valid-length) descriptor and the block tables.
+    """Host-side description of one step: flat tokens plus a per-row
+    (phase, start, valid-length) descriptor and the block tables.
 
     tokens: i32[B, S] (or [B, S, nc] for codebook models) — row b's valid
     tokens occupy [0, n_valid[b]); the rest is padding whose KV writes
     drop at the sentinel. row_start[b] is the absolute position of the
     row's first token (its committed context length; the prefill frontier
-    for PREFILL rows). phase[b] routes per-row math: PREFILL rows use the
-    dense FFN, DECODE/VERIFY the sparse decode path; IDLE rows are fully
-    masked (sentinel tables, garbage logits).
-
-    ``prefill_bucket`` P > 0 makes the step grouped (S is then the chunk
-    width): the runner runs the DECODE rows as a width-1 group and the
-    PREFILL rows as a [P, S] group, and never pads a decode row to S."""
+    for PREFILL rows). phase[b] routes the row: DECODE/VERIFY rows run in
+    the generation group at ``width`` W (the sparse decode FFN), PREFILL
+    rows in the prefill group of ``prefill_bucket`` P rows of the chunk
+    width (the dense FFN); IDLE rows are fully masked (sentinel tables,
+    garbage logits). S is W, or the chunk width when prefill rows may
+    ride along. ``add_row`` raises P to cover the prefill rows added."""
 
     tokens: np.ndarray
     row_start: np.ndarray
     n_valid: np.ndarray
     phase: np.ndarray
     tables: np.ndarray
+    width: int = 1
     prefill_bucket: int = 0
-
-    @classmethod
-    def empty(cls, max_batch: int, width: int, tables: np.ndarray,
-              n_codebooks: int = 0, prefill_bucket: int = 0
-              ) -> "StepBatch":
-        shape = (max_batch, width, n_codebooks) if n_codebooks \
-            else (max_batch, width)
-        return cls(tokens=np.zeros(shape, np.int32),
-                   row_start=np.zeros((max_batch,), np.int32),
-                   n_valid=np.zeros((max_batch,), np.int32),
-                   phase=np.full((max_batch,), IDLE, np.int32),
-                   tables=np.asarray(tables, np.int32),
-                   prefill_bucket=prefill_bucket)
 
     def add_row(self, slot: int, phase: int, tokens, start: int) -> None:
         toks = np.asarray(tokens, np.int32)
@@ -199,21 +195,27 @@ class StepBatch:
         self.row_start[slot] = start
         self.n_valid[slot] = len(toks)
         self.phase[slot] = phase
+        if phase == PREFILL:
+            n = int(np.sum(self.phase == PREFILL))
+            self.prefill_bucket = max(
+                self.prefill_bucket,
+                prefill_bucket_for(n, self.tokens.shape[0]))
 
 
 @dataclasses.dataclass
 class StepOutput:
-    """Device results of one step. ``logits[b, j]`` is the distribution
-    for the token FOLLOWING tokens[b, j]; ``last_logits[b]`` is row b's
-    logits at its last valid position (what decode rows and
-    prompt-completing prefill rows sample from). ``row_logits`` pulls one
-    row to host lazily — verify rows need the full chain, everyone else
-    only samples from ``last_logits``."""
+    """Device results of one step. ``logits[b, j]`` is the generation
+    group's distribution for the token FOLLOWING tokens[b, j] ([B, W,
+    V(, nc x V for codebooks)]); ``prefill_logits`` the prefill group's
+    [P, C, V] and ``prefill_slots`` the slot of each of its rows (None
+    without a prefill group). ``last_logits[b]`` is slot b's logits at
+    its last valid position (what decode rows and prompt-completing
+    prefill rows sample from). ``row_logits`` pulls one slot's row to
+    host lazily, from whichever group ran it — verify rows need the full
+    chain, everyone else only samples from ``last_logits``."""
 
-    logits: jax.Array          # [B, S, V(, nc x V for codebooks)]
+    logits: jax.Array
     last_logits: jax.Array     # [B, V] / [B, nc, V]
-    # grouped steps: ``logits`` is the decode group's [B, 1, V]; the
-    # prefill group's [P, C, V] and the slot of each of its rows
     prefill_logits: Optional[jax.Array] = None
     prefill_slots: Optional[np.ndarray] = None
     # MoE models: the step's routing counts, i32[3] on the device
@@ -290,12 +292,12 @@ class ModelRunner:
                                       self._cache_shardings)
             self._repl = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
-        # how every width-1 group (decode ticks, the decode group of a
-        # grouped tick, bursts) reads the pool: "pool" attends over the
-        # whole leaf, "gather" gathers every table column, "kernel" is
-        # the flash kernel's table walk. Fixed by the static shapes
-        # (attention.reads_pool), under the mesh scope the steps are
-        # traced in.
+        # how every width-1 generation group (decode ticks with or
+        # without a prefill group, bursts) reads the pool: "pool" attends
+        # over the whole leaf, "gather" gathers every table column,
+        # "kernel" is the flash kernel's table walk. Fixed by the static
+        # shapes (attention.reads_pool), under the mesh scope the steps
+        # are traced in.
         with shd.activation_sharding_scope(mesh, self.policy) \
                 if mesh is not None else contextlib.nullcontext():
             pool = attention.reads_pool(
@@ -303,157 +305,144 @@ class ModelRunner:
                 scfg.block_size, scfg.attn_backend)
         self.kv_read = "kernel" if scfg.attn_backend == "flash" \
             else "pool" if pool else "gather"
-        self.buckets = sorted({1, scfg.prefill_chunk}
-                              | ({scfg.spec.k_max + 1}
-                                 if scfg.spec is not None else set()))
+        # generation-group widths: decode, and verify / resync rows
+        self.widths = [1] + ([scfg.spec.k_max + 1]
+                             if scfg.spec is not None else [])
         self.prefill_buckets = prefill_buckets(scfg.max_batch)
         self._fns: Dict[tuple, callable] = {}
-        self._grouped_built = False
+        self._built = set()         # widths whose prefill buckets exist
         self.n_steps = 0            # device steps dispatched (async
         #                             engines add burst iterations on top)
 
     # --- batch construction ------------------------------------------------
-    def width_for(self, max_valid: int) -> int:
-        """Smallest compiled bucket covering ``max_valid`` tokens/row."""
-        for b in self.buckets:
-            if b >= max_valid:
-                return b
-        self.buckets.append(max_valid)      # rare: register a new bucket
-        self.buckets.sort()
-        return max_valid
-
     def prefill_bucket_for(self, n_prefill: int) -> int:
         """Smallest prefill-group bucket holding ``n_prefill`` rows."""
-        return next(p for p in self.prefill_buckets if p >= n_prefill)
+        return prefill_bucket_for(n_prefill, self.scfg.max_batch)
 
     def new_batch(self, max_valid: int, tables: np.ndarray,
                   n_prefill: int = 0) -> StepBatch:
-        """An empty batch for rows of at most ``max_valid`` tokens. With
-        ``n_prefill`` > 0 (the tick's prefill rows, when it has no verify
-        row) the batch is grouped: chunk-wide, decode rows at width 1."""
-        if n_prefill:
-            return StepBatch.empty(
-                self.scfg.max_batch, self.scfg.prefill_chunk, tables,
-                n_codebooks=self.cfg.n_codebooks,
-                prefill_bucket=self.prefill_bucket_for(n_prefill))
-        return StepBatch.empty(self.scfg.max_batch,
-                               self.width_for(max_valid), tables,
-                               n_codebooks=self.cfg.n_codebooks)
+        """An empty batch whose generation group is the narrowest width
+        holding rows of ``max_valid`` tokens (the widest when none does:
+        only a prefill row is that long), with a prefill group of
+        ``n_prefill`` rows. The token buffer widens to the chunk when
+        prefill rows may ride along."""
+        B, C = self.scfg.max_batch, self.scfg.prefill_chunk
+        W = next((w for w in self.widths if w >= max_valid),
+                 self.widths[-1])
+        S = max(W, C) if n_prefill or max_valid > W else W
+        nc = (self.cfg.n_codebooks,) if self.cfg.n_codebooks else ()
+        return StepBatch(tokens=np.zeros((B, S) + nc, np.int32),
+                         row_start=np.zeros((B,), np.int32),
+                         n_valid=np.zeros((B,), np.int32),
+                         phase=np.full((B,), IDLE, np.int32),
+                         tables=np.asarray(tables, np.int32), width=W,
+                         prefill_bucket=self.prefill_bucket_for(n_prefill))
 
     # --- the one step ------------------------------------------------------
-    def _fn(self, width: int, has_prefill: bool, prefill_bucket: int = 0):
-        """One jit per step shape, named for it: ``serve_step_w1``,
-        ``serve_step_w32_prefill`` (every row at width 32), or
-        ``serve_step_w1_prefill_p4x32`` (a grouped tick: decode rows at
-        width 1, four prefill rows of 32). A compile event and the
-        profiler's dispatch span say which shape ran. No-prefill ticks —
-        the serving steady state — compile to the pure sparse decode FFN
-        and never stream the dense W_down."""
-        key = (width, has_prefill, prefill_bucket)
+    def _fn(self, width: int, prefill_bucket: int):
+        """One jit per step shape (W, P), named for it:
+        ``serve_step_w1`` (decode), ``serve_step_w5`` (verify rows of
+        K+1 = 5), ``serve_step_w1_p4x32`` (decode rows beside four
+        prefill rows of 32). A compile event and the profiler's dispatch
+        span say which shape ran. P = 0 — the serving steady state —
+        compiles to the pure sparse decode FFN and never streams the
+        dense W_down."""
+        key = (width, prefill_bucket)
         fn = self._fns.get(key)
-        if fn is None:
-            mdl, bs = self.model, self.scfg.block_size
-            backend = self.scfg.attn_backend
-            mesh, policy = self.mesh, self.policy
-            if prefill_bucket:
-                name = f"serve_step_w1_prefill_p{prefill_bucket}x{width}"
-                run = grouped_step(mdl, bs, backend)
-            else:
-                name = f"serve_step_w{width}" + ("_prefill" if has_prefill
-                                                 else "")
-                run = uniform_step(mdl, bs, backend, has_prefill)
-            if mesh is None:
-                # shared across runner instances
-                gkey = (mdl.cfg, bs, backend) + key
-                fn = _JIT_CACHE.get(gkey)
-                if fn is not None:
-                    self._fns[key] = fn
-                    return fn
-
-            if mesh is not None:
-                # trace under the activation-sharding scope (model code
-                # consults it for the seq-sharded LSE decode path) and pin
-                # the cache's layout so it never drifts across ticks;
-                # logits come back replicated — sampling and the verify
-                # chain read them host-side, as do the routing counts.
-                base = run
-
-                def run(*args):
-                    with shd.activation_sharding_scope(mesh, policy):
-                        return base(*args)
-
+        if fn is not None:
+            return fn
+        mdl, bs = self.model, self.scfg.block_size
+        backend = self.scfg.attn_backend
+        mesh, policy = self.mesh, self.policy
+        name = f"serve_step_w{width}"
+        if prefill_bucket:
+            name += f"_p{prefill_bucket}x{self.scfg.prefill_chunk}"
+        run = step_fn(mdl, bs, backend)
+        if mesh is None:
+            # shared across runner instances
+            gkey = (mdl.cfg, bs, backend, name)
+            fn = _JIT_CACHE.get(gkey)
+            if fn is None:
                 run.__name__ = run.__qualname__ = name
-                n_logits = 3 if prefill_bucket else 2
-                fn = jax.jit(run, out_shardings=(
-                    (self._repl,) * n_logits
-                    + (self._cache_shardings, self._repl)))
-            else:
-                run.__name__ = run.__qualname__ = name
-                fn = jax.jit(run)
-                _JIT_CACHE[gkey] = fn
-            self._fns[key] = fn
+                fn = _JIT_CACHE[gkey] = jax.jit(run)
+        else:
+            # trace under the activation-sharding scope (model code
+            # consults it for the seq-sharded LSE decode path) and pin
+            # the cache's layout so it never drifts across ticks; logits
+            # come back replicated — sampling and the verify chain read
+            # them host-side, as do the routing counts.
+            base = run
+
+            def run(*args):
+                with shd.activation_sharding_scope(mesh, policy):
+                    return base(*args)
+
+            run.__name__ = run.__qualname__ = name
+            fn = jax.jit(run, out_shardings=(
+                self._repl, self._repl, self._repl, self._cache_shardings,
+                self._repl))
+        self._fns[key] = fn
         return fn
 
-    def _build_grouped(self) -> None:
-        """Build the grouped executable of every prefill bucket, each run
-        once on an all-idle batch (every write drops at the sentinel, the
-        pool is left as it was): the first tick with a prefill row pays
-        for all of them, and a later tick with more prefill rows compiles
-        nothing."""
+    def step_args(self, width: int, prefill_bucket: int, spec):
+        """The step's arguments after params, in the shape of (W, P):
+        ``spec(shape, dtype)`` makes each array (zeros to run it,
+        ShapeDtypeStruct to lower it); the cache is the caller's."""
         B, C = self.scfg.max_batch, self.scfg.prefill_chunk
-        nc = self.cfg.n_codebooks
+        P = prefill_bucket
+        nc = (self.cfg.n_codebooks,) if self.cfg.n_codebooks else ()
+        pf_tokens = spec((P, C) + nc, jnp.int32) if P else None
+        rows = [spec((P,), jnp.int32) if P else None for _ in range(3)]
+        return (spec((B, width) + nc, jnp.int32), pf_tokens,
+                spec((B,), jnp.int32), *rows)
+
+    def _build_prefill_buckets(self, width: int) -> None:
+        """Build the executable of every prefill bucket at generation
+        width ``width``, each run once on an all-idle batch (every write
+        drops at the sentinel, the pool is left as it was): the first tick
+        with a prefill row pays for all of them, and a later tick with
+        more prefill rows compiles nothing."""
+        B = self.scfg.max_batch
         n_blocks = jax.tree.leaves(self.cache["units"])[0].shape[1]
         cache = dict(self.cache, block_tables=jnp.full_like(
             self.cache["block_tables"], n_blocks))
         for P in self.prefill_buckets:
-            dec = np.zeros((B, 1, nc) if nc else (B, 1), np.int32)
-            pf = np.zeros((P, C, nc) if nc else (P, C), np.int32)
-            out = self._fn(C, True, P)(
-                self.params, jnp.asarray(dec), jnp.asarray(pf), cache,
-                jnp.zeros((B,), jnp.int32), jnp.full((P,), B, jnp.int32),
-                jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.int32))
+            toks, pf, n_valid, slot, start, valid = self.step_args(
+                width, P, jnp.zeros)
+            out = self._fn(width, P)(
+                self.params, toks, pf, cache, n_valid,
+                jnp.full_like(slot, B), start, valid)
             jax.block_until_ready(out)
             del out
-        self._grouped_built = True
+        self._built.add(width)
 
     def step(self, batch: StepBatch, fence: bool = True,
              tokens=None) -> StepOutput:
-        """Run one unified step: republish host-truth lens/tables, execute
-        the bucketed jit, return per-position and last-valid logits.
+        """Run one step: republish host-truth lens/tables, execute the
+        (W, P) jit, return per-position and last-valid logits.
 
         ``fence=False`` is the async engine's double-buffered dispatch
         (docs/async.md): even under tracing with ``fence_device`` on, the
         call returns as soon as the step is dispatched — the engine
         reconciles the results one tick later, attributing the deferred
-        wait to its sample_sync span instead. ``tokens`` overrides
-        ``batch.tokens`` with a DEVICE array (same [B, S] shape), letting
+        wait to its sample_sync span instead. ``tokens`` overrides the
+        generation group's tokens with a DEVICE array ([B, W]), letting
         tick t+1's input chain on tick t's still-in-flight sampled tokens
         without a host round-trip."""
-        width = batch.tokens.shape[1]
-        has_prefill = bool(np.any(batch.phase == PREFILL))
-        P = batch.prefill_bucket
-        if P and not self._grouped_built:
-            self._build_grouped()
+        W, P = batch.width, batch.prefill_bucket
+        pf_slots = np.flatnonzero(batch.phase == PREFILL)
+        if P and W not in self._built:
+            self._build_prefill_buckets(W)
         tr = self.tracer
         attrs = {"prefill_bucket": P} if P else {}
-        pf_slots = pf_logits = None
-        with tr.span("device_dispatch", width=width,
-                     has_prefill=has_prefill, **attrs):
+        with tr.span("device_dispatch", width=batch.tokens.shape[1],
+                     has_prefill=bool(pf_slots.size), **attrs):
             self.cache["lens"] = jnp.asarray(batch.row_start)
             self.cache["block_tables"] = jnp.asarray(batch.tables)
-            if P:
-                pf_slots = np.flatnonzero(batch.phase == PREFILL)
-                dec, pf, *rows = _grouped_args(batch, pf_slots)
-                logits, pf_logits, last, self.cache, counts = self._fn(
-                    width, True, P)(self.params, dec, pf, self.cache, *rows)
-            else:
-                toks = jnp.asarray(batch.tokens) if tokens is None \
-                    else tokens
-                logits, last, self.cache, counts = self._fn(
-                    width, has_prefill)(
-                    self.params, toks, self.cache,
-                    jnp.asarray(batch.n_valid),
-                    jnp.asarray(batch.phase == PREFILL))
+            toks, pf, *rows = _batch_inputs(batch, pf_slots,
+                                            self.scfg.prefill_chunk, tokens)
+            logits, pf_logits, last, self.cache, counts = self._fn(W, P)(
+                self.params, toks, pf, self.cache, *rows)
         self.n_steps += 1
         if fence and tr.enabled and tr.cfg.fence_device:
             # fence so device_wait covers actual execution, not just
@@ -462,7 +451,8 @@ class ModelRunner:
             with tr.span("device_wait"):
                 jax.block_until_ready((logits, last))
         return StepOutput(logits=logits, last_logits=last,
-                          prefill_logits=pf_logits, prefill_slots=pf_slots,
+                          prefill_logits=pf_logits,
+                          prefill_slots=pf_slots if P else None,
                           moe_counts=counts)
 
     # --- device-resident decode burst (async engine, docs/async.md) ---
@@ -571,4 +561,4 @@ class ModelRunner:
 
 __all__ = ["BACKENDS", "DECODE", "IDLE", "ModelRunner", "PREFILL",
            "STOP_L", "STOP_NS", "StepBatch", "StepOutput", "VERIFY",
-           "grouped_step", "prefill_buckets", "uniform_step"]
+           "prefill_bucket_for", "prefill_buckets", "step_fn"]

@@ -671,9 +671,9 @@ def profiled(nectar, tmp_path_factory):
 
 def test_engine_step_spans_carry_tick_and_rows(profiled):
     """Every tick runs inside one ``engine.step`` annotation whose stats
-    name the tick (step_num), the bucket (width, has_prefill) and the
-    rows as n_tokens:ctx_before:sampled — the rows of the batch the
-    runner executed."""
+    name the tick (step_num), the step shape (width, gen_width,
+    has_prefill) and the rows as n_tokens:ctx_before:sampled — the rows
+    of the batch the runner executed."""
     from repro.serve.runner import DECODE, IDLE, PREFILL
     eng, batches, ev = profiled
     steps = sorted(ev["engine.step"], key=lambda x: x[1]["step_num"])
@@ -689,7 +689,10 @@ def test_engine_step_spans_carry_tick_and_rows(profiled):
         assert sorted((n, c) for n, c, _ in rows) == sorted(
             zip(n_valid[live].tolist(), start[live].tolist()))
         assert bool(st["has_prefill"]) == bool((phase == PREFILL).any())
-        assert st["width"] in eng.runner.buckets
+        assert st["gen_width"] in eng.runner.widths
+        assert st["width"] == (eng.scfg.prefill_chunk
+                               if (phase == PREFILL).any()
+                               else st["gen_width"])
         assert st["prefill_tokens"] == int(n_valid[phase == PREFILL].sum())
         assert st["sampled_rows"] == sum(s for _, _, s in rows)
         # every decode row samples; only a prompt's last chunk does
@@ -718,7 +721,8 @@ def test_device_dispatch_span_carries_attributes(profiled):
     disp = ev["device_dispatch"]
     assert disp and all({"width", "has_prefill"} <= set(st)
                         for _, st in disp)
-    assert {st["width"] for _, st in disp} <= set(eng.runner.buckets)
+    assert {st["width"] for _, st in disp} \
+        <= {*eng.runner.widths, eng.scfg.prefill_chunk}
     assert all("rows" in st for _, st in ev["sample_sync"])
 
 
@@ -737,7 +741,7 @@ def test_compile_of_new_bucket_is_counted_and_named(nectar):
     # prefill bucket (max_batch 2: one and two rows of 13)
     for p in (1, 2):
         assert snap['engine_compiles_total{fun_name="jit(serve_step_w1_'
-                    f'prefill_p{p}x13)"}}'] == 1
+                    f'p{p}x13)"}}'] == 1
     names = set(eng.metrics.compiles.values)
     assert any(n.startswith("jit(serve_step_w") for n in names)
     first = eng.tracer.tick_stats[0]
@@ -746,7 +750,7 @@ def test_compile_of_new_bucket_is_counted_and_named(nectar):
         == reg.value("engine_compiles_total")
     text = reg.prometheus_text()
     assert "# TYPE engine_compiles_total counter" in text
-    assert 'engine_compiles_total{fun_name="jit(serve_step_w1_prefill_' \
+    assert 'engine_compiles_total{fun_name="jit(serve_step_w1_' \
         'p1x13)"} 1' in text
     # a fresh window starts the count again; warm buckets do not compile
     eng.reset_metrics()
@@ -765,8 +769,8 @@ def test_grouped_buckets_build_at_first_prefill_tick(nectar):
                     paged=True, max_batch=4, max_seq=96, block_size=8,
                     prefill_chunk=12)
     assert eng.runner.prefill_buckets == [1, 4]
-    assert {"jit(serve_step_w1_prefill_p1x12)",
-            "jit(serve_step_w1_prefill_p4x12)"} \
+    assert {"jit(serve_step_w1_p1x12)",
+            "jit(serve_step_w1_p4x12)"} \
         <= set(eng.metrics.compiles.values)
     eng.reset_metrics()
     # one to four chunks each: ticks with 4, 3, 2 and 1 prefill rows

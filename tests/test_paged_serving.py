@@ -59,9 +59,9 @@ def test_paged_decode_token_identical_to_contiguous(nectar):
 
 
 def test_chunked_prefill_matches_whole_prompt_logits(nectar):
-    """Prefill split into fixed chunks (PREFILL rows of the unified
-    forward_step) produces the same last-position logits as one
-    whole-prompt forward."""
+    """Prefill split into fixed chunks (the prefill group of the one
+    forward_step, beside an idle generation group) produces the same
+    last-position logits as one whole-prompt forward."""
     cfg, model, params = nectar
     prompt = _prompts(cfg, [29])[0]
 
@@ -74,15 +74,16 @@ def test_chunked_prefill_matches_whole_prompt_logits(nectar):
     tables = np.full((1, MB), nb, np.int32)
     tables[0, :MB] = np.arange(MB)
     pc["block_tables"] = jnp.asarray(tables)
+    one = jnp.zeros((1,), jnp.int32)
     pos = 0
     while pos < len(prompt):
         valid = min(C, len(prompt) - pos)
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :valid] = prompt[pos:pos + valid]
-        pc["lens"] = jnp.full((1,), pos, jnp.int32)
-        logits, pc = model.forward_step(
-            params, jnp.asarray(chunk), pc,
-            jnp.full((1,), valid, jnp.int32), jnp.ones((1,), bool), bs)
+        _, logits, pc, _ = model.forward_step(
+            params, jnp.zeros((1, 1), jnp.int32), jnp.asarray(chunk), pc,
+            one, one, jnp.full((1,), pos, jnp.int32),
+            jnp.full((1,), valid, jnp.int32), bs)
         last = logits[:, valid - 1]
         pos += valid
     np.testing.assert_allclose(np.asarray(last), np.asarray(ref)[:, 0],
@@ -111,11 +112,11 @@ def qwen_smoke():
 @pytest.mark.parametrize("n_prefill", [1, 2, 3])
 @pytest.mark.parametrize("model_name,backend", [
     ("nectar", "naive"), ("qwen_smoke", "naive"), ("nectar", "flash")])
-def test_grouped_step_matches_uniform_step(request, model_name, backend,
-                                           n_prefill):
-    """A grouped step (decode rows at width 1 beside a [P, C] prefill
-    group) gives the logits, last logits and pool of the uniform [B, C]
-    step in which every row is padded to the chunk."""
+def test_grouped_tick_matches_phase_by_phase_ticks(request, model_name,
+                                                   backend, n_prefill):
+    """A tick that runs decode rows at width 1 beside a [P, C] prefill
+    group gives the logits, last logits and pool of the same rows run
+    phase by phase: a prefill-only tick, then a decode-only tick."""
     from repro.serve.runner import DECODE, PREFILL, ModelRunner
     cfg, model, params = request.getfixturevalue(model_name)
     C = 8
@@ -127,7 +128,7 @@ def test_grouped_step_matches_uniform_step(request, model_name, backend,
     rng = np.random.default_rng(n_prefill)
     ctx = [11, 8, 8, 5]
     seqs = [rng.integers(0, cfg.vocab, n + C, dtype=np.int32) for n in ctx]
-    # the contexts, written by uniform prefill steps
+    # the contexts, written by prefill ticks
     for pos in range(0, max(ctx), C):
         b = runner.new_batch(C, tables)
         for slot, n in enumerate(ctx):
@@ -136,33 +137,42 @@ def test_grouped_step_matches_uniform_step(request, model_name, backend,
                           pos)
         runner.step(b)
     cache0 = runner.cache
+    rows = _GROUPED_TICKS[n_prefill]
 
-    def tick(n_prefill_rows):
-        runner.cache = cache0
-        b = runner.new_batch(C, tables, n_prefill=n_prefill_rows)
-        for slot, row in enumerate(_GROUPED_TICKS[n_prefill]):
-            if row is not None:
-                kind, n, start = row
-                b.add_row(slot, PREFILL if kind == "P" else DECODE,
-                          seqs[slot][start:start + n], start)
-        return b, runner.step(b), runner.cache
+    def tick(kinds):
+        """One tick of this case's rows of ``kinds`` ("P", "D")."""
+        mine = [(slot, row) for slot, row in enumerate(rows)
+                if row is not None and row[0] in kinds]
+        n_pf = sum(1 for _, row in mine if row[0] == "P")
+        b = runner.new_batch(1, tables, n_prefill=n_pf)
+        for slot, (kind, n, start) in mine:
+            b.add_row(slot, PREFILL if kind == "P" else DECODE,
+                      seqs[slot][start:start + n], start)
+        return b, runner.step(b)
 
-    bu, uni, cache_u = tick(0)
-    bg, grp, cache_g = tick(n_prefill)
-    assert bu.prefill_bucket == 0 and bu.tokens.shape == (4, C)
-    assert bg.prefill_bucket == {1: 1, 2: 4, 3: 4}[n_prefill]
+    runner.cache = cache0
+    bg, grp = tick("PD")
+    cache_g = runner.cache
+    runner.cache = cache0
+    bp, pre = tick("P")
+    bd, dec = tick("D")
+    assert bg.prefill_bucket == bp.prefill_bucket \
+        == {1: 1, 2: 4, 3: 4}[n_prefill]
+    assert bg.width == bd.width == 1 and bd.prefill_bucket == 0
+    assert bd.tokens.shape == (4, 1) and bg.tokens.shape == (4, C)
     assert grp.prefill_logits.shape[:2] == (bg.prefill_bucket, C)
     tol = dict(rtol=1e-5, atol=1e-5)
-    for slot, row in enumerate(_GROUPED_TICKS[n_prefill]):
+    for slot, row in enumerate(rows):
         if row is None:
             continue
+        ref = pre if row[0] == "P" else dec
         n = row[1]
         np.testing.assert_allclose(np.asarray(grp.last_logits)[slot],
-                                   np.asarray(uni.last_logits)[slot], **tol)
+                                   np.asarray(ref.last_logits)[slot], **tol)
         np.testing.assert_allclose(grp.row_logits(slot)[:n],
-                                   uni.row_logits(slot)[:n], **tol)
+                                   ref.row_logits(slot)[:n], **tol)
     for a, b in zip(jax.tree.leaves(cache_g["units"]),
-                    jax.tree.leaves(cache_u["units"])):
+                    jax.tree.leaves(runner.cache["units"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
 
 
@@ -231,10 +241,9 @@ def test_pool_read_matches_gather_read(qwen_smoke, case):
 
 def _decode_step_hlo(runner):
     """The lowered text of the runner's width-1 decode step."""
-    B = runner.scfg.max_batch
-    return runner._fn(1, False).lower(
-        runner.params, jnp.zeros((B, 1), jnp.int32), runner.cache,
-        jnp.ones((B,), jnp.int32), jnp.zeros((B,), bool)).as_text()
+    toks, pf, n_valid, *rows = runner.step_args(1, 0, jnp.ones)
+    return runner._fn(1, 0).lower(runner.params, toks, pf, runner.cache,
+                                  n_valid, *rows).as_text()
 
 
 @pytest.mark.parametrize("n_kv_blocks,read", [(30, "pool"), (0, "gather")])

@@ -82,6 +82,9 @@ def test_buckets_attainment_in_unit_interval(profiled):
     assert {r["bucket"] for r in rows} == {"decode", "mixed_p1",
                                            "mixed_p2"}
     for r in rows:
+        assert r["width"] == 1
+        assert r["bucket"] == (f"mixed_p{r['prefill_bucket']}"
+                               if r["prefill_bucket"] else "decode")
         assert r["ticks"] > 0 and r["dev_ms"] > 0
         assert r["GFLOP/s"] > 0 and r["GB/s"] > 0 and r["AI"] > 0
         assert 0.0 < r["attain"] <= 1.0

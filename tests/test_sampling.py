@@ -399,15 +399,27 @@ def test_runner_mixed_prefill_decode_batch(nectar):
 
 
 def test_runner_width_buckets(nectar):
+    """The generation group runs at width 1 or k_max+1 and the prefill
+    group at the prefill buckets; the token buffer widens to the chunk
+    only when prefill rows may ride along."""
     cfg, model, params = nectar
     scfg = ServeConfig(**_kw(spec=SpecConfig(k_max=6)))
     r = ModelRunner(model, params, scfg)
-    assert r.buckets == [1, 7, 16]
-    assert r.width_for(1) == 1
-    assert r.width_for(5) == 7
-    assert r.width_for(9) == 16
-    assert r.width_for(40) == 40               # registered on demand
-    assert 40 in r.buckets
+    tables = np.zeros((2, scfg.blocks_per_seq), np.int32)
+    assert r.widths == [1, 7]
+    assert r.prefill_buckets == [1, 2]
+    assert [r.prefill_bucket_for(n) for n in (0, 1, 2)] == [0, 1, 2]
+    shapes = {n: r.new_batch(n, tables) for n in (1, 5, 7)}
+    assert {n: (b.width, b.tokens.shape[1], b.prefill_bucket)
+            for n, b in shapes.items()} == {1: (1, 1, 0), 5: (7, 7, 0),
+                                            7: (7, 7, 0)}
+    b = r.new_batch(5, tables, n_prefill=2)
+    assert (b.width, b.tokens.shape[1], b.prefill_bucket) == (7, 16, 2)
+    # a row longer than every generation width can only be a prefill row
+    b = r.new_batch(9, tables)
+    assert (b.width, b.tokens.shape[1], b.prefill_bucket) == (7, 16, 0)
+    b.add_row(0, PREFILL, np.arange(9), 0)
+    assert b.prefill_bucket == 1
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.configs.base import ServeConfig, SpecConfig
+from repro.configs.base import ObsConfig, ServeConfig, SpecConfig
 from repro.models import Model
 from repro.serve import api, paged_kv
 from repro.serve.engine import Engine
@@ -73,6 +73,29 @@ def test_greedy_spec_ngram_token_identical(nectar):
     assert eng.pool.n_free == eng.pool.n_blocks
 
 
+def test_verify_rows_beside_prefill_group_run_at_k_plus_1(nectar):
+    """Staggered prompts: ticks that verify some rows while others
+    prefill run the verify rows at k_max+1 beside the prefill group (the
+    executable's name says so) instead of padding them to the chunk, and
+    greedy output equals the non-speculative engine's."""
+    cfg, _, params = nectar
+    # prefill_chunk 11: a shape no other test compiles, so this engine
+    # counts its compiles
+    kw = dict(_base_kw(), prefill_chunk=11)
+    prompts = _prompts(cfg, [5, 40, 70], seed=6)
+    base, _ = _serve(cfg, params, prompts, **kw)
+    spec, eng = _serve(cfg, params, prompts,
+                       spec=SpecConfig(drafter="selfspec", k=3, k_max=4),
+                       obs=ObsConfig(enabled=True), **kw)
+    assert base == spec
+    both = [t for t in eng.tracer.tick_stats
+            if t.get("rows_verify") and t.get("rows_prefill")]
+    assert any(t["gen_width"] == 5 for t in both)
+    assert all(t["width"] == 11 for t in both)
+    assert "jit(serve_step_w5_p1x11)" in set(eng.metrics.compiles.values)
+    assert eng.pool.n_free == eng.pool.n_blocks
+
+
 def test_greedy_spec_model_drafter_token_identical(nectar, draft):
     """A random-init draft model accepts ~nothing — output must STILL be
     token-identical (speculation changes cost, never content)."""
@@ -101,40 +124,40 @@ def test_greedy_spec_selfspec_token_identical(nectar):
 
 
 def test_verify_step_matches_sequential_decode(nectar):
-    """Model-level acceptance: one K+1-position VERIFY row of the unified
-    forward_step produces the same logits chain as feeding the tokens one
-    DECODE row at a time."""
+    """Model-level acceptance: one K+1-position VERIFY row of the
+    forward_step's generation group produces the same logits chain as
+    feeding the tokens one DECODE row at a time."""
     cfg, model, params = nectar
     bs, MB, nb = 8, 8, 16
     prompt = _prompts(cfg, [13], seed=4)[0]
     toks = _prompts(cfg, [4], seed=5)[0]         # pending + 3 "drafts"
     P = len(prompt)
-    no_prefill = jnp.zeros((1,), bool)
+    zero = jnp.zeros((1,), jnp.int32)
 
     def fresh():
         c = model.init_paged_cache(1, nb, bs, MB, jnp.float32)
         tables = np.full((1, MB), nb, np.int32)
         tables[0] = np.arange(MB)
         c["block_tables"] = jnp.asarray(tables)
-        c["lens"] = jnp.zeros((1,), jnp.int32)
-        _, c = model.forward_step(
-            params, jnp.asarray(np.pad(prompt, (0, 16 - P))[None]), c,
-            jnp.full((1,), P, jnp.int32), jnp.ones((1,), bool), bs)
+        _, _, c, _ = model.forward_step(
+            params, jnp.zeros((1, 1), jnp.int32),
+            jnp.asarray(np.pad(prompt, (0, 16 - P))[None]), c, zero, zero,
+            zero, jnp.full((1,), P, jnp.int32), bs)
         return c
 
     cache = fresh()
     cache["lens"] = jnp.full((1,), P, jnp.int32)
-    v_logits, _ = model.forward_step(
-        params, jnp.asarray(toks[None]), cache,
-        jnp.full((1,), len(toks), jnp.int32), no_prefill, bs)
+    v_logits, _, _, _ = model.forward_step(
+        params, jnp.asarray(toks[None]), None, cache,
+        jnp.full((1,), len(toks), jnp.int32), None, None, None, bs)
 
     cache = fresh()
     seq = []
     for i, t in enumerate(toks):
         cache["lens"] = jnp.full((1,), P + i, jnp.int32)
-        lg, cache = model.forward_step(
-            params, jnp.asarray([[t]]), cache, jnp.ones((1,), jnp.int32),
-            no_prefill, bs)
+        lg, _, cache, _ = model.forward_step(
+            params, jnp.asarray([[t]]), None, cache,
+            jnp.ones((1,), jnp.int32), None, None, None, bs)
         seq.append(np.asarray(lg)[0, 0])
     np.testing.assert_allclose(np.asarray(v_logits)[0], np.stack(seq),
                                rtol=2e-4, atol=2e-4)
@@ -351,14 +374,15 @@ def test_int8_kv_decode_equivalence_within_tolerance(nectar):
         tables[0] = np.arange(MB)
         c["block_tables"] = jnp.asarray(tables)
         P = len(prompt)
-        c["lens"] = jnp.zeros((1,), jnp.int32)
-        _, c = model.forward_step(
-            params, jnp.asarray(np.pad(prompt, (0, 32 - P))[None]), c,
-            jnp.full((1,), P, jnp.int32), jnp.ones((1,), bool), bs)
+        zero = jnp.zeros((1,), jnp.int32)
+        _, _, c, _ = model.forward_step(
+            params, jnp.zeros((1, 1), jnp.int32),
+            jnp.asarray(np.pad(prompt, (0, 32 - P))[None]), c, zero, zero,
+            zero, jnp.full((1,), P, jnp.int32), bs)
         c["lens"] = jnp.full((1,), P, jnp.int32)
-        lg, _ = model.forward_step(
-            params, jnp.asarray([[5]]), c, jnp.ones((1,), jnp.int32),
-            jnp.zeros((1,), bool), bs)
+        lg, _, _, _ = model.forward_step(
+            params, jnp.asarray([[5]]), None, c, jnp.ones((1,), jnp.int32),
+            None, None, None, bs)
         return np.asarray(lg)[0, 0]
 
     fp = decode_logits(False)

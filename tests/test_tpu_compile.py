@@ -83,15 +83,13 @@ def _compile_llama_step(one_chip, backend, n_blocks):
     cache = placed(jax.eval_shape(
         lambda: model.init_paged_cache(B, n_blocks, bs, mb, jnp.float32)))
 
-    def step(params, tokens, cache, n_valid, is_prefill):
-        return model.forward_step(params, tokens, cache, n_valid,
-                                  is_prefill, bs, backend=backend,
-                                  has_prefill=False)
+    def step(params, tokens, cache, n_valid):
+        return model.forward_step(params, tokens, None, cache, n_valid,
+                                  None, None, None, bs, backend=backend)
 
     compiled = jax.jit(step).lower(
         params, placed(jax.ShapeDtypeStruct((B, 1), jnp.int32)), cache,
-        placed(jax.ShapeDtypeStruct((B,), jnp.int32)),
-        placed(jax.ShapeDtypeStruct((B,), jnp.bool_))).compile()
+        placed(jax.ShapeDtypeStruct((B,), jnp.int32))).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
